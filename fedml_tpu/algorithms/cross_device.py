@@ -63,6 +63,7 @@ from fedml_tpu.device_cohort import (WaveAdmission, make_scaffold_wave_fn,
                                      make_wave_fn, plan_waves)
 from fedml_tpu.obs import telemetry
 from fedml_tpu.parallel.cohort import train_cohort
+from fedml_tpu.parallel.mesh import placement_of
 from fedml_tpu.trainer.local_sgd import make_local_trainer
 from fedml_tpu.trainer.workload import make_client_optimizer
 
@@ -452,6 +453,7 @@ class CrossDevice(FedAvg):
         acc = {"tau": 0.0,             # fednova: Σ n_i·tau_i across waves
                "c_delta": None,        # scaffold: Σ live·(c_i+ − c_i)
                "folded": 0, "live": 0}
+        wave_devices = 0  # devices the last wave's updates land on
 
         for wi, wave in enumerate(waves):
             if wave.n_live == 0:
@@ -470,6 +472,8 @@ class CrossDevice(FedAvg):
                     params, wave_data, round_rng, offset)
                 new_c = c_delta = None
             wave_weight = float(total)  # blocks: the wave ran to completion
+            if wi == len(waves) - 1:
+                wave_devices = placement_of(stacked)["devices"]
             dt = time.perf_counter() - t0
             self._c_waves.inc()
             self._h_wave.observe(dt)
@@ -543,7 +547,8 @@ class CrossDevice(FedAvg):
                 round_idx, new_global=jax.tree.map(np.asarray, new_params),
                 cohort=len(ids), waves=len(waves), folded_waves=folded)
         return new_params, {"waves": len(waves), "folded_waves": folded,
-                            "clients": live_clients}
+                            "clients": live_clients,
+                            "wave_devices": wave_devices}
 
     # -- run loop -------------------------------------------------------------
     def run(self, params=None, rng: Optional[jax.Array] = None,
@@ -606,6 +611,7 @@ class CrossDevice(FedAvg):
             if (round_idx % cfg.frequency_of_the_test == 0
                     or round_idx == cfg.comm_round - 1):
                 stats = self.evaluate_global(params)
+                where = placement_of(params)
                 stats.update(round=round_idx, round_s=round_s,
                              cohort=len(ids), waves=info["waves"],
                              folded_waves=info["folded_waves"],
@@ -613,7 +619,11 @@ class CrossDevice(FedAvg):
                              # provenance: which sampler/trainer made
                              # this curve — never silently cross-compare
                              sampler=cfg.sampler,
-                             local_alg=cfg.local_alg)
+                             local_alg=cfg.local_alg,
+                             # where the state landed
+                             wave_devices=info["wave_devices"],
+                             global_platform=where["platform"],
+                             global_devices=where["devices"])
                 logger.info("round %d: %s", round_idx, stats)
                 self.history.append(stats)
                 if self.sink is not None:

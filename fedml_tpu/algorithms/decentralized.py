@@ -32,10 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from fedml_tpu.core.topology import SymmetricTopologyManager
 from fedml_tpu.data.stacking import FederatedData
-from fedml_tpu.parallel.cohort import (cohort_eval,
-                                       compat_axis_size,
-                                       compat_pcast_varying,
-                                       compat_shard_map)
+from fedml_tpu.parallel.cohort import cohort_eval
 from fedml_tpu.trainer.local_sgd import make_local_trainer, make_evaluator
 from fedml_tpu.trainer.workload import Workload, make_client_optimizer
 
@@ -70,7 +67,7 @@ def ring_mix_sharded(local: Pytree, axis_name: str, w_self: float,
                      w_left: float, w_right: float) -> Pytree:
     """Ring gossip over a mesh axis with two `ppermute`s — the ICI-native
     neighbor exchange (one node per device)."""
-    n = compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if not isinstance(n, int):
         # the traced psum-of-ones last resort serves arithmetic-only
         # callers (hierarchical's copy divisor); the ppermute tables
@@ -160,7 +157,7 @@ class DecentralizedGossip:
             w_self, w_left, w_right = _ring_weights(np.asarray(self.W))
 
             def per_device(stacked_params, data_stacked, rng):
-                rng = compat_pcast_varying(rng, ("clients",))
+                rng = jax.lax.pcast(rng, ("clients",), to="varying")
                 i = jax.lax.axis_index("clients")
                 local_params = jax.tree.map(lambda x: x[0], stacked_params)
                 local_data = jax.tree.map(lambda x: x[0], data_stacked)
@@ -172,7 +169,7 @@ class DecentralizedGossip:
                                          w_self, w_left, w_right)
                 return jax.tree.map(lambda x: x[None], mixed)
 
-            self._round = jax.jit(compat_shard_map(
+            self._round = jax.jit(jax.shard_map(
                 per_device, mesh=mesh,
                 in_specs=(P("clients"), P("clients"), P()),
                 out_specs=P("clients")))

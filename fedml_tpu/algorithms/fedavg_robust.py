@@ -22,7 +22,8 @@ import logging
 from fedml_tpu.algorithms.fedavg import FedAvg, FedAvgConfig
 from fedml_tpu.core.byzantine import METHODS as BYZ_METHODS
 from fedml_tpu.core.byzantine import make_byzantine_aggregate
-from fedml_tpu.core.pallas_agg import make_fused_robust_aggregate
+from fedml_tpu.core.pallas_agg import (make_fused_robust_aggregate,
+                                       pallas_interpret)
 from fedml_tpu.core.robust import add_gaussian_noise, clip_update
 from fedml_tpu.parallel.cohort import make_cohort_step
 from fedml_tpu.trainer.local_sgd import make_local_trainer
@@ -119,12 +120,11 @@ class FedAvgRobust(FedAvg):
                 raise ValueError("defense_backend='pallas' does not shard "
                                  "over a mesh; drop --mesh_clients or use "
                                  "the xla backend")
-            import jax
             fused = make_fused_robust_aggregate(
                 norm_bound=(cfg.norm_bound if cfg.defense in
                             ("norm_diff_clipping", "weak_dp") else None),
                 noise_std=(cfg.stddev if cfg.defense == "weak_dp" else 0.0),
-                interpret=jax.default_backend() != "tpu")
+                interpret=pallas_interpret("robust_aggregate"))
             self.cohort_step = make_cohort_step(
                 local_train, aggregate=fused,
                 client_axis=cfg.client_axis)

@@ -33,9 +33,7 @@ from fedml_tpu.algorithms.fedavg import FedAvg, FedAvgConfig
 from fedml_tpu.core.pytree import tree_weighted_mean
 from fedml_tpu.core.sampling import sample_clients
 from fedml_tpu.data.stacking import gather_cohort
-from fedml_tpu.parallel.cohort import (compat_axis_size,
-                                       compat_pcast_varying,
-                                       compat_shard_map, train_cohort)
+from fedml_tpu.parallel.cohort import train_cohort
 
 logger = logging.getLogger(__name__)
 
@@ -110,8 +108,9 @@ def make_two_level_round(local_train, group_comm_round: int, mesh):
     from jax.sharding import PartitionSpec as P
 
     def per_device(params, cohort, rng):
-        params = compat_pcast_varying(params, ("groups", "clients"))
-        rng = compat_pcast_varying(rng, ("groups", "clients"))
+        params = jax.lax.pcast(params, ("groups", "clients"),
+                               to="varying")
+        rng = jax.lax.pcast(rng, ("groups", "clients"), to="varying")
         g = jax.lax.axis_index("groups")
         c = jax.lax.axis_index("clients")
         local = jax.tree.map(lambda v: v[0], cohort)   # [M/D, ...] shard
@@ -145,14 +144,14 @@ def make_two_level_round(local_train, group_comm_round: int, mesh):
         # duplicate copies — this also lets shard_map statically prove the
         # P() (fully replicated) out_spec
         tot = jax.lax.psum(total_g, "groups")
-        D = compat_axis_size("clients")
+        D = jax.lax.axis_size("clients")
         share = total_g / jnp.maximum(tot, 1.0) / D
         return jax.tree.map(
             lambda x: jax.lax.psum(x.astype(jnp.float32) * share,
                                    ("groups", "clients")).astype(x.dtype),
             p_g)
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(), P("groups", "clients"), P()), out_specs=P())
     return jax.jit(sharded)

@@ -108,7 +108,10 @@ class IngestArena:
     fold worker) is the caller's contract — the flat buffer is reused
     across uploads."""
 
-    def __init__(self, template, *, name: str = "ingest", perf=None):
+    def __init__(self, template, *, name: str = "ingest", perf=None,
+                 device=None):
+        """``device``: where staged uploads land (a sharded server passes
+        each shard's own); None is the default device."""
         import jax
         from fedml_tpu.comm.message import _flatten_arrays
         # host-normalize first: the wire codec ships numpy trees, and
@@ -130,6 +133,7 @@ class IngestArena:
         self._offsets = np.concatenate(
             ([0], np.cumsum(self._sizes))).astype(np.int64)
         self.n_elems = int(self._offsets[-1])
+        self._device = device
         if not self.supported:
             return
         import jax
@@ -137,7 +141,8 @@ class IngestArena:
         # the pre-pinned arena: reused across uploads (single consumer),
         # one device_put ships it whole
         self._flat = np.empty(self.n_elems, np.float32)
-        self._ref = jnp.zeros(self.n_elems, jnp.float32)
+        self._ref = jax.device_put(np.zeros(self.n_elems, np.float32),
+                                   device)
 
         def _screen(flat, ref):
             # fused finite + sumsq over the flat buffer: ONE reduction
@@ -175,9 +180,9 @@ class IngestArena:
         if not self.supported:
             return
         import jax
-        import jax.numpy as jnp
         if reference is None:
-            self._ref = jnp.zeros(self.n_elems, jnp.float32)
+            self._ref = jax.device_put(
+                np.zeros(self.n_elems, np.float32), self._device)
             return
         from fedml_tpu.comm.message import _flatten_arrays
         leaves, _ = _flatten_arrays(jax.tree.map(np.asarray, reference))
@@ -185,7 +190,7 @@ class IngestArena:
         for view, o, n in zip(leaves, self._offsets[:-1], self._sizes):
             np.copyto(flat[o:o + n],
                       np.asarray(view, np.float32).reshape(-1))
-        self._ref = jax.device_put(flat)
+        self._ref = jax.device_put(flat, self._device)
 
     # -- the structural screen (header vs template, no tree walk) ------------
     def match_header(self, descr, spec) -> bool:
@@ -261,7 +266,7 @@ class IngestArena:
         flat = self._flat
         for v, o, n in zip(views, self._offsets[:-1], self._sizes):
             np.copyto(flat[o:o + n], v.reshape(-1))
-        dev = jax.device_put(flat)          # ONE transfer per shard
+        dev = jax.device_put(flat, self._device)  # ONE transfer per shard
         finite, sumsq = self._screen_fn(dev, self._ref)
         leaves = self._split_fn(dev)
         from fedml_tpu.comm.message import _unflatten_arrays

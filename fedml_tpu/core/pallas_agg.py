@@ -26,13 +26,13 @@ Semantics parity: with σ=0 the result equals the XLA compose
 stream differs (murmur counter PRG vs threefry), exactly like the SecAgg
 pallas backend (secure/pallas_mask.py).
 
-CPU/test fallback: ``interpret=True`` runs the same kernel through the
-Pallas interpreter.
+Compiled or interpreted: `pallas_interpret`, for every in-repo kernel.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Optional
 
 import jax
@@ -44,6 +44,28 @@ from fedml_tpu.core.robust import _masked_global_norm, default_is_weight_param
 
 Pytree = Any
 
+log = logging.getLogger(__name__)
+
+
+def pallas_interpret(kernel: str) -> bool:
+    """The ``interpret=`` argument for an in-repo Pallas kernel.  On a TPU
+    never interpret: Mosaic compiles it, and a kernel Mosaic refuses is a
+    bug to fix.  On the CPU an explicitly requested Pallas backend runs
+    through the interpreter (a correctness tool, not a speed).  Any other
+    platform is an error, not a silently interpreted kernel.  The decision
+    is logged by kernel name (chip_smoke.py asserts "compiled" from it)."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        log.info("pallas kernel %s: %s", kernel, "compiled")
+        return False
+    if platform == "cpu":
+        log.info("pallas kernel %s: %s", kernel, "interpreted")
+        return True
+    raise RuntimeError(
+        f"pallas kernel {kernel}: platform {platform!r} is neither tpu "
+        f"(Mosaic) nor cpu (interpreter); select the xla backend")
+
+
 _LANES = 128
 _MAX_BLOCK_ELEMS = 4096 * 128   # x-block budget: N*rows*128 f32 <= 2 MiB
 
@@ -54,6 +76,8 @@ def _rows_per_block(num_clients: int) -> int:
 
 
 def _murmur_fmix(x: jax.Array) -> jax.Array:
+    """murmur3's 32-bit finalizer — a full-avalanche uint32 hash on the
+    VPU."""
     x = x ^ (x >> 16)
     x = x * jnp.uint32(0x85EBCA6B)
     x = x ^ (x >> 13)

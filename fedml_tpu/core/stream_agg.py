@@ -88,10 +88,9 @@ class StreamingAggregator:
     ``kind="delta"`` clips against zeros and pads the reservoir with
     zero deltas (the async server's semantics).
 
-    ``donate="auto"``: donate the accumulator buffer to each fold so XLA
-    reuses it in place — O(model) steady state with zero per-fold
-    allocation off-CPU; CPU backends warn-and-ignore donation, so auto
-    keeps it off there (same contract as `make_defended_aggregate`).
+    The accumulator buffer is donated to each fold so XLA reuses it in
+    place — O(model) steady state with zero per-fold allocation, on every
+    backend (the CPU tier runs the same donated programs the chip runs).
 
     ``device``: a `fedml_tpu.obs.device.DeviceRecorder`; when set, the
     hot fold/finalize jits run behind the observatory's wrappers — each
@@ -105,7 +104,7 @@ class StreamingAggregator:
                  noise_std: float = 0.0, seed: int = 0,
                  reservoir_k: int = 64, trim_frac: float = 0.1,
                  byz_f: int = 0, krum_m: int = 1, gm_iters: int = 8,
-                 gm_eps: float = 1e-6, donate="auto", sentry=None,
+                 gm_eps: float = 1e-6, sentry=None,
                  device=None):
         from fedml_tpu.robust.defense import (ROBUST_AGG_METHODS,
                                               make_defended_aggregate)
@@ -145,10 +144,10 @@ class StreamingAggregator:
         self.count = 0                  # uploads folded this round
         self.weight_total = 0.0         # host f64 fold-order weight sum:
         #                                 readable AFTER finalize (the
-        #                                 device _wsum is donated away
-        #                                 there) — the edge frame's
-        #                                 num_samples and the health
-        #                                 observatory both read it
+        #                                 device _wsum is dropped there)
+        #                                 — the edge frame's num_samples
+        #                                 and the health observatory
+        #                                 both read it
         self._seen = 0                  # reservoir: uploads offered
         self._res_leaves: Optional[list] = None   # [K, ...] host buffers
         self._res_def = None
@@ -156,9 +155,6 @@ class StreamingAggregator:
         self._res_rng = np.random.RandomState(seed)
 
         if method == "mean":
-            if donate == "auto":
-                donate = jax.default_backend() != "cpu"
-
             def _fold(acc, wsum, upload, weight, reference):
                 if norm_clip > 0:
                     upload = clip_update(upload, reference, norm_clip)
@@ -200,9 +196,9 @@ class StreamingAggregator:
                 return acc, wsum
 
             self._fold_fn = jax.jit(
-                _fold, donate_argnums=(0, 1) if donate else ())
+                _fold, donate_argnums=(0, 1))
             self._fold_wave_fn = jax.jit(
-                _fold_wave, donate_argnums=(0, 1) if donate else ())
+                _fold_wave, donate_argnums=(0, 1))
             self._finalize_fn = jax.jit(_finalize)
             if device is not None:
                 # per-arrival hot path: every fold call feeds the
@@ -233,7 +229,7 @@ class StreamingAggregator:
             self._finalize_fn = make_defended_aggregate(
                 method, trim_frac=trim_frac, byz_f=byz_f, krum_m=krum_m,
                 gm_iters=gm_iters, gm_eps=gm_eps, norm_clip=norm_clip,
-                noise_std=noise_std, seed=seed, donate=donate)
+                noise_std=noise_std, seed=seed)
             if device is not None:
                 # the reservoir finalize IS the sentry-monitored cache
                 # (self._hot_jit): signatures land under the registered
@@ -451,8 +447,8 @@ class StreamingAggregator:
         if self.method == "mean":
             out = self._finalize_fn(self._acc, self._wsum, self._reference,
                                     step)
-            # the accumulator was (possibly) donated; drop our handle so
-            # a stale buffer is never folded into the next round
+            # drop our handle to the finalized accumulator so a stale
+            # buffer is never folded into the next round
             self._acc = self._wsum = None
         else:
             out = self._finalize_fn(self._reference, self._res_stack,

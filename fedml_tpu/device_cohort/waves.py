@@ -37,10 +37,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from fedml_tpu.core.pytree import acc_dtype
-# new-vs-old jax shard_map/pcast compat lives with the cohort engine —
-# THE one home for the convention (parallel/cohort.py)
-from fedml_tpu.parallel.cohort import (compat_pcast_varying,
-                                       compat_shard_map)
 # per-wave screens reuse the live admission pipeline's statistics
 # helpers so wave screening can never drift from upload screening
 from fedml_tpu.robust.admission import (AdmissionVerdict, _all_finite,
@@ -135,8 +131,8 @@ def make_wave_fn(make_stacked: Callable, mesh: Optional[Mesh] = None):
         # per-device: wave_data leaves are the local shard [W/D, ...];
         # params/rng arrive replicated — mark them device-varying so the
         # local-train scan carry typechecks (parallel/cohort.py idiom)
-        params = compat_pcast_varying(params, ("clients",))
-        rng = compat_pcast_varying(rng, ("clients",))
+        params = jax.lax.pcast(params, ("clients",), to="varying")
+        rng = jax.lax.pcast(rng, ("clients",), to="varying")
         local_c = wave_data["num_samples"].shape[0]
         local_off = offset + jax.lax.axis_index("clients") * local_c
         stacked, aux = make_stacked(params, wave_data, rng, local_off)
@@ -145,7 +141,7 @@ def make_wave_fn(make_stacked: Callable, mesh: Optional[Mesh] = None):
                                               psum_axis="clients")
         return stacked, w, mean, total, aux_sums
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         _sharded, mesh=mesh,
         in_specs=(P(), P("clients"), P(), P()),
         out_specs=(P("clients"), P("clients"), P(), P(), P()))

@@ -56,7 +56,10 @@ def load_experiment_data(cfg: ExperimentConfig):
     from fedml_tpu.data import load_data
     kw: Dict[str, Any] = {"batch_size": cfg.batch_size}
     if cfg.dataset in ("cifar10", "cifar100", "cinic10"):
+        # client_num partitions the real pickle tree, num_clients sizes
+        # the hermetic twin (each loader drops the other's knob)
         kw.update(client_num=cfg.client_num_in_total,
+                  num_clients=cfg.client_num_in_total,
                   partition_method=cfg.partition_method,
                   partition_alpha=cfg.partition_alpha,
                   seed=cfg.seed)
@@ -1290,6 +1293,16 @@ def run_cross_silo(cfg, data, mesh, sink):
         if r % cfg.frequency_of_the_test == 0 or r == cfg.comm_round - 1:
             stats = _eval_global(wl, params, data)
             stats["round"] = r
+            # where the state lives: fewer devices than shards keeps
+            # everything on the default device, and the summary says so
+            from fedml_tpu.parallel.mesh import placement_of
+            where = placement_of(params)
+            stats["global_platform"] = where["platform"]
+            stats["global_devices"] = where["devices"]
+            if shard_spine is not None:
+                stats["shard_state_devices"] = len(
+                    {shard_spine.agg.shard_device(s)
+                     for s in range(shard_spine.num_shards)})
             if cfg.wire_compression != "none":
                 # compressed bytes received since the last eval round
                 stats["upload_bytes"] = wire_stats["bytes"]
@@ -1473,7 +1486,9 @@ def run_cross_silo(cfg, data, mesh, sink):
             # a ciphertext norm is PRG noise — but the ring fold still
             # runs on the worker.
             if shard_spine is not None:
-                arenas = [IngestArena(sl, name=f"ingest_s{s}", perf=perf)
+                # each shard's upload lands where its fold state lives
+                arenas = [IngestArena(sl, name=f"ingest_s{s}", perf=perf,
+                                      device=shard_spine.agg.shard_device(s))
                           for s, sl in enumerate(
                               shard_spine.broadcast_slices(init))]
             else:
@@ -1988,8 +2003,8 @@ def run_vfl(cfg, data, mesh, sink):
 # --------------------------------------------------------------------------
 
 def setup_platform(cfg: ExperimentConfig) -> None:
-    """Pick the jax platform/devices BEFORE any backend initializes (env
-    vars alone don't stick — the PJRT plugin overwrites them)."""
+    """Apply ``--platform`` / ``--host_device_count`` BEFORE any backend
+    initializes (without the flags, JAX's own env vars decide)."""
     import os
     if cfg.host_device_count > 0:
         flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
@@ -2002,22 +2017,51 @@ def setup_platform(cfg: ExperimentConfig) -> None:
         jax.config.update("jax_platforms", cfg.platform)
 
 
-def enable_compile_cache() -> None:
-    """Persistent XLA compilation cache, gated on the RESOLVED backend
-    (initializes it): TPU first-compiles run 20-40s+ per program
-    (multi-minute for the big models), so caching makes every rerun of the
-    same config start hot.  CPU backends stay uncached — compiles are
-    cheap there and tests churn shapes, which would just grow the cache.
-    ``FEDML_TPU_CACHE=path`` overrides the location; empty disables.
-    Call AFTER platform selection (setup_platform), at a point where
-    backend initialization is acceptable."""
-    import os
+def open_backend() -> None:
+    """Initialize the backend NOW: a process that cannot get a chip fails
+    here, at start-up and in seconds (libtpu's "already in use by process
+    ..."), with the supported layouts in the message."""
     import jax
-    cache = os.environ.get("FEDML_TPU_CACHE",
-                           os.path.expanduser("~/.cache/fedml_tpu_xla"))
-    if cache and jax.default_backend() != "cpu":
+    try:
+        jax.devices()
+    except RuntimeError as e:
+        raise RuntimeError(
+            f"{e}\nfedml_tpu: a chip belongs to one process at a time — "
+            f"run one process per chip (TPU_VISIBLE_CHIPS=k), or keep the "
+            f"processes that only aggregate (the gRPC server, --node_id 0) "
+            f"on the CPU with --platform cpu") from e
+
+
+def compile_cache_dir() -> Optional[str]:
+    """``<checkout>/.jax_cache`` (git-ignored), derived from this
+    package's location and nothing that changes between runs — a cache in
+    a directory that moves never hits.  None when
+    ``JAX_COMPILATION_CACHE_DIR`` is set: JAX reads that itself, and the
+    code sets no other."""
+    import os
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
+
+
+def enable_compile_cache() -> None:
+    """Persistent XLA compilation cache — THE one place every entry point
+    (`main`, bench.py, chip_smoke.py) configures it.  Call AFTER platform
+    selection (it initializes the backend).  A CPU run is left alone:
+    compiles are cheap there and tests churn shapes.  On an accelerator
+    the directory is the environment's or `compile_cache_dir`, and every
+    program is kept whatever its compile time: chip_smoke.py's cold run
+    spent 116 of its 245 compile seconds in programs that took under 5 s
+    each (93 s under 1 s), which the former 5 s floor — or JAX's 1 s
+    default — would pay again on every start (PERF.md, PR 21)."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return
+    cache = compile_cache_dir()
+    if cache:
         jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def main(argv=None) -> Dict[str, Any]:
@@ -2042,6 +2086,7 @@ def main(argv=None) -> Dict[str, Any]:
     from fedml_tpu.parallel.mesh import init_distributed, make_mesh
     init_distributed(cfg.coordinator_address, cfg.num_processes,
                      cfg.process_id)
+    open_backend()
     enable_compile_cache()
     mesh = None
     if cfg.mesh_groups > 0:

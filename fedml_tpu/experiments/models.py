@@ -48,6 +48,22 @@ def create_workload(model_name: str, dataset: str, class_num: int,
     if attn_block_size and attn_flash:
         raise ValueError("--attn_block_size and --attn_flash are mutually "
                          "exclusive attention backends; pick one")
+    if attn_flash:
+        # refuse HERE, at config time, what the kernel would otherwise
+        # refuse at trace time inside the first training jit
+        import jax
+        from fedml_tpu.models.transformer import FLASH_BLOCK
+        seq_len = int(sample_shape[0])
+        if jax.default_backend() != "tpu":
+            raise ValueError(
+                f"--attn_flash is JAX's TPU flash-attention kernel and "
+                f"this run resolved to the {jax.default_backend()!r} "
+                f"backend; use --attn_block_size instead")
+        if seq_len % FLASH_BLOCK:
+            raise ValueError(
+                f"--attn_flash tiles the sequence in blocks of "
+                f"{FLASH_BLOCK} and --dataset {dataset} has windows of "
+                f"{seq_len} tokens; use --attn_block_size instead")
     if dtype is not None and dataset == "stackoverflow_lr":
         raise ValueError(
             f"--compute_dtype is not wired into the tag-prediction "

@@ -52,16 +52,22 @@ def _auto_block(t: int, threshold: int, max_block: int = 512,
     return None
 
 
+# the flash kernel's q/kv block: the sequence must be a whole number of
+# these (its default BlockSizes; `create_workload` checks at config time)
+FLASH_BLOCK = 128
+
+
 def _pallas_flash(q, k, v):
     """TPU-fused flash attention (jax.experimental.pallas.ops.tpu) for the
     dense causal case — one VMEM-tiled kernel instead of XLA-scheduled
-    matmul+softmax.  TPU backend only; q/k/v are [B, T, H, d]."""
-    import jax
-    if jax.default_backend() != "tpu":
+    matmul+softmax.  TPU backend only; q/k/v are [B, T, H, d] with T a
+    multiple of `FLASH_BLOCK`."""
+    if jax.default_backend() != "tpu" or q.shape[1] % FLASH_BLOCK:
         raise RuntimeError(
-            "use_flash=True needs a TPU backend (the pallas flash kernel "
-            "does not run on CPU); use block_size= for a backend-neutral "
-            "memory-efficient path")
+            f"use_flash=True needs a TPU backend and a sequence that is a "
+            f"multiple of {FLASH_BLOCK} (got {jax.default_backend()!r}, "
+            f"T={q.shape[1]}); use block_size= for a backend-neutral "
+            f"memory-efficient path")
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention)
     # kernel layout is [B, H, T, d]

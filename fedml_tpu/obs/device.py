@@ -32,10 +32,10 @@ Three instruments, riding the `PerfRecorder` round cadence (one
 Honesty contract (the retracted-mfu-1.57 lesson, obs/trend.py):
 
 * an unmeasurable quantity ledgers ``null``, never 0;
-* MFU's denominator is the shared device-kind peak table.  On backends
-  with no table entry (CPU) the conservative accelerator-class default
-  applies — an upper bound no host CPU approaches, so the gauge is
-  <= 1.0 by construction there and the section labels its backend;
+* MFU's denominator is the shared device-kind peak table.  The CPU
+  backend has no entry and no peak: ``peak_tflops`` and ``mfu`` ledger
+  ``null`` there, with the reason in ``peak_source``.  An ACCELERATOR
+  whose ``device_kind`` is not in the table is an error, never a default;
 * FLOPs whose cost analysis failed mark the round ``flops_complete:
   false`` (the reported sum is then a lower bound — and so is the MFU).
 
@@ -67,42 +67,37 @@ PEAK_TFLOPS_BY_KIND = (("v6", 918.0), ("trillium", 918.0), ("v5p", 459.0),
                        ("v5e", 197.0), ("v5lite", 197.0), ("v4", 275.0),
                        ("v3", 123.0), ("v2", 45.0))
 
-# unknown accelerator: keep the v5e assumption.  On CPU backends this is
-# a deliberate upper bound MANY orders above the silicon, which is what
-# makes the live MFU gauge <= 1.0 by construction there (and useless as
-# a utilization number — the ledger labels backend "cpu" so nobody
-# quotes it as one).
-DEFAULT_PEAK_TFLOPS = 197.0
-
 MFU_PROVENANCE = ("xla_cost_analysis_of_registered_hot_jits / "
                   "shared_device_kind_peak_table")
 
 
-def peak_tflops_for_device(dev) -> float:
-    """Peak bf16 TF/s for ``dev`` (None allowed: env override or the
-    conservative default).  THE peak table — ``bench._peak_for_device``
-    is this function (identity-pinned)."""
+def peak_and_source(dev) -> Tuple[Optional[float], str]:
+    """``(peak bf16 TF/s, where it came from)`` for ``dev`` — the source
+    is ledgered beside every MFU so an impossible value is attributable
+    to its denominator, and a null one to its reason."""
     env = os.environ.get("BENCH_PEAK_TFLOPS")
     if env:
-        return float(env)
+        return float(env), "BENCH_PEAK_TFLOPS env override"
+    if getattr(dev, "platform", None) == "cpu":
+        return None, ("cpu backend: no accelerator peak, so no MFU "
+                      "(null, never a number against an assumed chip)")
     kind = str(getattr(dev, "device_kind", "")).lower().replace(" ", "")
     for key, peak in PEAK_TFLOPS_BY_KIND:
         if key in kind:
-            return peak
-    return DEFAULT_PEAK_TFLOPS
+            return peak, f"device_kind table ({key})"
+    raise ValueError(
+        f"no peak-FLOPS entry for accelerator device_kind "
+        f"{getattr(dev, 'device_kind', None)!r}: add it to "
+        f"PEAK_TFLOPS_BY_KIND with its source (or set BENCH_PEAK_TFLOPS) "
+        f"— an unknown device is an error, not a default")
 
 
-def peak_source_for_device(dev) -> str:
-    """Where the peak number came from — ledgered beside every MFU so an
-    impossible value is attributable to its denominator assumption."""
-    if os.environ.get("BENCH_PEAK_TFLOPS"):
-        return "BENCH_PEAK_TFLOPS env override"
-    kind = str(getattr(dev, "device_kind", "")).lower().replace(" ", "")
-    for key, _ in PEAK_TFLOPS_BY_KIND:
-        if key in kind:
-            return f"device_kind table ({key})"
-    return (f"device_kind table default (no entry for {kind!r} — "
-            f"conservative accelerator-class upper bound)")
+def peak_tflops_for_device(dev) -> Optional[float]:
+    """Peak bf16 TF/s for ``dev``: the env override, else the table
+    entry for its ``device_kind``; None on the CPU backend; an
+    accelerator kind the table lacks RAISES.  THE peak table —
+    ``bench._peak_for_device`` is this function (identity-pinned)."""
+    return peak_and_source(dev)[0]
 
 
 def compiled_flops(jitted, *args, **kwargs) -> float:
@@ -335,33 +330,25 @@ class DeviceRecorder:
 
     # -- peak / backend resolution (lazy: jax must not load at import) -------
     def _resolve_peak(self) -> None:
-        if self._peak_tflops is not None:
+        if self._peak_source is not None:
             return
-        dev = None
-        n = 1
-        try:
-            import jax
-            devs = jax.local_devices()
-            dev = devs[0] if devs else None
-            n = max(1, len(devs))
-            self._backend = jax.default_backend()
-        except Exception:  # noqa: BLE001
-            pass
+        import jax
+        devs = jax.local_devices()
+        n = len(devs)
+        self._backend = jax.default_backend()
         # the achieved-FLOP/s numerator sums programs across ALL local
         # devices, so the denominator is the per-chip table peak TIMES
         # the local device count — a sharded aggregate honestly beating
         # one chip's peak must not ledger as "physically impossible"
-        self._peak_tflops = peak_tflops_for_device(dev) * n
-        self._peak_source = peak_source_for_device(dev) + (
-            f" x {n} local devices" if n > 1 else "")
+        peak, source = peak_and_source(devs[0])
+        self._peak_tflops = None if peak is None else peak * n
+        self._peak_source = source + (
+            f" x {n} local devices" if peak is not None and n > 1 else "")
 
-    def backend(self) -> Optional[str]:
+    def backend(self) -> str:
         if self._backend is None:
-            try:
-                import jax
-                self._backend = jax.default_backend()
-            except Exception:  # noqa: BLE001
-                return None
+            import jax
+            self._backend = jax.default_backend()
         return self._backend
 
     # -- instrumentation -----------------------------------------------------
@@ -547,7 +534,8 @@ class DeviceRecorder:
         achieved = mfu = None
         if flops > 0 and round_s:
             achieved = flops / float(round_s)
-            mfu = achieved / (self._peak_tflops * 1e12)
+            if self._peak_tflops is not None:
+                mfu = achieved / (self._peak_tflops * 1e12)
         section = {
             "backend": self.backend(),
             "memory": mem,
@@ -595,7 +583,9 @@ class DeviceRecorder:
             if self._g_flops is None:
                 self._g_flops = self._registry.gauge(
                     "fedml_dev_achieved_flops_value")
-                self._g_mfu = self._registry.gauge("fedml_perf_mfu_ratio")
             self._g_flops.set(achieved)
+        if mfu is not None:
+            if self._g_mfu is None:
+                self._g_mfu = self._registry.gauge("fedml_perf_mfu_ratio")
             self._g_mfu.set(mfu)
         return section

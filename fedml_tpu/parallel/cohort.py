@@ -35,60 +35,6 @@ CohortData = Dict[str, jax.Array]  # leaves [C, S, B, ...]; "num_samples" [C]
 CohortStep = Callable[..., Tuple[Pytree, Dict[str, jax.Array]]]
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """`jax.shard_map` where available, else the experimental spelling
-    older toolchains ship.  ``check_vma=None`` leaves the new API's
-    default checking on; the old API's `check_rep` (its analog) is
-    disabled — it predates the pcast annotations these bodies use to
-    satisfy the checker."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def compat_is_legacy_shard_map() -> bool:
-    """True on toolchains without `jax.shard_map` (the experimental
-    fallback runs instead).  Two surfaces are UNSUPPORTED there and must
-    refuse loudly rather than misbehave: gradients THROUGH a psum inside
-    the mapped body (the old API's transpose is wrong without the
-    replication tracking pcast feeds — sequence-parallel training), and
-    the MoE pipeline schedule (its scalar balance output trips the old
-    spec checker at trace time)."""
-    return getattr(jax, "shard_map", None) is None
-
-
-def compat_axis_size(axis_name):
-    """`jax.lax.axis_size` where available (a STATIC python int —
-    callers build ppermute tables from it); older jax reads the same
-    static size off the tracing-time axis env (private API, guarded —
-    the traced psum-of-ones fallback serves only callers that never
-    need a concrete int)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    try:
-        from jax._src.core import get_axis_env
-        return get_axis_env().axis_size(axis_name)
-    except (ImportError, AttributeError):
-        import jax.numpy as _jnp
-        return jax.lax.psum(_jnp.int32(1), axis_name)
-
-
-def compat_pcast_varying(x, axes):
-    """`jax.lax.pcast(..., to="varying")` marks replicated args
-    device-varying for the new shard_map's VMA checker; older jax has
-    no VMA tracking (and no pcast) — identity there."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")
-
-
 def train_cohort(local_train, params: Pytree, data: CohortData,
                  rng: jax.Array, index_offset=0, transform_update=None,
                  client_axis: str = "vmap"):
@@ -186,8 +132,9 @@ def make_cohort_step(local_train, mesh: Optional[Mesh] = None,
         # runs per-device: cohort_data leaves are the local shard [C/D, ...]
         # params/rng arrive replicated (unvarying); mark them device-varying so
         # the local-train scan carry (which mixes in varying data) typechecks
-        global_params = compat_pcast_varying(global_params, ("clients",))
-        rng = compat_pcast_varying(rng, ("clients",))
+        global_params = jax.lax.pcast(global_params, ("clients",),
+                                      to="varying")
+        rng = jax.lax.pcast(rng, ("clients",), to="varying")
         local_c = cohort_data["num_samples"].shape[0]
         offset = jax.lax.axis_index("clients") * local_c
         stacked, metrics = _train_cohort(global_params, cohort_data, rng, offset)
@@ -203,7 +150,7 @@ def make_cohort_step(local_train, mesh: Optional[Mesh] = None,
         return new_global, metrics
 
     data_spec = P("clients")
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         _sharded, mesh=mesh,
         in_specs=(P(), data_spec, P()),
         out_specs=(P(), data_spec))
@@ -368,7 +315,7 @@ def make_sharded_stateful_round(core, mesh: Mesh, in_specs, out_specs):
             is_leaf=lambda s: isinstance(s, P))
     else:
         eff_out = out_specs
-    fn = jax.jit(compat_shard_map(per_device, mesh=mesh, in_specs=in_specs,
+    fn = jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                                   out_specs=eff_out, check_vma=False))
     if not multiproc:
         return fn
@@ -412,7 +359,7 @@ def cohort_eval(evaluate, mesh: Optional[Mesh] = None):
         local = _eval_cohort(params, data)
         return jax.tree.map(lambda x: jax.lax.psum(x, "clients"), local)
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         _sharded, mesh=mesh, in_specs=(P(), P("clients")), out_specs=P())
     n_dev = mesh.shape["clients"]
 

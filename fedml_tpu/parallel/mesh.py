@@ -77,6 +77,18 @@ def make_two_level_mesh(group_axis: int, client_axis: Optional[int] = None,
     return Mesh(arr, ("groups", "clients"))
 
 
+def placement_of(tree: Any) -> dict:
+    """Which devices hold a pytree's leaves, for a run's summary line:
+    ``{"platform": "tpu", "devices": 4}``; ``"host"`` / 0 when any leaf
+    is not a `jax.Array` — "tpu" means EVERY leaf is on a TPU."""
+    leaves = jax.tree.leaves(tree)
+    if not leaves or not all(isinstance(x, jax.Array) for x in leaves):
+        return {"platform": "host", "devices": 0}
+    devs = set().union(*(x.devices() for x in leaves))
+    return {"platform": "+".join(sorted({d.platform for d in devs})),
+            "devices": len(devs)}
+
+
 def make_model_mesh(num_shards: int,
                     devices: Optional[Sequence[jax.Device]] = None
                     ) -> Optional[Mesh]:
@@ -85,7 +97,8 @@ def make_model_mesh(num_shards: int,
     round state lives on its own device of the ``model`` axis.  Returns
     None when fewer than ``num_shards`` devices exist — the spine then
     runs placement-free on the default device (same math, no per-device
-    memory split), which is the honest posture on a 1-chip host."""
+    memory split), which is the honest posture on a 1-chip host; the
+    run's summary says so (``shard_state_devices``)."""
     devices = list(devices if devices is not None else jax.devices())
     if num_shards < 1:
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")

@@ -249,21 +249,7 @@ class PipelineLM:
             m_mb = (self._pad_mask(toks).reshape(n_micro, b // n_micro, t)
                     if moe else jnp.zeros((0,), jnp.float32))
 
-            from fedml_tpu.parallel.cohort import (
-                compat_is_legacy_shard_map, compat_pcast_varying,
-                compat_shard_map)
-            if moe and compat_is_legacy_shard_map():
-                # the scalar balance-loss output trips the legacy spec
-                # checker at trace time with an opaque _SpecError —
-                # name the real requirement instead
-                raise RuntimeError(
-                    "the MoE pipeline schedule (--mesh_stages + "
-                    "--moe_experts) requires a jax with jax.shard_map; "
-                    "the legacy experimental shard_map rejects its "
-                    "balance-loss carry — upgrade jax or drop "
-                    "--moe_experts (the dense pipeline runs everywhere)")
-
-            @partial(compat_shard_map, mesh=mesh,
+            @partial(jax.shard_map, mesh=mesh,
                      in_specs=(P("stages"), P(), P()),
                      out_specs=(P(), P()))
             def pipeline(blocks_sharded, xm, mm):
@@ -302,10 +288,10 @@ class PipelineLM:
                 # pattern as cohort.py's sharded path)
                 msk0 = (jnp.zeros_like(mm[0]) if moe
                         else jnp.zeros((0,), jnp.float32))
-                init = compat_pcast_varying(
+                init = jax.lax.pcast(
                     (jnp.zeros_like(xm[0]), msk0,
                      jnp.zeros_like(xm), jnp.float32(0.0)),
-                    ("stages",))
+                    ("stages",), to="varying")
                 (_, _, out, bal), _ = jax.lax.scan(
                     step, init, jnp.arange(n_micro + n_stages - 1))
                 # only the last stage holds real outputs; psum replicates

@@ -151,8 +151,7 @@ def make_sequence_parallel_apply(model, mesh: Mesh,
         return model.apply({"params": params}, x, positions=positions,
                            ring_axis=axis_name)
 
-    from fedml_tpu.parallel.cohort import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         _apply, mesh=mesh,
         in_specs=(P(), P(None, axis_name)),
         out_specs=P(None, axis_name))

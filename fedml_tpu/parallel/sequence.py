@@ -35,8 +35,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fedml_tpu.parallel.cohort import (compat_pcast_varying,
-                                       compat_shard_map, train_cohort)
+from fedml_tpu.parallel.cohort import train_cohort
 from fedml_tpu.trainer.local_sgd import make_local_trainer
 from fedml_tpu.trainer.workload import Workload
 
@@ -124,19 +123,6 @@ def make_sp_cohort_step(workload: Workload,
     fully-replicated out_spec (same trick as the two-level hierarchical
     mesh, algorithms/hierarchical.py).
     """
-    from fedml_tpu.parallel.cohort import compat_is_legacy_shard_map
-    if compat_is_legacy_shard_map():
-        # fail-loud, not train-wrong: grad_reduce psums INSIDE the
-        # mapped backward pass, and the legacy experimental shard_map
-        # transposes that psum incorrectly without the replication
-        # tracking pcast feeds — observed 3.4e-3 param drift vs the
-        # dense oracle, i.e. silently wrong training
-        raise RuntimeError(
-            "sequence-parallel training (make_sp_cohort_step) requires "
-            "a jax with jax.shard_map: the legacy experimental "
-            "shard_map mis-transposes the gradient psum and trains "
-            "silently wrong — upgrade jax (single-chip and "
-            "--attn_block_size paths work everywhere)")
     local_train = make_local_trainer(
         workload, optimizer, epochs,
         grad_reduce=lambda g: jax.lax.psum(g, axis_name))
@@ -144,8 +130,9 @@ def make_sp_cohort_step(workload: Workload,
     n_seq = mesh.shape[axis_name]
 
     def _sharded(params, data, rng):
-        params = compat_pcast_varying(params, ("clients", axis_name))
-        rng = compat_pcast_varying(rng, ("clients", axis_name))
+        params = jax.lax.pcast(params, ("clients", axis_name),
+                               to="varying")
+        rng = jax.lax.pcast(rng, ("clients", axis_name), to="varying")
         local_c = data["num_samples"].shape[0]
         offset = jax.lax.axis_index("clients") * local_c
         stacked, metrics = train_cohort(local_train, params, data, rng,
@@ -169,9 +156,9 @@ def make_sp_cohort_step(workload: Workload,
                  "y": P("clients", None, None, axis_name),
                  "mask": P("clients"),
                  "num_samples": P("clients")}
-    sharded = compat_shard_map(_sharded, mesh=mesh,
-                               in_specs=(P(), data_spec, P()),
-                               out_specs=(P(), P("clients")))
+    sharded = jax.shard_map(_sharded, mesh=mesh,
+                            in_specs=(P(), data_spec, P()),
+                            out_specs=(P(), P("clients")))
 
     @jax.jit
     def step(params, cohort_data, rng):

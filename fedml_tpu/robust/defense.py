@@ -42,8 +42,8 @@ def make_defended_aggregate(method: str = "mean", *, trim_frac: float = 0.1,
                             byz_f: int = 0, krum_m: int = 1,
                             gm_iters: int = 8, gm_eps: float = 1e-6,
                             norm_clip: float = 0.0, noise_std: float = 0.0,
-                            seed: int = 0, donate="auto",
-                            sentry=None, device=None) -> Callable:
+                            seed: int = 0, sentry=None,
+                            device=None) -> Callable:
     """Build the jitted ``fn(global_params, stacked, weights, step) ->
     new_params`` the server actors call once per round/version.
 
@@ -56,15 +56,8 @@ def make_defended_aggregate(method: str = "mean", *, trim_frac: float = 0.1,
     single jit — tests pin ``fn._cache_size() == 1`` after a full run
     (no per-round recompiles, the acceptance criterion).
 
-    ``donate``: donate the ``stacked`` cohort argument's device buffer to
-    XLA — the round's H2D transfer of the staged cohort is reused for the
-    aggregation's temporaries instead of allocating a second model-sized
-    HBM block every round.  The host staging buffer itself is unaffected
-    (a numpy argument is copied to the device before donation applies).
-    ``"auto"`` enables it off-CPU only: CPU backends warn-and-ignore
-    donation on every call, and the sync/async servers both pass numpy
-    cohorts, so there is nothing to reuse there anyway.  Donation never
-    adds a trace — the jit-once pin holds with it on or off.
+    Nothing is donated: XLA honours a donation only by aliasing it to
+    an output of the same shape, and none has the ``[N, ...]`` shape.
 
     ``sentry``: a `fedml_tpu.obs.perf.RecompileSentry`; when set, the
     returned jit registers itself, so the flight recorder counts (and
@@ -139,9 +132,7 @@ def make_defended_aggregate(method: str = "mean", *, trim_frac: float = 0.1,
             out = add_gaussian_noise(out, key, noise_std)
         return out
 
-    if donate == "auto":
-        donate = jax.default_backend() != "cpu"
-    fn = jax.jit(_aggregate, donate_argnums=(1,) if donate else ())
+    fn = jax.jit(_aggregate)
     if sentry is not None:
         sentry.register(f"defended_aggregate[{method}]", fn)
     if device is not None:

@@ -34,7 +34,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+# murmur3's finalizer, shared with the aggregation kernels: a counter-based
+# PRG (hash(seed, index)) needs no sequential state, so the two ends of a
+# pair trivially generate identical streams
+from fedml_tpu.core.pallas_agg import _murmur_fmix
 
 Pytree = Any
 
@@ -55,18 +59,6 @@ def derive_pair_seeds(round_key: jax.Array, client_idx,
         return jax.random.key_data(key).astype(jnp.uint32)[:2].astype(
             jnp.int32)
     return jax.vmap(one)(jnp.arange(num_clients))
-
-
-def _murmur_fmix(x: jax.Array) -> jax.Array:
-    """murmur3's 32-bit finalizer — a full-avalanche uint32 hash on the VPU
-    (counter-based PRG: hash(seed, index) needs no sequential state, so the
-    two ends of a pair trivially generate identical streams)."""
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    return x
 
 
 def _mask_kernel(seeds_ref, signs_ref, x_ref, o_ref, *, num_clients,

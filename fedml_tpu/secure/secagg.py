@@ -160,6 +160,9 @@ class SecureCohortAggregator:
         self.scale = scale
         self.clip = clip
         self.backend = backend
+        if backend == "pallas":
+            from fedml_tpu.core.pallas_agg import pallas_interpret
+            self._interpret = pallas_interpret("secagg_mask")
 
     def mask_update(self, update: Pytree, weight, client_idx,
                     round_key: jax.Array) -> Pytree:
@@ -175,8 +178,7 @@ class SecureCohortAggregator:
             from fedml_tpu.secure.pallas_mask import fused_quantize_mask
             return fused_quantize_mask(
                 update, weight, client_idx, round_key, self.num_clients,
-                self.scale, self.clip,
-                interpret=jax.default_backend() != "tpu")
+                self.scale, self.clip, interpret=self._interpret)
         weighted = jax.tree.map(
             lambda x: x * jnp.asarray(weight, x.dtype), update)
         q = quantize(weighted, self.scale, self.clip)
@@ -203,7 +205,13 @@ class SecureCohortAggregator:
         def per_client(c):
             upd = jax.tree.map(lambda x: x[c], updates)
             return self.mask_update(upd, w_norm[c], c, round_key)
-        masked = jax.vmap(per_client)(jnp.arange(self.num_clients))
+        clients = jnp.arange(self.num_clients)
+        if self.backend == "pallas":
+            # Mosaic refuses to block the kernel's SMEM seeds/signs over
+            # a vmapped client axis — walk the clients instead
+            masked = jax.lax.map(per_client, clients)
+        else:
+            masked = jax.vmap(per_client)(clients)
         summed = jax.tree.map(lambda x: jnp.sum(x, axis=0, dtype=jnp.uint32),
                               masked)
         return self.unmask_sum(summed, 1.0)

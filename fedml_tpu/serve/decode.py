@@ -173,10 +173,7 @@ class DecodeScheduler:
 
         # ONE jit entry for the scheduler's lifetime: static [slots]
         # shapes, donated cache.  _cache_size is the sentry probe.
-        # Donation is auto-off on CPU (the backend ignores it with a
-        # warning — the make_defended_aggregate convention).
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        self._step_jit = jax.jit(_step, donate_argnums=donate)
+        self._step_jit = jax.jit(_step, donate_argnums=(1,))
         self._step_fn = self._step_jit   # obs instrumentation wraps this
 
         reg = telemetry.get_registry()

@@ -112,17 +112,32 @@ def _divergence(y_live: np.ndarray, y_canary: np.ndarray) -> float:
     slice.  Classification heads ([N, C], C > 1) compare argmax — the
     user-visible prediction; anything else compares values within a
     relative tolerance (regression outputs drift a little every honest
-    round; a poisoned model blows far past it)."""
-    if y_live.ndim >= 2 and y_live.shape[-1] > 1:
-        a = np.argmax(y_live.reshape(y_live.shape[0], -1), axis=-1)
-        b = np.argmax(y_canary.reshape(y_canary.shape[0], -1), axis=-1)
-        return float(np.mean(a != b))
+    round; a poisoned model blows far past it).
+
+    An argmax flip counts only when at least one of the two models
+    separates the two classes by more than that same tolerance: a row
+    both score as a tie (an all-zero request sees the bias alone) has no
+    prediction to change — which class wins it is last-ulp arithmetic, and
+    identical such rows flip together.  A non-finite canary row always
+    counts."""
     flat_l = y_live.reshape(y_live.shape[0], -1).astype(np.float64)
     flat_c = y_canary.reshape(y_canary.shape[0], -1).astype(np.float64)
+    bad = ~np.all(np.isfinite(flat_c), axis=-1)
+    if y_live.ndim >= 2 and y_live.shape[-1] > 1:
+        rows = np.arange(flat_l.shape[0])
+        a = np.argmax(flat_l, axis=-1)
+        b = np.argmax(flat_c, axis=-1)
+        with np.errstate(invalid="ignore"):
+            decided = (
+                (flat_l[rows, a] - flat_l[rows, b]
+                 > 1e-3 * (1.0 + np.abs(flat_l[rows, a])))
+                | (flat_c[rows, b] - flat_c[rows, a]
+                   > 1e-3 * (1.0 + np.abs(flat_c[rows, b]))))
+        return float(np.mean(bad | ((a != b) & decided)))
     tol = 1e-3 * (1.0 + np.abs(flat_l))
-    row_diff = np.any(~np.isfinite(flat_c) | (np.abs(flat_l - flat_c)
-                                              > tol), axis=-1)
-    return float(np.mean(row_diff))
+    with np.errstate(invalid="ignore"):
+        row_diff = np.any(np.abs(flat_l - flat_c) > tol, axis=-1)
+    return float(np.mean(bad | row_diff))
 
 
 class ReleaseController:

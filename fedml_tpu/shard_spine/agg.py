@@ -30,7 +30,8 @@ Fold math (the parity contract tests/test_shard_spine.py pins):
 Finalize backends: ``fused=False`` is the XLA compose (division + noise
 per shard); ``fused=True`` wires `core.pallas_agg.make_fused_shard_finalize`
 — clip(at fold) + weighted mean + weak-DP noise complete as ONE Pallas
-kernel launch per shard, ``interpret=True`` on CPU.  sigma=0 fused is
+kernel launch per shard (compiled on a TPU, interpreted on the CPU:
+`core.pallas_agg.pallas_interpret`).  sigma=0 fused is
 bit-identical to the XLA compose for f32 models (same elementwise f32
 division); the kernels register with the device observatory so the
 compile ledger names them and the MFU gauge finally measures an
@@ -76,7 +77,7 @@ class ShardedStreamingAggregator:
 
     def __init__(self, plan: ShardPlan, template, *, kind: str = "params",
                  norm_clip: float = 0.0, noise_std: float = 0.0,
-                 seed: int = 0, donate="auto", fused: bool = False,
+                 seed: int = 0, fused: bool = False,
                  interpret: Optional[bool] = None, mesh=None,
                  sentry=None, device=None):
         if kind != "params":
@@ -94,16 +95,14 @@ class ShardedStreamingAggregator:
         self.noise_std = float(noise_std)
         self.seed = int(seed)
         self.fused = bool(fused)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        if fused and interpret is None:
+            from fedml_tpu.core.pallas_agg import pallas_interpret
+            interpret = pallas_interpret("shard_finalize")
         self.interpret = bool(interpret)
         self.defended = norm_clip > 0 or noise_std > 0
         self._treedef = jax.tree.structure(template)
         self._devices = plan.shard_devices(mesh) if mesh is not None \
             else None
-        if donate == "auto":
-            donate = jax.default_backend() != "cpu"
-        self._donate = bool(donate)
 
         S = plan.num_shards
         self._weight_flags = [plan.slice_weight_flags(s) for s in range(S)]
@@ -119,13 +118,11 @@ class ShardedStreamingAggregator:
                                 if norm_clip > 0 else None)
         self._scale_fn = jax.jit(self._combine_scale) if norm_clip > 0 \
             else None
-        self._wadd_fn = jax.jit(
-            lambda ws, w: ws + w,
-            donate_argnums=(0,) if self._donate else ())
+        self._wadd_fn = jax.jit(lambda ws, w: ws + w, donate_argnums=(0,))
         self._wadd_wave_fn = jax.jit(
             lambda ws, w: jax.lax.scan(
                 lambda c, wi: (c + wi, None), ws, w)[0],
-            donate_argnums=(0,) if self._donate else ())
+            donate_argnums=(0,))
         if fused:
             from fedml_tpu.core.pallas_agg import make_fused_shard_finalize
             self._finalize_fns = [
@@ -187,8 +184,7 @@ class ShardedStreamingAggregator:
                 out[k] = a + u.astype(a.dtype) * weight.astype(a.dtype)
             return out
 
-        return jax.jit(_fold,
-                       donate_argnums=(0,) if self._donate else ())
+        return jax.jit(_fold, donate_argnums=(0,))
 
     def _make_fold_wave(self, shard: int):
         flags = self._weight_flags[shard]
@@ -208,8 +204,7 @@ class ShardedStreamingAggregator:
             acc, _ = jax.lax.scan(body, acc, (stacked, weights, scales))
             return acc
 
-        return jax.jit(_fold_wave,
-                       donate_argnums=(0,) if self._donate else ())
+        return jax.jit(_fold_wave, donate_argnums=(0,))
 
     @staticmethod
     def _slice_sumsq(upload, reference, flags):
@@ -281,6 +276,12 @@ class ShardedStreamingAggregator:
     @property
     def reference(self):
         return self._reference
+
+    def shard_device(self, shard: int):
+        """The device shard ``shard``'s fold state is committed to, or
+        None when the host has fewer devices than shards and everything
+        shares the default device."""
+        return None if self._devices is None else self._devices[shard]
 
     def _place(self, shard: int, slice_body: dict) -> dict:
         """Commit one shard's pieces to its device (consistent committed

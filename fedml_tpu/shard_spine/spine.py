@@ -116,9 +116,9 @@ def build_shard_spine(template, *, num_shards: int,
     """Build the spine from the live template.
 
     ``fused``: ``"on"`` wires the Pallas finalize unconditionally
-    (``interpret=True`` off-TPU — the parity/proof mode); ``"auto"``
-    compiles it on TPU and keeps the XLA compose on CPU (an interpreted
-    kernel is a correctness tool, not a speedup — the honest default);
+    (interpreted on the CPU — the parity/proof mode); ``"auto"`` wires it
+    on a TPU, where Mosaic compiles it, and keeps the XLA compose on the
+    CPU (an interpreted kernel is a correctness tool, not a speedup);
     ``"off"`` keeps the XLA compose everywhere.
 
     ``mesh="auto"``: build a ``[1, S]`` model mesh when the host has at
@@ -128,9 +128,8 @@ def build_shard_spine(template, *, num_shards: int,
     if fused not in ("auto", "on", "off"):
         raise ValueError(f"fused must be auto|on|off, got {fused!r}")
     import jax
-    backend = jax.default_backend()
-    use_fused = fused == "on" or (fused == "auto" and backend == "tpu")
-    interpret = backend != "tpu"
+    use_fused = fused == "on" or (fused == "auto"
+                                  and jax.default_backend() == "tpu")
     if mesh == "auto":
         from fedml_tpu.parallel.mesh import make_model_mesh
         mesh = make_model_mesh(num_shards)
@@ -143,8 +142,8 @@ def build_shard_spine(template, *, num_shards: int,
                             min_split_elems=min_split_elems)
     agg = ShardedStreamingAggregator(
         plan, template, norm_clip=norm_clip, noise_std=noise_std,
-        seed=seed, fused=use_fused, interpret=interpret, mesh=mesh,
-        sentry=sentry, device=device)
+        seed=seed, fused=use_fused, mesh=mesh, sentry=sentry,
+        device=device)
     admission = None
     if admission_on:
         admission = ShardAdmission(

@@ -13,8 +13,8 @@ cd "$(dirname "$0")/.."
 
 # encode-once wire path under faults: the smoke bench drives a real
 # federation through dup/reorder/corrupt chaos with the admission screen
-# armed.  Smoke output goes to /tmp — the committed BENCH_wire.json is
-# the FULL bench's artifact and must not be overwritten by smoke numbers.
+# armed.  Smoke output goes to /tmp — BENCH_wire.json is a FULL run's
+# artifact and must not be overwritten by smoke numbers.
 env JAX_PLATFORMS=cpu python scripts/wire_bench.py --smoke \
     --out /tmp/BENCH_wire_smoke.json
 

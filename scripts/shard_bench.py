@@ -231,6 +231,8 @@ def _run_live(run_dir: str, rounds: int, smoke: bool) -> dict:
             "fused_finalize_compiles": fused_fns,
             "mfu_values": mfus,
             "mfu_max": max(mfus) if mfus else None,
+            "flops_measured": any((r.get("device") or {}).get("flops")
+                                  for r in rows),
             "backend": rows[0]["device"]["backend"],
             "ledger_lines": rows}
 
@@ -309,9 +311,12 @@ def main() -> int:
     if not live["fused_finalize_compiles"]:
         failures.append("compile ledger never named the fused finalize "
                         "kernel")
-    if live["mfu_max"] is None:
-        failures.append("MFU gauge null on every ledger line")
-    elif live["mfu_max"] > 1.0:
+    # this script pins the CPU, which has no peak and so no MFU (null by
+    # contract, obs/device.py); what it CAN gate is that the fused
+    # kernel's cost-analysis FLOPs reached the ledger
+    if not live["flops_measured"]:
+        failures.append("device FLOPs null on every ledger line")
+    elif live["mfu_max"] is not None and live["mfu_max"] > 1.0:
         failures.append(f"mfu {live['mfu_max']} > 1.0 — timing "
                         f"untrusted")
 

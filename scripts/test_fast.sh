@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# One-command non-slow test tier for the driver (VERDICT r02 #7).
+# One-command non-slow test tier for the driver.
 #
 # pytest-xdist shards across workers; --dist loadfile keeps each test file
 # on one worker (transport tests bind fixed ports and share module

@@ -34,7 +34,7 @@ def main() -> int:
                     help="details artifact path ('' to skip writing); "
                          "default BENCH_wire.json for full runs, a /tmp "
                          "path for --smoke so CI-sized numbers can never "
-                         "clobber the committed full-bench artifact")
+                         "clobber a full run's artifact")
     args = ap.parse_args()
     if args.out is None:
         args.out = ("/tmp/BENCH_wire_smoke.json" if args.smoke

@@ -1,7 +1,7 @@
 """Straggler CHAOS via the first-class injection layer (comm/chaos.py):
 seeded drops, delays, duplicates, and partitions against the cross-silo
 drop policy and the async (FedBuff) server — liveness and progress must
-survive every seed (VERDICT r3 item 7).
+survive every seed.
 
 The reference's only straggler story is a barrier that hangs until
 MPI.Abort (FedAvgServerManager.py:51, server_manager.py:64); these tests

@@ -106,7 +106,7 @@ def test_reference_curve_reader_parses_published_cifar10():
 def test_noniid_cifar_twin_learning_curve_shape():
     """A non-IID (Dirichlet-partitioned) CIFAR run whose accuracy series
     must show the same qualitative shape as the published reference curve
-    (rising tail; VERDICT round-1 item 4). Small CNN stands in for resnet56
+    (rising tail). Small CNN stands in for resnet56
     so the run fits CPU; the partition/augment path is the real one."""
     import jax
     import flax.linen as nn

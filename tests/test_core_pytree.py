@@ -61,7 +61,7 @@ def test_global_norm(rng):
 def test_psum_mean_matches_local_mean(rng, devices):
     """Distributed weighted mean over an 8-device mesh == the list version."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from fedml_tpu.parallel.cohort import compat_shard_map as shard_map
+    from jax import shard_map
 
     trees = [_random_tree(rng) for _ in range(8)]
     ns = np.array([5., 1., 2., 8., 3., 4., 6., 7.], np.float32)

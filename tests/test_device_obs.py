@@ -54,19 +54,34 @@ def test_bench_delegates_peak_and_flops_by_identity():
 
 
 class _FakeDev:
-    def __init__(self, kind):
+    def __init__(self, kind, platform="tpu"):
         self.device_kind = kind
+        self.platform = platform
 
 
 def test_peak_table_kind_match_and_env_override(monkeypatch):
     monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
     assert peak_tflops_for_device(_FakeDev("TPU v5 lite")) == 197.0
     assert peak_tflops_for_device(_FakeDev("TPU v4")) == 275.0
-    assert peak_tflops_for_device(None) == device_obs.DEFAULT_PEAK_TFLOPS
-    assert "no entry" in device_obs.peak_source_for_device(_FakeDev("cpu"))
     monkeypatch.setenv("BENCH_PEAK_TFLOPS", "42.5")
     assert peak_tflops_for_device(_FakeDev("TPU v4")) == 42.5
-    assert "env override" in device_obs.peak_source_for_device(None)
+    assert "env override" in device_obs.peak_and_source(None)[1]
+
+
+def test_no_default_peak(monkeypatch):
+    """The module's "null, never 0" contract, applied to the peak: the
+    CPU backend has NO peak (so no MFU), and an accelerator whose kind
+    the table lacks is an error — never the v5e number by default (how
+    BENCH_device.json came to report an "MFU" on a CPU)."""
+    monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
+    assert not hasattr(device_obs, "DEFAULT_PEAK_TFLOPS")
+    cpu = _FakeDev("cpu", platform="cpu")
+    assert peak_tflops_for_device(cpu) is None
+    assert "cpu backend" in device_obs.peak_and_source(cpu)[1]
+    for dev in (_FakeDev("mystery accelerator"), _FakeDev("TPU v9000"),
+                None):
+        with pytest.raises(ValueError, match="device_kind"):
+            peak_tflops_for_device(dev)
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +147,7 @@ def test_memory_snapshot_absent_backend_is_null_never_zero(monkeypatch):
 # compile ledger + flops + MFU on a real jit
 # ---------------------------------------------------------------------------
 
-def test_instrument_compile_ledger_flops_and_cpu_mfu_leq_one():
+def test_instrument_compile_ledger_flops_and_cpu_mfu_null():
     import jax
     import jax.numpy as jnp
     reg = _reg()
@@ -153,17 +168,13 @@ def test_instrument_compile_ledger_flops_and_cpu_mfu_leq_one():
     # XLA cost analysis: a [16,16] matmul is 2*16^3 flops per call
     assert section["flops"] == pytest.approx(3 * 2 * 16 ** 3, rel=0.5)
     assert section["flops_complete"] is True
-    # honest MFU on the CPU backend: the shared table has no CPU entry,
-    # so the denominator is the conservative accelerator-class default —
-    # an upper bound no host CPU reaches, hence <= 1.0 by construction
+    # the CPU backend has no accelerator peak: achieved FLOP/s is
+    # measured, but peak and MFU ledger null with the reason — never a
+    # ratio against an assumed chip
     assert section["backend"] == "cpu"
-    assert 0.0 < section["mfu"] <= 1.0
-    # the denominator scales by local device count: the numerator sums
-    # programs across all local devices, so a sharded run honestly
-    # beating one chip's peak must not read "physically impossible"
-    import jax
-    assert section["peak_tflops"] == pytest.approx(
-        peak_tflops_for_device(None) * len(jax.local_devices()))
+    assert section["achieved_flops_per_s"] > 0
+    assert section["mfu"] is None and section["peak_tflops"] is None
+    assert "cpu backend" in section["peak_source"]
     assert section["mfu_provenance"] == device_obs.MFU_PROVENANCE
     # later rounds: cache hit, no new compile entries
     rec.round_start()
@@ -173,7 +184,16 @@ def test_instrument_compile_ledger_flops_and_cpu_mfu_leq_one():
     assert section2["jit_calls"] == {"probe": 1}
     snap = reg.snapshot()
     assert snap["counters"]['fedml_dev_compiles_total{fn="probe"}'] == 1
-    assert 0.0 < snap["gauges"]["fedml_perf_mfu_ratio"] <= 1.0
+    # no peak => the MFU gauge is never registered (absent, not 0)
+    assert snap["gauges"]["fedml_dev_achieved_flops_value"] > 0
+    assert "fedml_perf_mfu_ratio" not in snap["gauges"]
+    # an explicit peak (a caller that knows its chip) does gauge an MFU
+    rec2 = DeviceRecorder(registry=reg, peak_tflops=1000.0)
+    g = rec2.instrument("probe2", jax.jit(lambda a: a @ a))
+    rec2.round_start()
+    g(x)
+    assert 0.0 < rec2.round_snapshot(round_s=0.01)["mfu"] <= 1.0
+    assert 0.0 < reg.snapshot()["gauges"]["fedml_perf_mfu_ratio"] <= 1.0
 
 
 def test_instrument_forwards_cache_probe_and_unmeasured_is_null():

@@ -262,7 +262,7 @@ def test_two_process_four_device_hierarchical_round(tmp_path):
     full hierarchical round — 2 group-local FedAvg rounds + global
     weighted psum across processes — must match the single-process
     vmapped simulation leaf-for-leaf and agree bit-identically between
-    the processes (VERDICT r3 item 8)."""
+    the processes."""
     script = tmp_path / "worker2.py"
     script.write_text(_WORKER_2LEVEL.format(repo=REPO))
     port = _free_port()

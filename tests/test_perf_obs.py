@@ -313,7 +313,7 @@ def test_mfu_lint_retraction_markers_are_sticky_downward(tmp_path):
     ok.write_text(json.dumps({
         "cohort_scaling": {"128": {
             "mfu": 1.57,
-            "mfu_retracted": "timing retracted, see ROUND_NOTES"}},
+            "mfu_retracted": "timing retracted"}},
         "quarantined": {"timing_untrusted": "broken timer",
                         "nested": [{"mfu": 3.08}]},
         "configs": {"a": {"mfu": 0.9}}}))

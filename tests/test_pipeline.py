@@ -126,13 +126,6 @@ def test_pp_workload_local_training_matches_sequential(setup, devices):
     assert abs(float(m_seq["correct"]) - float(m_pp["correct"])) <= 2
 
 
-_NEEDS_NEW_SHARD_MAP = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="the MoE pipeline schedule requires jax.shard_map (the "
-           "legacy fallback rejects its balance-loss carry; PipelineLM "
-           "refuses loudly there)")
-
-
 @pytest.fixture(scope="module")
 def moe_setup():
     lm = PipelineLM(vocab_size=32, d_model=32, n_heads=2, n_layers=4,
@@ -146,7 +139,6 @@ def moe_setup():
 
 
 @pytest.mark.parametrize("n_stages,n_micro", [(4, 4), (2, 8)])
-@_NEEDS_NEW_SHARD_MAP
 def test_pp_moe_forward_and_balance_match_sequential(moe_setup, devices,
                                                      n_stages, n_micro):
     """ep x pp: the Switch-MoE block stack pipelined over stages must
@@ -166,7 +158,6 @@ def test_pp_moe_forward_and_balance_match_sequential(moe_setup, devices,
     assert float(bal_pp) > 0.0  # real routing pressure, not a dropped sow
 
 
-@_NEEDS_NEW_SHARD_MAP
 def test_pp_moe_gradients_carry_balance_loss(moe_setup, devices):
     """The balance term must flow into the ROUTER's gradient through the
     pipeline: d(loss)/d(router) equals the sequential twin's, and is
@@ -197,7 +188,6 @@ def test_pp_moe_gradients_carry_balance_loss(moe_setup, devices):
     assert float(np.abs(router_g).max()) > 0.0
 
 
-@_NEEDS_NEW_SHARD_MAP
 def test_pp_moe_workload_local_training_matches_sequential(moe_setup,
                                                            devices):
     """The MoE pipeline rides the standard Workload/local-trainer seam,

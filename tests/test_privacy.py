@@ -102,8 +102,8 @@ def test_fixed_size_wor_q1_is_replace_one_gaussian():
 
 
 def test_fixed_size_wor_pins_against_poisson_approximation():
-    """VERDICT r4 item 7: the fixed-size bound APPLIES to the sampler
-    dp_fedavg actually uses and must be CONSERVATIVE relative to the
+    """The fixed-size bound APPLIES to the sampler dp_fedavg actually
+    uses and must be CONSERVATIVE relative to the
     Poisson approximation at the same (q, z) — never optimistic.  Both
     stay finite and positive, and the WOR bound never exceeds its own
     unsubsampled replace-one clamp."""

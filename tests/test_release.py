@@ -194,6 +194,19 @@ class TestDivergence:
         assert _divergence(y1, y1) == 0.0
         assert _divergence(y1, y2) == 0.25
 
+    def test_tie_flips_are_not_divergence(self):
+        """A row both models score as a tie (the bias alone, ~1e-3 apart)
+        has no prediction to change: its argmax is last-ulp arithmetic.
+        One model deciding the pair is enough to count the flip."""
+        live = np.tile(np.float32([5e-4, 1e-3, 0, 0]), (4, 1))
+        canary = np.tile(np.float32([1e-3, 5e-4, 0, 0]), (4, 1))
+        assert live.argmax(-1)[0] != canary.argmax(-1)[0]
+        assert _divergence(live, canary) == 0.0
+        canary[0] = [3.0, 0, 0, 0]        # the canary now DECIDES row 0
+        assert _divergence(live, canary) == 0.25
+        canary[1] = np.nan                # NaN argmax is 0: still counts
+        assert _divergence(live, canary) == 0.5
+
     def test_scalar_outputs_use_relative_tolerance(self):
         y1 = np.ones((8, 1), np.float32) * 100
         assert _divergence(y1, y1 * (1 + 1e-6)) == 0.0
@@ -597,16 +610,20 @@ def test_poisoned_round_rolled_back_before_serving():
     publishes every round through the gate with real shadow traffic;
     the seeded poisoned round's version must never reach the live slot,
     and the clean rounds around it must promote.  (Clean rounds move
-    ~1.6% of shadow argmaxes on this seed; the scale:1e6 poison moves
-    ~97% — the 0.1 budget separates them with margin either way.)"""
+    none of the 64 shadow argmaxes on this seed; the scale:1e6 poison
+    moves 94% — the 0.1 budget separates them with margin either way.)"""
     data, wl, CrossDevice, cfg = _cross_device_fixture(
         comm_round=4, wave_adversary="3:0:scale:1000000",
         admission="off")
     apply_fn = jax.jit(lambda p, x: wl.apply(p, x))
     reg = ModelRegistry(apply_fn, history=8)
     shadow = ShadowSampler(every=1, slots=64)
+    # shadow traffic is REAL requests: the stacked test split pads every
+    # client to a whole batch with all-zero rows (mask 0), which no user
+    # sends — and which every model scores as a tie (the bias alone)
     xt = np.asarray(data.test["x"])
-    for row in xt.reshape(-1, xt.shape[-1])[:64]:
+    real = np.asarray(data.test["mask"]).reshape(-1) > 0
+    for row in xt.reshape(-1, xt.shape[-1])[real][:64]:
         shadow.offer(row)
 
     rc = ReleaseController(reg, shadow=shadow, divergence_budget=0.1,

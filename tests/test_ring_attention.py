@@ -55,8 +55,7 @@ def test_ring_attention_matches_full(devices, causal):
     def _sharded(q, k, v, pos):
         return ring_attention(q, k, v, pos, pos, "sequence", causal=causal)
 
-    from fedml_tpu.parallel.cohort import compat_shard_map
-    fn = jax.jit(compat_shard_map(
+    fn = jax.jit(jax.shard_map(
         _sharded, mesh=mesh,
         in_specs=(P(None, "sequence"), P(None, "sequence"),
                   P(None, "sequence"), P("sequence")),
@@ -204,11 +203,6 @@ def test_transformer_is_causal():
     assert not np.allclose(out[0, 10:], out2[0, 10:])
 
 
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="sequence-parallel training requires jax.shard_map (the "
-           "legacy fallback mis-transposes the gradient psum; "
-           "make_sp_cohort_step refuses loudly there)")
 def test_sp_cohort_step_matches_dense_cohort(devices):
     """Federated long-context: the dp×sp [4 clients, 2 sequence] mesh round
     (ring attention + psum'd loss/grads within each client, weighted psum

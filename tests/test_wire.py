@@ -19,7 +19,6 @@ import json
 import logging
 import struct
 import threading
-import warnings
 
 import jax
 import numpy as np
@@ -496,17 +495,12 @@ class TestIncrementalStaging:
         assert s1.dropped_silos == s2.dropped_silos
         jax.tree.map(np.testing.assert_array_equal, seed_params, new_params)
 
-    def test_jit_once_pin_with_donation_and_staging(self):
-        """Acceptance: _cache_size() == 1 across rounds with donation ON
-        and incremental staging enabled."""
-        with warnings.catch_warnings():
-            # CPU backends warn that donation is unimplemented; the pin
-            # under test is the trace-cache size, which donation must not
-            # perturb on any backend
-            warnings.simplefilter("ignore")
-            fn = make_defended_aggregate("mean", norm_clip=5.0, donate=True)
-            _, server = _run_federation(encode_once=True, staging=True,
-                                        rounds=4, defended=fn)
+    def test_jit_once_pin_with_staging(self):
+        """Acceptance: _cache_size() == 1 across rounds with incremental
+        staging enabled."""
+        fn = make_defended_aggregate("mean", norm_clip=5.0)
+        _, server = _run_federation(encode_once=True, staging=True,
+                                    rounds=4, defended=fn)
         assert fn._cache_size() == 1
         assert server.round_idx == 4
 
