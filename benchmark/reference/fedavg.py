@@ -1,0 +1,243 @@
+"""Plain FedAvg (McMahan et al. 2017), the yardstick `correct` is read from.
+
+One client after another, one mini-batch after another, in float32 with
+its products at the precision the configuration states (``precision``; a
+cell's is ``default``, which on the TPU is one bfloat16 pass with float32
+sums: a reference at ``highest`` lies 0.4 % from every float32 run there,
+further than the bfloat16 control does, and so cannot tell them apart): no
+vmap over clients, no scan, no padding to the longest client, no waves, no
+streaming fold.  It imports
+nothing of ``fedml_tpu`` and takes nothing the program made; what it shares
+with the program is JAX's and flax's public random-number derivation, which
+is what lets it start from the same weights and drop the same units:
+
+  key            = jax.random.key(seed); key, init_key = split(key)
+  each round:      key, round_key = split(key)
+  cohort:          all clients when the cohort is the population, else
+                   numpy RandomState(round).choice(range(N), m, False)
+                   (FedML's published sampler)
+  client in slot j: ck = fold_in(round_key, j);
+  each step:       ck, dropout_key = split(ck)
+
+A client's local run (FedML ``MyModelTrainer``): E=1 pass over its rows in
+order in batches of B (the last one short), mean cross-entropy over the
+batch, gradient clipped to global norm 1, plain SGD.  The round's global is
+the mean of the clients' results weighted by their row counts.
+
+``fault`` plants one of the faults the benchmark's comparison has to catch,
+so that their readings can be taken from the reference put in the
+program's place.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+FAULTS = ("half_batch", "state_unchanged")
+
+
+def sample_cohort(round_idx: int, population: int, cohort: int) -> np.ndarray:
+    if population == cohort:
+        return np.arange(population, dtype=np.int64)
+    rng = np.random.RandomState(round_idx)
+    return rng.choice(range(population), min(cohort, population),
+                      replace=False)
+
+
+def _pad(x: np.ndarray, rows: int) -> np.ndarray:
+    if len(x) == rows:
+        return x
+    out = np.zeros((rows,) + x.shape[1:], x.dtype)
+    out[:len(x)] = x
+    return out
+
+
+def make_steps(model, lr: float, clip: float, dtype=None):
+    """(train_step, eval_batch, accumulate) for ``model``.  ``dtype``
+    computes the model in a lower precision: weights and inputs are cast
+    to it, the loss and the update stay float32."""
+
+    def logits_of(params, x, train, rng):
+        if dtype is not None:
+            params = jax.tree.map(lambda p: p.astype(dtype), params)
+            x = x.astype(dtype)
+        kw = {"rngs": {"dropout": rng}} if train else {}
+        return model.apply({"params": params}, x, train=train,
+                           **kw).astype(jnp.float32)
+
+    def ce(logits, y):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
+
+    def loss_fn(params, x, y, mask, rng):
+        return (jnp.sum(ce(logits_of(params, x, True, rng), y) * mask)
+                / jnp.maximum(jnp.sum(mask), 1.0))
+
+    @jax.jit
+    def train_step(params, x, y, mask, key):
+        key, dropout_key = jax.random.split(key)
+        grads = jax.grad(loss_fn)(params, x, y, mask, dropout_key)
+        norm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                            for g in jax.tree.leaves(grads)))
+        scale = jnp.where(norm < clip, 1.0, clip / norm)
+        params = jax.tree.map(lambda p, g: p - lr * scale * g, params, grads)
+        return params, key
+
+    @jax.jit
+    def eval_batch(params, x, y, mask):
+        return jnp.sum(ce(logits_of(params, x, False, None), y) * mask)
+
+    @jax.jit
+    def accumulate(acc, params, weight):
+        return jax.tree.map(lambda a, q: a + weight * q, acc, params)
+
+    return train_step, eval_batch, accumulate
+
+
+def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
+        seed: int, rounds: int, cohort: int, batch_size: int, lr: float,
+        epochs: int = 1, clip: float = 1.0, dtype=None,
+        fault: Optional[str] = None, eval_rows: int = 1000,
+        precision: str = "highest",
+        log: Callable[[str], None] = lambda s: None) -> dict:
+    """Follow ``rounds`` rounds from the seed.  Returns the globals
+    ``states[0..rounds]`` as host trees and ``loss_r0``, the mean
+    cross-entropy of ``states[1]`` over every client's training rows."""
+    if epochs != 1:
+        raise ValueError("the reference follows E=1 only (see PERF.md)")
+    if fault is not None and fault not in FAULTS:
+        raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
+    B = batch_size
+    with jax.default_matmul_precision(precision):
+        train_step, eval_batch, accumulate = make_steps(model, lr, clip,
+                                                        dtype)
+        key = jax.random.key(seed)
+        key, init_key = jax.random.split(key)
+        x0 = jnp.asarray(_pad(clients[0][0][:B], B))
+        params = model.init(init_key, x0)["params"]
+        states: List = [jax.tree.map(np.asarray, params)]
+        loss_r0 = None
+        ones = np.ones(B, np.float32)
+        for r in range(rounds):
+            key, round_key = jax.random.split(key)
+            ids = sample_cohort(r, len(clients), cohort)
+            acc = jax.tree.map(jnp.zeros_like, params)
+            total = 0.0
+            for slot, cid in enumerate(ids):
+                x, y = clients[int(cid)]
+                n = len(y)
+                if n == 0:
+                    continue
+                ck = jax.random.fold_in(round_key, slot)
+                p = params
+                for lo in range(0, n, B):
+                    xb, yb = x[lo:lo + B], y[lo:lo + B]
+                    m = ones if len(yb) == B else _pad(
+                        np.ones(len(yb), np.float32), B)
+                    if fault == "half_batch":
+                        # half of each batch left out, the mean taken
+                        # over the rest
+                        m = m * (np.arange(B) % 2 == 0)
+                    p, ck = train_step(p, _pad(xb, B), _pad(yb, B), m, ck)
+                acc = accumulate(acc, p, float(n))
+                total += float(n)
+            new = jax.tree.map(lambda a: a / total, acc)
+            if fault != "state_unchanged":
+                params = new
+            states.append(jax.tree.map(np.asarray, params))
+            log(f"reference round {r}: {len(ids)} clients, {int(total)} rows")
+            if r == 0:
+                xs = np.concatenate([c[0] for c in clients])
+                ys = np.concatenate([c[1] for c in clients])
+                E = eval_rows
+                parts = []
+                for lo in range(0, len(ys), E):
+                    yb = ys[lo:lo + E]
+                    m = _pad(np.ones(len(yb), np.float32), E)
+                    parts.append(eval_batch(params, _pad(xs[lo:lo + E], E),
+                                            _pad(yb, E), m))
+                loss_r0 = sum(float(v) for v in parts) / len(ys)
+                del xs, ys
+    return {"states": states, "loss_r0": loss_r0}
+
+
+# ---------------------------------------------------------------------------
+# the numbers compared
+
+def _change(states, k):
+    """Leaves of (states[k] - states[0]), float64, flattened-tree order."""
+    return [np.asarray(x, np.float64) - np.asarray(y, np.float64)
+            for x, y in zip(jax.tree.leaves(states[k]),
+                            jax.tree.leaves(states[0]))]
+
+
+def _norms(leaves):
+    return np.asarray([np.linalg.norm(v.ravel()) for v in leaves])
+
+
+def _worst_leaf_gap(prog, ref, keep=None) -> float:
+    """Worst leaf's gap between the program's norm and the reference's,
+    against the reference's norm of that leaf or of the median leaf,
+    whichever is larger."""
+    gap = np.abs(prog - ref) / np.maximum(ref, np.median(ref))
+    return float((gap if keep is None else gap[keep]).max())
+
+
+def compare(prog_states, prog_loss_r0: float, ref: dict) -> dict:
+    """The numbers `correct` is decided on, program against reference.
+
+    ``prog_states``: the program's globals [g0, g1, ... gK] (K >= 1) as
+    host trees with the reference's layout.  Returns name -> reading."""
+    rs = ref["states"]
+    if jax.tree.structure(prog_states[0]) != jax.tree.structure(rs[0]):
+        raise ValueError(
+            "the program's parameter tree is not laid out as the "
+            "reference's: "
+            f"{jax.tree.structure(prog_states[0])} vs "
+            f"{jax.tree.structure(rs[0])}")
+    k = min(len(prog_states), len(rs)) - 1
+    out = {"loss_r0": abs(prog_loss_r0 - ref["loss_r0"])
+           / abs(ref["loss_r0"])}
+    g_ref = _norms(_change(rs, 1))
+    out["grad1_worst_leaf"] = _worst_leaf_gap(
+        _norms(_change(prog_states, 1)), g_ref)
+    # leaves whose first pseudo-gradient is nought to rounding in the
+    # reference are left out of the change (contract, step 4)
+    keep = g_ref >= 1e-3 * np.median(g_ref)
+    c_ref, c_prog = _change(rs, k), _change(prog_states, k)
+    out[f"change{k}_worst_leaf"] = _worst_leaf_gap(
+        _norms(c_prog), _norms(c_ref), keep)
+    # not a norm gap but the norm of the difference of the two changes: it
+    # also sees a change of the right size in the wrong direction (a
+    # clipped gradient keeps its norm whatever the batch holds).  Over the
+    # whole tree (``_diff``), and the median leaf's, each leaf against its
+    # reference norm or the median leaf's (``_median_leaf``): a few small
+    # leaves whose gradient is a sum that all but cancels (GroupNorm's 64
+    # scales) carry the whole tree's on some seeds, the median leaf is
+    # steady from seed to seed (PERF.md section 6)
+    for j in sorted({1, k}):
+        ref_j, prog_j = _change(rs, j), _change(prog_states, j)
+        diff = _norms([p - r for p, r in zip(prog_j, ref_j)])
+        n_ref = _norms(ref_j)
+        out[f"change{j}_diff"] = float(
+            np.sqrt(np.sum(diff ** 2)) / np.sqrt(np.sum(n_ref ** 2)))
+        out[f"change{j}_median_leaf"] = float(np.median(
+            (diff / np.maximum(n_ref, np.median(n_ref)))[keep]))
+    return out
+
+
+def verdict(numbers: dict, limits: dict) -> Tuple[bool, List[dict]]:
+    """Each number compared, beside its limit; correct iff all hold."""
+    rows = []
+    ok = True
+    for name, limit in limits.items():
+        value = numbers.get(name)
+        good = value is not None and np.isfinite(value) and value <= limit
+        ok = ok and good
+        rows.append({"name": name, "value": value, "limit": limit,
+                     "ok": bool(good)})
+    return ok, rows
