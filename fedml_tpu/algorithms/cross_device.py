@@ -45,7 +45,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-import time
 from typing import Any, Dict, Optional
 
 import jax
@@ -61,7 +60,7 @@ from fedml_tpu.core.stream_agg import StreamingAggregator
 from fedml_tpu.data.stacking import gather_cohort
 from fedml_tpu.device_cohort import (WaveAdmission, make_scaffold_wave_fn,
                                      make_wave_fn, plan_waves)
-from fedml_tpu.obs import telemetry
+from fedml_tpu.obs import telemetry, trace
 from fedml_tpu.parallel.cohort import train_cohort
 from fedml_tpu.parallel.mesh import placement_of
 from fedml_tpu.trainer.local_sgd import make_local_trainer
@@ -221,6 +220,13 @@ class CrossDevice(FedAvg):
         self._c_clients = reg.counter("fedml_cohort_clients_total")
         self._h_wave = reg.histogram("fedml_cohort_wave_seconds")
         self._h_fold = reg.histogram("fedml_cohort_fold_seconds")
+        # the round path's timing sites (`_span`): live when something
+        # reads them — the recorder's ledger and tracer, the telemetry
+        # histograms, the degrade tracker's completion latencies
+        self._tracer = perf.tracer if perf is not None else None
+        self._timed = (perf is not None or degrade is not None
+                       or reg.enabled)
+        self._round_ctx = None  # the round span's context (fold worker)
 
         self._wave_fn = self._build_wave_fn(workload, cfg, mesh)
         if perf is not None:
@@ -340,9 +346,18 @@ class CrossDevice(FedAvg):
             self.c_locals = zeros_client_state(
                 jax.tree.map(np.asarray, params), self.data.client_num)
 
-    def _perf_phase(self, name: str, seconds: float) -> None:
-        if self.perf is not None:
-            self.perf.add_phase(name, seconds)
+    def _span(self, name: str, phase: Optional[str] = None, hist=None,
+              wait: Optional[str] = None, **kw):
+        """THE timing site of the round path: one clock interval per
+        boundary, handed to the span ``name`` (and the profiler
+        annotation under it), to the ledger phase ``phase`` and to the
+        histogram ``hist`` (`obs.trace.TimedSpan`).  The shared null
+        context when nothing reads it (one branch, nothing kept: the pin
+        in tests/test_critical_path.py)."""
+        if not self._timed:
+            return trace.NULL_CONTEXT
+        return trace.TimedSpan(self._tracer, name, self.perf, phase, hist,
+                               wait, **kw)
 
     # -- the wave loop --------------------------------------------------------
     def _pin_placement(self, params):
@@ -367,27 +382,37 @@ class CrossDevice(FedAvg):
         Every argument is bound at submit time (no late-binding loop
         closures); ``acc`` carries the round's cross-wave accumulators,
         touched only here until the pre-finalize drain."""
-        cfg = self.cfg
         if wave_weight <= 0:
             # a wave of only weightless clients (all-pad / all-empty
             # shards): folds as weight 0 — skipped entirely, never a
             # 0/0 in the normalizer (pinned in tests)
             return
-        t0 = time.perf_counter()
-        mean_host = jax.tree.map(np.asarray, mean)
-        attack = self._wave_attacks.get((round_idx, wi))
-        if attack is not None:
-            # poison the WAVE SUMMARY pre-admission: the screen, the
-            # health sketch, and the fold all see the attacked mean —
-            # exactly what a compromised wave aggregation would ship
-            from fedml_tpu.robust.adversary import poison_wave_summary
-            mean_host = poison_wave_summary(attack, mean_host,
-                                            host_params,
-                                            seed=cfg.seed)
-            logger.warning("round %d wave %d POISONED (%s:%g)",
-                           round_idx, wi, attack.kind, attack.param)
-        verdict = self.admission.screen(mean_host, host_params)
-        self._perf_phase("admission", time.perf_counter() - t0)
+        # explicit parent: on the fold worker's thread no span is active
+        with self._span("fold_wave", parent=self._round_ctx):
+            self._screen_and_fold(
+                round_idx, wi, wave, stacked, w, mean, wave_weight,
+                aux_sums, new_c, c_delta, host_params, acc)
+
+    def _screen_and_fold(self, round_idx, wi, wave, stacked, w, mean,
+                         wave_weight, aux_sums, new_c, c_delta,
+                         host_params, acc):
+        """`_fold_one`'s body, under its span."""
+        cfg = self.cfg
+        with self._span("admission.copy", "admission", wait="device"):
+            mean_host = jax.tree.map(np.asarray, mean)
+        with self._span("admission.screen", "admission"):
+            attack = self._wave_attacks.get((round_idx, wi))
+            if attack is not None:
+                # poison the WAVE SUMMARY pre-admission: the screen, the
+                # health sketch, and the fold all see the attacked mean —
+                # exactly what a compromised wave aggregation would ship
+                from fedml_tpu.robust.adversary import poison_wave_summary
+                mean_host = poison_wave_summary(attack, mean_host,
+                                                host_params,
+                                                seed=cfg.seed)
+                logger.warning("round %d wave %d POISONED (%s:%g)",
+                               round_idx, wi, attack.kind, attack.param)
+            verdict = self.admission.screen(mean_host, host_params)
         if not verdict.ok:
             logger.warning("round %d wave %d REJECTED (%s): %d "
                            "clients' work discarded", round_idx, wi,
@@ -395,34 +420,30 @@ class CrossDevice(FedAvg):
             if self.health is not None:
                 self.health.observe_rejected(wi + 1, verdict.reason)
             return
-        t0 = time.perf_counter()
-        if attack is not None:
-            # fold the POISONED mean through the SAME stacked wave
-            # program as every clean wave — each member ships the
-            # attacked mean (the weighted mean of identical rows IS
-            # the row), so the spine receives what admission and
-            # health were shown AND its hot fold never traces a new
-            # path in an attack round (the strict recompile sentry
-            # holds even under attack)
-            poisoned = jax.tree.map(
-                lambda m, s: jnp.broadcast_to(
-                    jnp.asarray(m, dtype=s.dtype), s.shape),
-                mean_host, stacked)
-            self.stream.fold_wave(poisoned, w)
-        else:
-            self.stream.fold_wave(stacked, w)
-        dt = time.perf_counter() - t0
-        self._h_fold.observe(dt)
-        self._perf_phase("fold", dt)
+        with self._span("fold.dispatch", "fold", self._h_fold):
+            if attack is not None:
+                # fold the POISONED mean through the SAME stacked wave
+                # program as every clean wave — each member ships the
+                # attacked mean (the weighted mean of identical rows IS
+                # the row), so the spine receives what admission and
+                # health were shown AND its hot fold never traces a new
+                # path in an attack round (the strict recompile sentry
+                # holds even under attack)
+                poisoned = jax.tree.map(
+                    lambda m, s: jnp.broadcast_to(
+                        jnp.asarray(m, dtype=s.dtype), s.shape),
+                    mean_host, stacked)
+                self.stream.fold_wave(poisoned, w)
+            else:
+                self.stream.fold_wave(stacked, w)
         acc["folded"] += 1
         acc["live"] += wave.n_live
         self._c_clients.inc(wave.n_live)
         if self.health is not None:
-            t0 = time.perf_counter()
-            self.health.observe_admitted(wi + 1, mean_host,
-                                         wave_weight,
-                                         norm=verdict.norm)
-            self._perf_phase("health", time.perf_counter() - t0)
+            with self._span("health", "health"):
+                self.health.observe_admitted(wi + 1, mean_host,
+                                             wave_weight,
+                                             norm=verdict.norm)
         if cfg.local_alg == "fednova":
             acc["tau"] += float(aux_sums["tau"])
         elif cfg.local_alg == "scaffold":
@@ -439,14 +460,20 @@ class CrossDevice(FedAvg):
         cfg = self.cfg
         W = cfg.wave_size
         waves = plan_waves(ids, W)
-        params = self._pin_placement(params)
-        self._ensure_bound(params)
-        self.admission.round_start()
-        host_params = jax.tree.map(np.asarray, params)
+        # what `_fold_one` hangs its span under: the round span `run`
+        # opened around this call (none when a caller drives rounds itself)
+        self._round_ctx = (self._tracer.current_context()
+                           if self._tracer is not None else None)
+        with self._span("round.pin"):
+            params = self._pin_placement(params)
+            self._ensure_bound(params)
+            self.admission.round_start()
+            self.stream.reset(params)
+        with self._span("round.host_copy", wait="device"):
+            host_params = jax.tree.map(np.asarray, params)
         if self.health is not None:
             self.health.round_start(round_idx, host_params,
                                     expected=range(1, len(waves) + 1))
-        self.stream.reset(params)
         # cross-wave accumulators: one mutable dict so the fold worker
         # (--ingest_pipeline) and the inline path share the same code;
         # the main thread reads it only after the pre-finalize drain
@@ -458,31 +485,38 @@ class CrossDevice(FedAvg):
         for wi, wave in enumerate(waves):
             if wave.n_live == 0:
                 continue  # empty-cohort edge: nothing sampled
-            t0 = time.perf_counter()
-            wave_data = gather_cohort(self.data.train, wave.ids, pad_to=W)
-            offset = jnp.int32(wave.offset)
-            if cfg.local_alg == "scaffold":
-                c_cohort = gather_client_rows(self.c_locals, wave.ids, W)
-                (stacked, w, mean, total, new_c, c_delta,
-                 _m) = self._wave_fn(params, wave_data, round_rng, offset,
-                                     self.c_global, c_cohort)
-                aux_sums = {}
-            else:
-                stacked, w, mean, total, aux_sums = self._wave_fn(
-                    params, wave_data, round_rng, offset)
-                new_c = c_delta = None
-            wave_weight = float(total)  # blocks: the wave ran to completion
-            if wi == len(waves) - 1:
-                wave_devices = placement_of(stacked)["devices"]
-            dt = time.perf_counter() - t0
+            with self._span("wave", "wave", self._h_wave) as wave_sp:
+                # stage.gather and stage.put open inside gather_cohort
+                wave_data = gather_cohort(self.data.train, wave.ids,
+                                          pad_to=W)
+                if cfg.local_alg == "scaffold":
+                    with self._span("stage.gather"):
+                        c_cohort = gather_client_rows(self.c_locals,
+                                                      wave.ids, W)
+                with self._span("wave.dispatch"):
+                    offset = jnp.int32(wave.offset)
+                    if cfg.local_alg == "scaffold":
+                        (stacked, w, mean, total, new_c, c_delta,
+                         _m) = self._wave_fn(params, wave_data, round_rng,
+                                             offset, self.c_global,
+                                             c_cohort)
+                        aux_sums = {}
+                    else:
+                        stacked, w, mean, total, aux_sums = self._wave_fn(
+                            params, wave_data, round_rng, offset)
+                        new_c = c_delta = None
+                with self._span("wave.wait", wait="device"):
+                    # blocks: the wave ran to completion
+                    wave_weight = float(total)
+                    if wi == len(waves) - 1:
+                        wave_devices = placement_of(stacked)["devices"]
             self._c_waves.inc()
-            self._h_wave.observe(dt)
-            self._perf_phase("wave", dt)
             if self.degrade is not None:
                 # every live client completed with the wave: feed the
                 # latency history and repay any participation debt
                 for cid in wave.ids:
-                    self.degrade.observe_completion(int(cid) + 1, dt)
+                    self.degrade.observe_completion(int(cid) + 1,
+                                                    wave_sp.seconds)
                     self.degrade.note_accept(int(cid) + 1)
             if self.perf is not None:
                 # a completed wave is this regime's "upload arrival" on
@@ -507,9 +541,8 @@ class CrossDevice(FedAvg):
         if self.ingest is not None:
             # rendezvous: every queued fold lands before finalize reads
             # the stream (the wait is the round's true fold overhang)
-            t0 = time.perf_counter()
-            self.ingest.drain()
-            self._perf_phase("barrier_wait", time.perf_counter() - t0)
+            with self._span("fold.drain", "barrier_wait"):
+                self.ingest.drain()
         folded, live_clients = acc["folded"], acc["live"]
         tau_acc, c_delta_acc = acc["tau"], acc["c_delta"]
 
@@ -518,29 +551,31 @@ class CrossDevice(FedAvg):
                            "global unchanged", round_idx)
             new_params = params
         else:
-            t0 = time.perf_counter()
+            # its span, finalize.dispatch (phase fold), opens inside
             new_params = self.stream.finalize(round_idx)
-            self._perf_phase("fold", time.perf_counter() - t0)
-            if cfg.local_alg == "fednova":
-                # x+ = x − tau_eff·Σ p_i d_i, with mean = x − Σ p_i d_i
-                tau_eff = tau_acc / self.stream.weight_total
-                new_params = jax.tree.map(
-                    lambda p, m: (p.astype(jnp.float32) - tau_eff
-                                  * (p.astype(jnp.float32)
-                                     - m.astype(jnp.float32))
-                                  ).astype(p.dtype),
-                    params, new_params)
-            elif cfg.local_alg == "scaffold" and c_delta_acc is not None:
-                # c+ = c + (|S|/N)·mean(c_i+ − c_i) = c + Σdelta/N
-                n_total = float(self.data.client_num)
-                self.c_global = jax.tree.map(
-                    lambda cg, dv: cg + dv / n_total,
-                    self.c_global, c_delta_acc)
-            if self.server_opt is not None:
-                # the server-optimizer seam: Δ = params − finalize, one
-                # jitted step (plain returns the finalize untouched)
-                new_params = self.server_opt.apply(params, new_params,
-                                                   round_idx)
+            with self._span("server_step"):
+                if cfg.local_alg == "fednova":
+                    # x+ = x − tau_eff·Σ p_i d_i, with mean = x − Σ p_i d_i
+                    tau_eff = tau_acc / self.stream.weight_total
+                    new_params = jax.tree.map(
+                        lambda p, m: (p.astype(jnp.float32) - tau_eff
+                                      * (p.astype(jnp.float32)
+                                         - m.astype(jnp.float32))
+                                      ).astype(p.dtype),
+                        params, new_params)
+                elif (cfg.local_alg == "scaffold"
+                      and c_delta_acc is not None):
+                    # c+ = c + (|S|/N)·mean(c_i+ − c_i) = c + Σdelta/N
+                    n_total = float(self.data.client_num)
+                    self.c_global = jax.tree.map(
+                        lambda cg, dv: cg + dv / n_total,
+                        self.c_global, c_delta_acc)
+                if self.server_opt is not None:
+                    # the server-optimizer seam: Δ = params − finalize,
+                    # one jitted step (plain returns the finalize
+                    # untouched)
+                    new_params = self.server_opt.apply(params, new_params,
+                                                       round_idx)
         self._c_rounds.inc()
         if self.health is not None:
             self.health.round_end(
@@ -554,90 +589,110 @@ class CrossDevice(FedAvg):
     def run(self, params=None, rng: Optional[jax.Array] = None,
             checkpointer=None):
         cfg = self.cfg
-        rng = rng if rng is not None else jax.random.key(cfg.seed)
-        if params is None:
-            # the FedAvg.run rng chain, mirrored exactly: parity runs on
-            # the same seed start from the same init and round rngs
-            rng, init_rng = jax.random.split(rng)
-            params = self.workload.init(init_rng, jax.tree.map(
-                lambda v: v[0, 0], {k: self.data.train[k]
-                                    for k in ("x", "y", "mask")}))
-        params, rng, start_round = self._maybe_resume(checkpointer, params,
-                                                      rng)
-        # normalize to device arrays once: a numpy round-0 global and
-        # later jax outputs must key ONE wave jit entry (the PR 5
-        # double-compile class)
-        params = jax.tree.map(jnp.asarray, params)
+        with self._span("setup.init"):
+            rng = rng if rng is not None else jax.random.key(cfg.seed)
+            if params is None:
+                # the FedAvg.run rng chain, mirrored exactly: parity runs
+                # on the same seed start from the same init and round rngs
+                rng, init_rng = jax.random.split(rng)
+                params = self.workload.init(init_rng, jax.tree.map(
+                    lambda v: v[0, 0], {k: self.data.train[k]
+                                        for k in ("x", "y", "mask")}))
+            params, rng, start_round = self._maybe_resume(checkpointer,
+                                                          params, rng)
+            # normalize to device arrays once: a numpy round-0 global and
+            # later jax outputs must key ONE wave jit entry (the PR 5
+            # double-compile class)
+            params = jax.tree.map(jnp.asarray, params)
         for round_idx in range(start_round, cfg.comm_round):
-            t0 = time.time()
-            if self.perf is not None:
-                self.perf.round_start(round_idx)
-            ids = self._sample_round(round_idx)
-            rng, round_rng = jax.random.split(rng)
-            params, info = self._run_round(params, ids, round_rng,
-                                           round_idx)
-            jax.block_until_ready(params)
-            if self.publish is not None:
-                self.publish(params, round_idx + 1)
-            decision = None
-            if self.controller is not None:
-                # the pacing verdict for the NEXT round, from this
-                # round's health line (decided before the checkpoint so
-                # a resume continues the same trajectory)
-                kw = ({"debt": self.degrade.max_debt()}
-                      if self.degrade is not None else {})
-                decision = self.controller.decide(
-                    round_idx,
-                    self.health.last_line if self.health is not None
-                    else None, **kw)
-            round_s = time.time() - t0
-            if self.perf is not None:
-                extra = dict(info)
-                # the round's post-finalize global CRC: the ingest
-                # bench's bit-parity gate compares this sequence between
-                # the inline and pipelined twins (utils.journal.tree_crc
-                # — the same checksum the crash journal trusts)
-                from fedml_tpu.utils.journal import tree_crc
-                extra["global_crc"] = tree_crc(
-                    jax.tree.map(np.asarray, params))
-                if self.server_opt is not None:
-                    extra["server_opt"] = self.server_opt.name
-                if decision is not None:
-                    extra["adapt"] = decision.as_ledger()
-                self.perf.round_end(round_idx, cohort=len(ids),
-                                    wave_size=cfg.wave_size, **extra)
-            if self.slo is not None:
-                self.slo.evaluate()
-            if (round_idx % cfg.frequency_of_the_test == 0
-                    or round_idx == cfg.comm_round - 1):
-                stats = self.evaluate_global(params)
-                where = placement_of(params)
-                stats.update(round=round_idx, round_s=round_s,
-                             cohort=len(ids), waves=info["waves"],
-                             folded_waves=info["folded_waves"],
-                             wave_size=cfg.wave_size,
-                             # provenance: which sampler/trainer made
-                             # this curve — never silently cross-compare
-                             sampler=cfg.sampler,
-                             local_alg=cfg.local_alg,
-                             # where the state landed
-                             wave_devices=info["wave_devices"],
-                             global_platform=where["platform"],
-                             global_devices=where["devices"])
-                logger.info("round %d: %s", round_idx, stats)
-                self.history.append(stats)
-                if self.sink is not None:
-                    self.sink.log(stats, step=round_idx)
-            if checkpointer is not None:
-                checkpointer.maybe_save(
-                    round_idx, self._ckpt_state(params, rng, round_idx),
-                    last_round=round_idx == cfg.comm_round - 1)
+            # the round's root span, one trace id a round; always a live
+            # site (never the null context): the metrics row's round_s is
+            # read from it
+            with trace.TimedSpan(self._tracer, "round", self.perf,
+                                 parent=None,
+                                 round=round_idx) as round_sp:
+                params, rng = self._round(params, rng, round_idx, round_sp,
+                                          checkpointer)
         if checkpointer is not None:
             checkpointer.flush()
         if self.ingest is not None:
             # every round drained before its finalize; nothing queued
             self.ingest.stop()
         return params
+
+    def _round(self, params, rng, round_idx, round_sp, checkpointer):
+        """One pass of `run`'s loop, under the round's root span."""
+        cfg = self.cfg
+        if self.perf is not None:
+            self.perf.round_start(round_idx)
+        with self._span("round.sample"):
+            ids = self._sample_round(round_idx)
+        rng, round_rng = jax.random.split(rng)
+        params, info = self._run_round(params, ids, round_rng, round_idx)
+        with self._span("round.sync", wait="device"):
+            jax.block_until_ready(params)
+        if self.publish is not None:
+            with self._span("round.publish"):
+                self.publish(params, round_idx + 1)
+        decision = None
+        if self.controller is not None:
+            # the pacing verdict for the NEXT round, from this
+            # round's health line (decided before the checkpoint so
+            # a resume continues the same trajectory)
+            kw = ({"debt": self.degrade.max_debt()}
+                  if self.degrade is not None else {})
+            decision = self.controller.decide(
+                round_idx,
+                self.health.last_line if self.health is not None
+                else None, **kw)
+        round_s = round_sp.elapsed()
+        if self.perf is not None:
+            extra = dict(info)
+            # the round's post-finalize global CRC: the ingest
+            # bench's bit-parity gate compares this sequence between
+            # the inline and pipelined twins (utils.journal.tree_crc
+            # — the same checksum the crash journal trusts)
+            from fedml_tpu.utils.journal import tree_crc
+            with self._span("round.crc"):
+                extra["global_crc"] = tree_crc(
+                    jax.tree.map(np.asarray, params))
+            if self.server_opt is not None:
+                extra["server_opt"] = self.server_opt.name
+            if decision is not None:
+                extra["adapt"] = decision.as_ledger()
+            with self._span("round.ledger"):
+                self.perf.round_end(round_idx, cohort=len(ids),
+                                    wave_size=cfg.wave_size, **extra)
+        if self.slo is not None:
+            with self._span("round.slo"):
+                self.slo.evaluate()
+        if (round_idx % cfg.frequency_of_the_test == 0
+                or round_idx == cfg.comm_round - 1):
+            with self._span("eval"):
+                stats = self.evaluate_global(params)
+            where = placement_of(params)
+            stats.update(round=round_idx, round_s=round_s,
+                         cohort=len(ids), waves=info["waves"],
+                         folded_waves=info["folded_waves"],
+                         wave_size=cfg.wave_size,
+                         # provenance: which sampler/trainer made
+                         # this curve — never silently cross-compare
+                         sampler=cfg.sampler,
+                         local_alg=cfg.local_alg,
+                         # where the state landed
+                         wave_devices=info["wave_devices"],
+                         global_platform=where["platform"],
+                         global_devices=where["devices"])
+            logger.info("round %d: %s", round_idx, stats)
+            self.history.append(stats)
+            if self.sink is not None:
+                self.sink.log(stats, step=round_idx)
+        if checkpointer is not None:
+            with self._span("checkpoint"):
+                checkpointer.maybe_save(
+                    round_idx, self._ckpt_state(params, rng, round_idx),
+                    last_round=round_idx == cfg.comm_round - 1)
+        return params, rng
 
     # -- checkpoint extra state (scaffold control variates, server
     # optimizer, adaptive controller) -----------------------------------------

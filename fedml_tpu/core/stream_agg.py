@@ -53,7 +53,7 @@ import numpy as np
 
 from fedml_tpu.core.pytree import acc_dtype
 from fedml_tpu.core.robust import add_gaussian_noise, clip_update
-from fedml_tpu.obs import telemetry
+from fedml_tpu.obs import telemetry, trace
 
 log = logging.getLogger(__name__)
 
@@ -442,16 +442,17 @@ class StreamingAggregator:
             raise RuntimeError("finalize() with no folded uploads; the "
                                "caller must skip aggregation on an empty "
                                "round")
-        import time
-        t0 = time.perf_counter()
-        if self.method == "mean":
-            out = self._finalize_fn(self._acc, self._wsum, self._reference,
-                                    step)
-            # drop our handle to the finalized accumulator so a stale
-            # buffer is never folded into the next round
-            self._acc = self._wsum = None
-        else:
-            out = self._finalize_fn(self._reference, self._res_stack,
-                                    self._res_weights.copy(), step)
-        self._h_finalize.observe(time.perf_counter() - t0)
+        # one interval: this module's histogram and, under a caller's
+        # open span site, that round's span and ledger phase as well
+        with trace.child("finalize.dispatch", phase="fold",
+                         hist=self._h_finalize):
+            if self.method == "mean":
+                out = self._finalize_fn(self._acc, self._wsum,
+                                        self._reference, step)
+                # drop our handle to the finalized accumulator so a stale
+                # buffer is never folded into the next round
+                self._acc = self._wsum = None
+            else:
+                out = self._finalize_fn(self._reference, self._res_stack,
+                                        self._res_weights.copy(), step)
         return out

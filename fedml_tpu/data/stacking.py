@@ -21,6 +21,8 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 import jax.numpy as jnp
 
+from fedml_tpu.obs import trace
+
 Array = np.ndarray
 
 
@@ -39,6 +41,9 @@ class FederatedData:
     test: Optional[Dict[str, Array]] = None
     train_global: Optional[Dict[str, Array]] = None
     test_global: Optional[Dict[str, Array]] = None
+    # (start, duration) of the load on perf_counter_ns, where the loader's
+    # caller stamped it: a runner records the span ``setup.data`` from it
+    load_ns: Optional[tuple] = None
 
     @property
     def train_data_num(self) -> int:
@@ -149,12 +154,22 @@ def gather_cohort(stacked: Dict[str, Array], client_ids: Sequence[int],
             f"pad_to={pad_to}; the static cohort shape cannot hold them "
             f"(chunk the cohort — device_cohort.plan_waves — or raise "
             f"pad_to)")
-    if pad_to is not None and len(ids) < pad_to:
-        ids = np.concatenate([ids, np.zeros(pad_to - len(ids), np.int64)])
-        live = np.concatenate([np.ones(len(client_ids)), np.zeros(pad_to - len(client_ids))])
-    else:
-        live = np.ones(len(ids))
-    out = {k: jnp.asarray(v[ids]) for k, v in stacked.items()}
-    out["mask"] = out["mask"] * jnp.asarray(live, jnp.float32)[:, None, None]
-    out["num_samples"] = out["num_samples"] * jnp.asarray(live, jnp.float32)
+    n_live = len(ids)
+    if pad_to is not None and n_live < pad_to:
+        ids = np.concatenate([ids, np.zeros(pad_to - n_live, np.int64)])
+    live = (np.arange(len(ids)) < n_live).astype(np.float32)
+    # two spans under the caller's (the round's ``wave``), where one is
+    # open: the numpy row gather, then the hand-over to the device
+    with trace.child("stage.gather") as sp:
+        rows = {k: v[ids] for k, v in stacked.items()}
+        if sp is not None:
+            # counts made where the rows are: what is gathered here is
+            # what ``stage.put`` hands over
+            sp.set(bytes=sum(int(v.nbytes) for v in rows.values()),
+                   rows_padded=int(rows["mask"].size),
+                   rows_real=int(rows["num_samples"][:n_live].sum()))
+    with trace.child("stage.put"):
+        out = {k: jnp.asarray(v) for k, v in rows.items()}
+        out["mask"] = out["mask"] * jnp.asarray(live)[:, None, None]
+        out["num_samples"] = out["num_samples"] * jnp.asarray(live)
     return out

@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import logging
+import time
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
@@ -66,7 +67,10 @@ def load_experiment_data(cfg: ExperimentConfig):
     else:
         # twin-only knob; real loaders carry their own client counts
         kw.update(num_clients=cfg.client_num_in_total, seed=cfg.seed)
-    return load_data(cfg.dataset, data_dir=cfg.data_dir, **kw)
+    t0_ns = time.perf_counter_ns()
+    data = load_data(cfg.dataset, data_dir=cfg.data_dir, **kw)
+    data.load_ns = (t0_ns, time.perf_counter_ns() - t0_ns)
+    return data
 
 
 def _fedavg_cfg_kwargs(cfg: ExperimentConfig) -> Dict[str, Any]:
@@ -1747,6 +1751,12 @@ def run_cross_device(cfg, data, mesh, sink):
     from fedml_tpu.algorithms.cross_device import (CrossDevice,
                                                    CrossDeviceConfig)
     perf = _make_perf(cfg)
+    if perf is not None and data.load_ns is not None:
+        # --perf is the one switch of the round path's spans: the
+        # recorder holds the tracer and writes run_dir/trace.json when
+        # it is closed.  The data were loaded before it existed
+        perf.tracer.record_span("setup.data", data.load_ns[1] / 1e9,
+                                t0_ns=data.load_ns[0])
     slo = _make_slo(cfg)
     # wave summaries are params-like trees: health norms/alignment read
     # them against the round's global exactly like cross-silo uploads

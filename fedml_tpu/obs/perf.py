@@ -50,6 +50,7 @@ from typing import Callable, Dict, Optional
 
 from fedml_tpu.obs import critical_path as _cpath
 from fedml_tpu.obs import telemetry
+from fedml_tpu.obs import trace as _trace
 from fedml_tpu.obs.health import HEALTH_SLOS
 from fedml_tpu.utils.journal import durable_append
 
@@ -373,6 +374,19 @@ class PerfRecorder:
         # carries one, on every algorithm that rides this recorder
         self.cpath: Optional[_cpath.RoundCriticalPath] = None
         self._ingest = _cpath.IngestGauges(reg)
+        # the round path's spans (obs/trace.TimedSpan sites): the
+        # process tracer where --trace_dir made one (main() writes that
+        # one, per node), else one of this recorder's own, in memory and
+        # written by `close` as trace.json beside the ledger — so --perf
+        # alone never turns on the actor paths' message-header
+        # propagation.  Like the ledger, one trace.json == one run
+        self.tracer = _trace.get_tracer()
+        self._trace_path = None
+        if self.tracer is None:
+            self.tracer = _trace.SpanTracer(node=node)
+            self._trace_path = os.path.join(d, "trace.json")
+            if os.path.exists(self._trace_path):
+                os.replace(self._trace_path, self._trace_path + ".prev")
 
     # -- registration --------------------------------------------------------
     def register_jit(self, name: str, fn) -> bool:
@@ -526,13 +540,19 @@ class PerfRecorder:
                         "ledger — training continues unledgered", e)
 
     def close(self) -> None:
-        """Stop the sampler thread; safe to call twice.  An open round
-        is NOT flushed — a half-measured round would ledger as a
-        misleadingly fast one."""
+        """Stop the sampler thread and write the spans this recorder's
+        own tracer holds, if any, once as ``trace.json`` beside the
+        ledger; safe to call twice.  An open round is NOT flushed — a
+        half-measured round would ledger as a misleadingly fast one."""
         if self._closed:
             return
         self._closed = True
         self.rss.stop()
+        if self._trace_path is not None and self.tracer.spans:
+            try:
+                self.tracer.export(self._trace_path)
+            except OSError:
+                log.exception("trace export failed")
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,17 @@ Cost contract: tracing is a process-global opt-in (`enable()`); when
 disabled ``get_tracer()`` is ``None`` and instrumented paths pay exactly
 one branch per message, no allocations, no threads.
 
+Clocks: a span's start and duration are ``time.perf_counter_ns()``
+readings (monotonic; the clock a harness in the same process stamps its
+round edges with).  The tracer takes one ``(time.time_ns(),
+perf_counter_ns())`` anchor when it is made, so the exported Chrome
+``ts`` stays on the wall clock — which is what stitches the per-process
+files of an actor run — while every event keeps the raw ``t0_ns`` /
+``dur_ns`` in its ``args``.  A span opened as a context manager also
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so whenever
+a profiler session is open the program's spans lie on the trace's host
+plane beside the device's ops (a flag check when none is).
+
 Duplicate tolerance: a chaotic wire can deliver one frame twice.  Spans
 created with ``deterministic=True`` derive their span id from
 ``(trace_id, parent_id, name, node)``, and the tracer records the FIRST
@@ -48,6 +59,23 @@ NULL_CONTEXT = contextlib.nullcontext()
 
 _USE_CURRENT = object()  # start_span default: parent = the active span
 _tracer_ids = itertools.count()
+# .site: the innermost open `TimedSpan` that has a tracer, on this
+# thread (`child` takes its tracer and its recorder from it)
+_ambient = threading.local()
+
+# kept spans per tracer: a run of any length holds at most this many (the
+# newest; the dropped count is written into the exported file)
+MAX_SPANS = 65536
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent
+    (this module stays importable on the stdlib alone)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class SpanContext:
@@ -71,11 +99,11 @@ class SpanContext:
 class Span:
     """One timed operation.  ``end()`` records it (idempotent)."""
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "node",
-                 "args", "t0", "tid", "_tracer", "_ended")
+                 "args", "t0", "tid", "_tracer", "_ended", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str], node, args: dict,
-                 t0: float):
+                 t0: int):
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
@@ -86,16 +114,21 @@ class Span:
         self.tid = threading.get_ident()
         self._tracer = tracer
         self._ended = False
+        self._annotation = None
 
     @property
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
 
-    def end(self) -> None:
+    def end(self) -> int:
+        """Record the span; returns its duration in nanoseconds (0 when
+        it had ended already)."""
         if self._ended:
-            return
+            return 0
         self._ended = True
-        self._tracer._record(self, self._tracer._clock() - self.t0)
+        dur_ns = self._tracer._clock() - self.t0
+        self._tracer._record(self, dur_ns)
+        return dur_ns
 
 
 class SpanTracer:
@@ -103,15 +136,19 @@ class SpanTracer:
 
     ``node`` labels spans that don't pass their own (in-process actors
     pass their node id per span, so one tracer serves a whole local
-    federation).  ``clock`` is injectable for deterministic tests.
+    federation).  ``clock`` (integer nanoseconds, monotonic) is
+    injectable for deterministic tests.
     """
 
-    def __init__(self, node="proc0", clock=time.time):
+    def __init__(self, node="proc0", clock=time.perf_counter_ns):
         self.node = node
         self._clock = clock
+        # wall-clock anchor: exported ``ts`` = wall + (t0 - mono)
+        self._anchor = (time.time_ns(), clock())
+        self._annotate = _trace_annotation()
         self._lock = threading.Lock()
-        self._spans: dict = {}              # span_id -> record (first wins)
-        self._order: list = []              # span ids in record order
+        self._spans: dict = {}      # span_id -> record, in record order
+        self.dropped = 0            # oldest records let go at MAX_SPANS
         self._seq = itertools.count()
         self._local = threading.local()
         # per-tracer nonce keeps generated ids unique across processes
@@ -166,86 +203,200 @@ class SpanTracer:
         return Span(self, name, trace_id, span_id, parent_id, node, args,
                     self._clock())
 
+    def enter(self, name: str, **kw) -> Span:
+        """Start a span, make it the thread's current (so sends and spans
+        inside it hang under it) and enter the profiler annotation of the
+        same name.  Pair with `exit` on the same thread, innermost first
+        — `span` and `TimedSpan` are the context managers that do."""
+        sp = self.start_span(name, **kw)
+        self._stack().append(sp)
+        if self._annotate is not None:
+            sp._annotation = self._annotate(name)
+            sp._annotation.__enter__()
+        return sp
+
+    def exit(self, sp: Span) -> int:
+        """End what `enter` started; returns the duration in ns."""
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
+        self._stack().pop()
+        return sp.end()
+
     @contextlib.contextmanager
     def span(self, name: str, **kw):
-        """Start a span, make it the thread's current (so sends inside it
-        propagate its context), end it on exit."""
-        sp = self.start_span(name, **kw)
-        stack = self._stack()
-        stack.append(sp)
+        """`enter` a span, `exit` it when the block ends."""
+        sp = self.enter(name, **kw)
         try:
             yield sp
         finally:
-            stack.pop()
-            sp.end()
+            self.exit(sp)
 
     def record_span(self, name: str, dur_s: float,
-                    t0: Optional[float] = None, parent=None,
+                    t0_ns: Optional[int] = None, parent=None,
                     trace_id: Optional[str] = None, node=None,
                     **args) -> None:
         """Record an already-finished span retroactively: the hot-path
         form for schedulers that know a phase's duration only after it
         ran (serve queue wait, batch execution, decode steps) — one call
         per event, no context-manager entry on the critical path.
-        ``t0`` defaults to ``now - dur_s`` on this tracer's clock; pass
-        a Span/SpanContext as ``parent`` to hang it under a request."""
+        ``t0_ns`` defaults to ``now - dur_s`` on this tracer's clock;
+        pass a Span/SpanContext as ``parent`` to hang it under a
+        request."""
         if isinstance(parent, Span):
             parent = parent.context
-        if t0 is None:
-            t0 = self._clock() - dur_s
+        dur_ns = int(dur_s * 1e9)
         sp = self.start_span(name, parent=parent, trace_id=trace_id,
                              node=node, **args)
-        sp.t0 = t0
+        sp.t0 = self._clock() - dur_ns if t0_ns is None else t0_ns
         sp._ended = True
-        self._record(sp, dur_s)
+        self._record(sp, dur_ns)
 
-    def _record(self, span: Span, dur_s: float) -> None:
+    def _record(self, span: Span, dur_ns: int) -> None:
         rec = {"name": span.name, "trace_id": span.trace_id,
                "span_id": span.span_id, "parent_id": span.parent_id,
-               "node": span.node, "ts": span.t0, "dur": dur_s,
+               "node": span.node, "t0_ns": span.t0, "dur_ns": dur_ns,
                "tid": span.tid, "args": span.args}
         with self._lock:
             if span.span_id not in self._spans:   # dedupe: first wins
                 self._spans[span.span_id] = rec
-                self._order.append(span.span_id)
+                if len(self._spans) > MAX_SPANS:
+                    del self._spans[next(iter(self._spans))]
+                    self.dropped += 1
 
     # -- export --------------------------------------------------------------
     @property
     def spans(self) -> list:
         """Recorded span dicts, in record order (test/report surface)."""
         with self._lock:
-            return [dict(self._spans[i]) for i in self._order]
+            return [dict(rec) for rec in self._spans.values()]
 
     def to_trace_events(self) -> list:
         """Chrome ``trace_event`` list: one complete ("X") event per span
         plus ``process_name`` metadata naming each node's track."""
         events, nodes = [], {}
+        wall_ns, mono_ns = self._anchor
         for rec in self.spans:
             pid = _node_pid(rec["node"])
             nodes.setdefault(pid, rec["node"])
             events.append({
                 "name": rec["name"], "cat": "fedml", "ph": "X",
-                "ts": int(rec["ts"] * 1e6), "dur": int(rec["dur"] * 1e6),
+                "ts": (wall_ns + rec["t0_ns"] - mono_ns) // 1000,
+                "dur": rec["dur_ns"] // 1000,
                 "pid": pid, "tid": rec["tid"] % 1_000_000,
                 "args": {"trace_id": rec["trace_id"],
                          "span_id": rec["span_id"],
                          "parent_id": rec["parent_id"],
-                         "node": str(rec["node"]), **rec["args"]}})
+                         "node": str(rec["node"]),
+                         "t0_ns": rec["t0_ns"], "dur_ns": rec["dur_ns"],
+                         **rec["args"]}})
         for pid, node in sorted(nodes.items()):
             events.append({"ph": "M", "name": "process_name", "pid": pid,
                            "tid": 0, "args": {"name": f"node {node}"}})
         return events
 
     def export(self, path: str) -> None:
-        """Write ``{"traceEvents": [...]}`` atomically (tmp + replace)."""
+        """Write ``{"traceEvents": [...]}`` atomically (tmp + replace);
+        ``otherData`` says what ``t0_ns`` is read on and how many spans
+        the cap let go."""
         d = os.path.dirname(path)
         if d:
             os.makedirs(d, exist_ok=True)
         tmp = path + ".tmp"
         with open(tmp, "w") as f:
             json.dump({"traceEvents": self.to_trace_events(),
-                       "displayTimeUnit": "ms"}, f)
+                       "displayTimeUnit": "ms",
+                       "otherData": {"clock": "perf_counter_ns",
+                                     "anchor_wall_ns": self._anchor[0],
+                                     "anchor_mono_ns": self._anchor[1],
+                                     "dropped_spans": self.dropped}}, f)
         os.replace(tmp, path)
+
+
+class TimedSpan:
+    """ONE timing site for one boundary: a context manager that reads the
+    clock once on entry and once on exit and hands that single interval
+    to everything that wants it — the span on ``tracer`` (and, through
+    it, the profiler annotation), the ledger phase ``phase`` of ``perf``
+    (`obs.perf.PerfRecorder.add_phase`) and the histogram ``hist``.  Any
+    of them may be None; with no tracer the site still times (a caller
+    that needs the seconds without ``--perf``).  ``wait="device"`` marks
+    a span in which the host only waits for the device.  Further
+    keywords go to `SpanTracer.start_span` (``parent``, ``trace_id``,
+    span args); `set` adds args once the work has produced them."""
+    __slots__ = ("_tracer", "_clock", "_name", "_perf", "_phase", "_hist",
+                 "_kw", "_outer", "span", "t0_ns", "dur_ns")
+
+    def __init__(self, tracer: Optional[SpanTracer], name: str, perf=None,
+                 phase: Optional[str] = None, hist=None,
+                 wait: Optional[str] = None, **kw):
+        self._tracer = tracer
+        self._clock = (tracer._clock if tracer is not None
+                       else time.perf_counter_ns)
+        self._name = name
+        self._perf = perf
+        self._phase = phase
+        self._hist = hist
+        if phase is not None:
+            kw["phase"] = phase
+        if wait is not None:
+            kw["wait"] = wait
+        self._kw = kw
+        self._outer = None
+        self.span: Optional[Span] = None
+        self.t0_ns = self.dur_ns = 0
+
+    def __enter__(self) -> "TimedSpan":
+        if self._tracer is not None:
+            self.span = self._tracer.enter(self._name, **self._kw)
+            self.t0_ns = self.span.t0
+            self._outer = getattr(_ambient, "site", None)
+            _ambient.site = self
+        else:
+            self.t0_ns = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self.span is not None:
+            _ambient.site = self._outer
+            self.dur_ns = self._tracer.exit(self.span)
+        else:
+            self.dur_ns = self._clock() - self.t0_ns
+        if self._phase is not None and self._perf is not None:
+            self._perf.add_phase(self._phase, self.dur_ns / 1e9)
+        if self._hist is not None:
+            self._hist.observe(self.dur_ns / 1e9)
+        return False
+
+    def set(self, **args) -> None:
+        """Counts that ride the span (rows, bytes, slots)."""
+        if self.span is not None:
+            self.span.args.update(args)
+
+    @property
+    def seconds(self) -> float:
+        """The measured interval, once the block has ended."""
+        return self.dur_ns / 1e9
+
+    def elapsed(self) -> float:
+        """Seconds since entry, read inside the block."""
+        return (self._clock() - self.t0_ns) / 1e9
+
+
+def child(name: str, phase: Optional[str] = None, hist=None):
+    """A `TimedSpan` under the site that is open on this thread, on that
+    site's tracer and ledger.  How library code a spanned caller runs
+    opens its own spans without its signature growing a tracer: the
+    cohort staging and the aggregator's finalize under the cross-device
+    round.  With no site open it times for ``hist`` alone, and is the
+    shared null context (one lookup, nothing kept) when there is no
+    histogram either."""
+    site = getattr(_ambient, "site", None)
+    if site is not None:
+        return TimedSpan(site._tracer, name, site._perf, phase, hist)
+    if hist is not None:
+        return TimedSpan(None, name, hist=hist)
+    return NULL_CONTEXT
 
 
 def _node_pid(node) -> int:
@@ -295,7 +446,7 @@ def get_tracer() -> Optional[SpanTracer]:
     return _tracer
 
 
-def enable(node="proc0", clock=time.time) -> SpanTracer:
+def enable(node="proc0", clock=time.perf_counter_ns) -> SpanTracer:
     global _tracer
     if _tracer is None:
         _tracer = SpanTracer(node=node, clock=clock)

@@ -276,6 +276,11 @@ def test_disabled_mode_is_zero_allocation():
 
     hub = LocalHub()
     mgr = Probe(0, hub.transport(0))
+    # the cross-device round path's one timing-site helper with nothing
+    # reading it, and the staging spans it parents (ISSUE 27)
+    from types import SimpleNamespace
+    from fedml_tpu.algorithms.cross_device import CrossDevice
+    engine = SimpleNamespace(_timed=False)
 
     def hot_path():
         for _ in range(200):
@@ -284,6 +289,10 @@ def test_disabled_mode_is_zero_allocation():
             with mgr._perf_phase("decode"):
                 pass
             mgr._note_arrival()
+            with CrossDevice._span(engine, "wave", "wave", None) as site:
+                assert site is None
+            with trace.child("stage.gather"):
+                pass
 
     # two warm-up passes: the second crosses the interpreter's adaptive
     # specialization threshold, so the measured pass is steady-state

@@ -456,7 +456,14 @@ def _run_degrade(init, rounds, *, n=3, degrade=None, ck=None, jr=None,
         # the audit point: start() ran recovery + broadcast, nothing
         # else has pumped yet
         server._start_arms = list(arm_log)
-    hub.pump()
+    try:
+        hub.pump()
+    except ActorKilled:
+        # a killed actor never reaches finish(): reap the straggler
+        # timer its process would have taken with it, or the 300 s Timer
+        # thread outlives this test into whatever shares its worker
+        server._cancel_timer(join=True)
+        raise
     return server
 
 

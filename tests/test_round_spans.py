@@ -1,0 +1,411 @@
+"""Round spans inside the cross-device engine (ISSUE 27).
+
+One timing site per boundary (`obs.trace.TimedSpan` through
+`CrossDevice._span`): the span tree of a round, the ledger phases fed from
+the same intervals, the profiler annotations under the span names, the
+repaired tracer clock, and the names the benchmark's trace reduction finds
+programs by.  PERF.md section 3 lists every span with the metric that
+reads it.
+"""
+
+import glob
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedml_tpu.algorithms.cross_device import CrossDevice, CrossDeviceConfig
+from fedml_tpu.core.stream_agg import StreamingAggregator, zeros_acc_like
+from fedml_tpu.data import load_data
+from fedml_tpu.data.stacking import gather_cohort
+from fedml_tpu.experiments.main import main
+from fedml_tpu.experiments.models import create_workload, sample_shape_of
+from fedml_tpu.obs import trace
+from fedml_tpu.obs.perf import PerfRecorder
+from fedml_tpu.utils.journal import tree_crc
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PHASES = ("wave", "fold", "admission", "barrier_wait", "health")
+WAIT_SPANS = {"round.host_copy", "wave.wait", "admission.copy",
+              "round.sync"}
+
+
+@pytest.fixture(scope="module")
+def data():
+    return load_data("mnist", data_dir=None, batch_size=4, num_clients=24,
+                     seed=0)
+
+
+@pytest.fixture(scope="module")
+def workload(data):
+    return create_workload("lr", "mnist", data.class_num,
+                           sample_shape_of(data))
+
+
+def _cfg(**kw):
+    base = dict(comm_round=2, client_num_per_round=12, epochs=1,
+                batch_size=4, wave_size=5, seed=0,
+                frequency_of_the_test=10)
+    base.update(kw)
+    return CrossDeviceConfig(**base)
+
+
+def _events(path):
+    with open(path) as f:
+        doc = json.load(f)
+    return doc, [e for e in doc["traceEvents"] if e["ph"] == "X"]
+
+
+# ---------------------------------------------------------------------------
+# the CLI: --perf writes run_dir/trace.json, one tree a round
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module", params=["inline", "ingest_pipeline"])
+def cli_run(request, tmp_path_factory):
+    run_dir = str(tmp_path_factory.mktemp(request.param))
+    argv = ["--algo", "cross_device", "--model", "lr", "--dataset", "mnist",
+            "--client_num_in_total", "12", "--client_num_per_round", "10",
+            "--wave_size", "4", "--comm_round", "2", "--batch_size", "4",
+            "--health", "true", "--log_stdout", "false", "--perf", "true",
+            "--run_dir", run_dir]
+    if request.param == "ingest_pipeline":
+        argv += ["--ingest_pipeline", "true"]
+    main(argv)
+    doc, events = _events(os.path.join(run_dir, "trace.json"))
+    with open(os.path.join(run_dir, "perf.jsonl")) as f:
+        ledger = [json.loads(line) for line in f]
+    return {"doc": doc, "events": events, "ledger": ledger,
+            "pipelined": request.param == "ingest_pipeline"}
+
+
+def _by_round(events):
+    roots = [e for e in events if e["name"] == "round"]
+    return {r["args"]["round"]: [e for e in events if e["args"]["trace_id"]
+                                 == r["args"]["trace_id"]] for r in roots}
+
+
+def test_every_round_is_one_tree_under_one_root(cli_run):
+    rounds = _by_round(cli_run["events"])
+    assert sorted(rounds) == [0, 1]
+    for members in rounds.values():
+        by_id = {e["args"]["span_id"]: e for e in members}
+        roots = [e for e in members if e["args"]["parent_id"] is None]
+        assert [r["name"] for r in roots] == ["round"]
+        for e in members:
+            hops = 0
+            while e["args"]["parent_id"] is not None:
+                parent = by_id[e["args"]["parent_id"]]  # no orphan
+                # a child lies inside its parent, on the raw clock
+                assert e["args"]["t0_ns"] >= parent["args"]["t0_ns"]
+                assert (e["args"]["t0_ns"] + e["args"]["dur_ns"]
+                        <= parent["args"]["t0_ns"]
+                        + parent["args"]["dur_ns"])
+                e, hops = parent, hops + 1
+                assert hops < 8
+            assert e is roots[0]
+
+
+def test_span_names_of_a_round_are_the_contract(cli_run):
+    for members in _by_round(cli_run["events"]).values():
+        by_id = {e["args"]["span_id"]: e for e in members}
+        paths = set()
+        for e in members:
+            parent = by_id.get(e["args"]["parent_id"])
+            paths.add((parent["name"] if parent else None, e["name"]))
+        want = {(None, "round"), ("round", "round.sample"),
+                ("round", "round.pin"), ("round", "round.host_copy"),
+                ("round", "wave"), ("wave", "stage.gather"),
+                ("wave", "stage.put"), ("wave", "wave.dispatch"),
+                ("wave", "wave.wait"), ("round", "fold_wave"),
+                ("fold_wave", "admission.copy"),
+                ("fold_wave", "admission.screen"),
+                ("fold_wave", "fold.dispatch"), ("fold_wave", "health"),
+                ("round", "finalize.dispatch"), ("round", "server_step"),
+                ("round", "round.sync"), ("round", "round.crc"),
+                ("round", "round.ledger")}
+        if cli_run["pipelined"]:
+            want.add(("round", "fold.drain"))
+        assert want <= paths, want - paths
+        for e in members:
+            assert (e["args"].get("wait") == "device") \
+                == (e["name"] in WAIT_SPANS), e["name"]
+    names = {e["name"] for e in cli_run["events"]}
+    assert {"setup.data", "setup.init", "eval"} <= names
+    # the names the harness patches in stay the harness's own
+    hooks = json.load(open(os.path.join(
+        ROOT, "benchmark", "hooks", "cross_device.json")))
+    assert not names & (set(hooks["spans"]) | {"bench_round"})
+
+
+def test_fold_wave_hangs_under_its_round_on_either_thread(cli_run):
+    for members in _by_round(cli_run["events"]).values():
+        root = [e for e in members if e["name"] == "round"][0]
+        folds = [e for e in members if e["name"] == "fold_wave"]
+        assert len(folds) == 3
+        assert {e["args"]["parent_id"] for e in folds} \
+            == {root["args"]["span_id"]}
+        on_worker = {e["tid"] != root["tid"] for e in folds}
+        assert on_worker == {cli_run["pipelined"]}
+
+
+def test_leaf_spans_of_one_thread_do_not_overlap(cli_run):
+    events = cli_run["events"]
+    parents = {e["args"]["parent_id"] for e in events}
+    by_tid = {}
+    for e in events:
+        if e["args"]["span_id"] not in parents:
+            by_tid.setdefault(e["tid"], []).append(
+                (e["args"]["t0_ns"], e["args"]["t0_ns"]
+                 + e["args"]["dur_ns"], e["name"]))
+    for leaves in by_tid.values():
+        leaves.sort()
+        for (_, end, a), (start, _, b) in zip(leaves, leaves[1:]):
+            assert start >= end, (a, b)
+
+
+def test_each_ledger_phase_is_the_sum_of_its_spans(cli_run):
+    """One timing site: a phase of perf.jsonl and the spans tagged with
+    it are the same clock readings (the ledger rounds to a microsecond)."""
+    rounds = _by_round(cli_run["events"])
+    assert len(cli_run["ledger"]) == 2      # one line a round, no other
+    for line in cli_run["ledger"]:
+        tagged = {}
+        for e in rounds[line["round"]]:
+            if "phase" in e["args"]:
+                tagged[e["args"]["phase"]] = tagged.get(
+                    e["args"]["phase"], 0) + e["args"]["dur_ns"]
+        assert set(tagged) == set(line["phases"]) <= set(PHASES)
+        assert ("barrier_wait" in tagged) == cli_run["pipelined"]
+        for phase, ns in tagged.items():
+            assert abs(line["phases"][phase] - ns / 1e9) <= 0.51e-6, phase
+
+
+def test_counts_ride_the_staging_spans(cli_run):
+    gathers = [e for e in cli_run["events"] if e["name"] == "stage.gather"]
+    puts = [e for e in cli_run["events"] if e["name"] == "stage.put"]
+    assert len(gathers) == len(puts) == 6
+    for g in gathers:
+        assert g["args"]["bytes"] > 0
+        assert 0 < g["args"]["rows_real"] <= g["args"]["rows_padded"]
+    # every wave gathers wave_size slots of S x B rows, live or padded
+    # (10 clients in waves of 4: the last wave carries two padded slots)
+    padded = {g["args"]["rows_padded"] for g in gathers}
+    assert len(padded) == 1 and padded.pop() % 4 == 0
+
+
+def test_export_keeps_wall_ts_and_raw_monotonic_clock(cli_run):
+    other = cli_run["doc"]["otherData"]
+    assert other["clock"] == "perf_counter_ns"
+    assert other["dropped_spans"] == 0
+    for e in cli_run["events"]:
+        wall_us = (other["anchor_wall_ns"] + e["args"]["t0_ns"]
+                   - other["anchor_mono_ns"]) // 1000
+        assert e["ts"] == wall_us
+        assert e["dur"] == e["args"]["dur_ns"] // 1000
+
+
+# ---------------------------------------------------------------------------
+# the engine: same bits with and without the sites; profiler annotations
+# ---------------------------------------------------------------------------
+
+def test_global_crc_is_the_same_with_perf_on_and_off(workload, data,
+                                                     tmp_path):
+    plain = CrossDevice(workload, data, _cfg()).run()
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    try:
+        spanned = CrossDevice(workload, data, _cfg(), perf=perf).run()
+    finally:
+        perf.close()
+    crc = tree_crc(jax.tree.map(np.asarray, spanned))
+    assert crc == tree_crc(jax.tree.map(np.asarray, plain))
+    with open(tmp_path / "perf.jsonl") as f:
+        assert json.loads(f.readlines()[-1])["global_crc"] == crc
+    assert any(s["name"] == "wave.wait" for s in perf.tracer.spans)
+
+
+def test_perf_alone_does_not_turn_on_header_propagation(tmp_path):
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    perf.close()
+    assert perf.tracer is not None and trace.get_tracer() is None
+
+
+def test_spans_are_profiler_annotations_on_the_host_plane(workload, data,
+                                                          tmp_path):
+    from jax.profiler import ProfileData
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    try:
+        with jax.profiler.trace(str(tmp_path / "prof")):
+            CrossDevice(workload, data, _cfg(), perf=perf).run()
+    finally:
+        perf.close()
+    path = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    names = {e.name for p in host for line in p.lines for e in line.events}
+    assert {s["name"] for s in perf.tracer.spans} <= names
+    assert {"round", "wave", "stage.gather", "stage.put", "wave.dispatch",
+            "wave.wait", "fold_wave", "finalize.dispatch",
+            "round.sync"} <= names
+
+
+def test_sites_without_a_recorder_keep_nothing(workload, data):
+    """The disabled path: one branch to the shared null context."""
+    eng = CrossDevice(workload, data, _cfg())
+    assert eng._span("wave", "wave", eng._h_wave) is trace.NULL_CONTEXT
+    assert trace.child("stage.gather") is trace.NULL_CONTEXT
+
+
+def test_degrade_reads_the_wave_interval_without_a_recorder(workload, data):
+    from fedml_tpu.robust.degrade import ReliabilityTracker
+    tracker = ReliabilityTracker(data.client_num)
+    eng = CrossDevice(workload, data, _cfg(comm_round=1), degrade=tracker)
+    assert eng._tracer is None
+    eng.run()
+    assert any(len(lat) and all(v > 0 for v in lat)
+               for lat in tracker._lat.values())
+
+
+# ---------------------------------------------------------------------------
+# the tracer: clock, cap, ambient child
+# ---------------------------------------------------------------------------
+
+def test_tracer_clock_is_monotonic_ns_with_a_wall_anchor():
+    ticks = iter(range(1000, 10 ** 6, 250))
+    tr = trace.SpanTracer(clock=lambda: next(ticks))   # anchor reads 1000
+    with trace.TimedSpan(tr, "outer"):
+        with trace.child("inner") as inner:
+            inner.set(rows=3)
+    inner_rec, outer_rec = tr.spans
+    assert (outer_rec["t0_ns"], outer_rec["dur_ns"]) == (1250, 750)
+    assert (inner_rec["t0_ns"], inner_rec["dur_ns"]) == (1500, 250)
+    assert inner_rec["parent_id"] == outer_rec["span_id"]
+    assert inner_rec["args"] == {"rows": 3}
+    outer_event = tr.to_trace_events()[1]
+    assert outer_event["ts"] == (tr._anchor[0] + 250) // 1000
+    assert trace.child("after") is trace.NULL_CONTEXT
+
+
+def test_kept_spans_are_capped_newest_kept(monkeypatch, tmp_path):
+    monkeypatch.setattr(trace, "MAX_SPANS", 4)
+    tr = trace.SpanTracer()
+    for i in range(7):
+        tr.record_span(f"s{i}", 0.001)
+    assert [s["name"] for s in tr.spans] == ["s3", "s4", "s5", "s6"]
+    tr.export(str(tmp_path / "t.json"))
+    doc, events = _events(str(tmp_path / "t.json"))
+    assert doc["otherData"]["dropped_spans"] == 3 and len(events) == 4
+
+
+def test_timed_span_feeds_phase_and_histogram_from_one_interval(tmp_path):
+    class Hist:
+        seen = []
+
+        def observe(self, v):
+            self.seen.append(v)
+
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    try:
+        perf.round_start(0)
+        with trace.TimedSpan(perf.tracer, "fold.dispatch", perf, "fold",
+                             Hist()) as site:
+            pass
+        line = perf.round_end(0)
+    finally:
+        perf.close()
+    rec = perf.tracer.spans[-1]
+    assert rec["args"] == {"phase": "fold"}
+    assert Hist.seen == [rec["dur_ns"] / 1e9] == [site.seconds]
+    assert line["phases"] == {"fold": round(rec["dur_ns"] / 1e9, 6)}
+
+
+def test_child_takes_tracer_and_ledger_from_the_open_site(tmp_path):
+    """How library code (the staging, the aggregator's finalize) joins
+    the caller's round: no tracer in its signature."""
+    class Hist:
+        def __init__(self):
+            self.seen = []
+
+        def observe(self, v):
+            self.seen.append(v)
+
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    under, alone = Hist(), Hist()
+    try:
+        perf.round_start(0)
+        with trace.TimedSpan(perf.tracer, "round", perf) as root:
+            with trace.child("finalize.dispatch", phase="fold", hist=under):
+                pass
+        line = perf.round_end(0)
+    finally:
+        perf.close()
+    rec = perf.tracer.spans[0]
+    assert rec["name"] == "finalize.dispatch"
+    assert rec["parent_id"] == root.span.span_id
+    assert under.seen == [rec["dur_ns"] / 1e9]
+    assert line["phases"] == {"fold": round(rec["dur_ns"] / 1e9, 6)}
+    # no site open: the histogram alone is fed, nothing is kept
+    with trace.child("finalize.dispatch", phase="fold", hist=alone) as site:
+        assert site.span is None
+    assert len(alone.seen) == 1 and len(perf.tracer.spans) == 2
+
+
+def test_trace_json_is_one_runs_own(tmp_path):
+    """A file left by an earlier run is moved aside when the recorder
+    starts, like the ledger; a recorder that rides the process tracer
+    (--trace_dir, written per node by main()) writes none."""
+    stale = tmp_path / "trace.json"
+    stale.write_text("{}")
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    assert not stale.exists()
+    assert (tmp_path / "trace.json.prev").read_text() == "{}"
+    perf.close()                      # no span recorded: no file
+    assert not stale.exists()
+    shared = trace.enable()
+    try:
+        perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+        assert perf.tracer is shared
+        with trace.TimedSpan(perf.tracer, "round", perf):
+            pass
+        perf.close()
+    finally:
+        trace.disable()
+    assert shared.spans and not stale.exists()
+
+
+# ---------------------------------------------------------------------------
+# the names the benchmark's trace reduction finds programs by
+# ---------------------------------------------------------------------------
+
+def _module_name(lowered) -> str:
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+def test_program_names_the_trace_reduction_reads(workload, data):
+    bench = os.path.join(ROOT, "benchmark")
+    hooks = json.load(open(os.path.join(bench, "hooks",
+                                        "cross_device.json")))
+    fold_modules = json.load(open(os.path.join(
+        bench, "layer_metrics", "fold_program_s.json")))["args"]["modules"]
+    eng = CrossDevice(workload, data, _cfg())
+    params = jax.tree.map(jnp.asarray, workload.init(
+        jax.random.key(0), jax.tree.map(
+            lambda v: v[0, 0],
+            {k: data.train[k] for k in ("x", "y", "mask")})))
+    wave_data = gather_cohort(data.train, [1, 2, 3], pad_to=5)
+    wave = eng._wave_fn.lower(params, wave_data, jax.random.key(1),
+                              jnp.int32(0))
+    assert _module_name(wave) == hooks["wave_program"] == "jit_wave_fn"
+
+    agg = StreamingAggregator(params)
+    acc = zeros_acc_like(params)
+    stacked = jax.tree.map(lambda p: jnp.stack([p] * 5), params)
+    names = [_module_name(agg._fold_wave_fn.lower(
+        acc, jnp.float32(0), stacked, jnp.ones(5, jnp.float32), params)),
+        _module_name(agg._finalize_fn.lower(acc, jnp.float32(1), params,
+                                            0))]
+    assert names == fold_modules == ["jit__fold_wave", "jit__finalize"]
