@@ -14,9 +14,11 @@ had never been wired together:
   cross-compared);
 * `device_cohort.plan_waves` pads the cohort into static device-sized
   WAVES; each wave trains as ONE compiled program
-  (`device_cohort.make_wave_fn`: vmap on one chip, shard_map over
-  `parallel/mesh.py`'s ``clients`` axis on a mesh — FedJAX's vmapped
-  client simulation, arXiv 2108.02117, grafted onto the live loop);
+  (`device_cohort.make_wave_fn`: the client axis vmapped, or run in
+  sequence for a conv model (`parallel/cohort.choose_client_axis`), on
+  one chip; shard_map over `parallel/mesh.py`'s ``clients`` axis on a
+  mesh — FedJAX's vmapped client simulation, arXiv 2108.02117, grafted
+  onto the live loop);
 * each wave's stacked updates fold DEVICE-SIDE into the PR 7
   `StreamingAggregator` at wave completion (`fold_wave`: a sequential
   slot-order scan, bit-identical to per-upload folds and to a
@@ -61,7 +63,7 @@ from fedml_tpu.data.stacking import gather_cohort
 from fedml_tpu.device_cohort import (WaveAdmission, make_scaffold_wave_fn,
                                      make_wave_fn, plan_waves)
 from fedml_tpu.obs import telemetry, trace
-from fedml_tpu.parallel.cohort import train_cohort
+from fedml_tpu.parallel.cohort import choose_client_axis, train_cohort
 from fedml_tpu.parallel.mesh import placement_of
 from fedml_tpu.trainer.local_sgd import make_local_trainer
 from fedml_tpu.trainer.workload import make_client_optimizer
@@ -135,9 +137,9 @@ class CrossDevice(FedAvg):
                     f"normalized server step need the stateful mesh wrap "
                     f"of parallel/cohort.make_sharded_stateful_round); "
                     f"drop --mesh_clients")
-            if cfg.client_axis != "vmap":
-                raise ValueError(f"--client_axis is not wired into the "
-                                 f"{cfg.local_alg} wave; drop the flag")
+            if cfg.client_axis not in (None, "vmap"):
+                raise ValueError(f"client_axis is not wired into the "
+                                 f"{cfg.local_alg} wave; leave it None")
         if cfg.local_alg == "scaffold":
             if cfg.client_optimizer != "sgd":
                 raise ValueError(
@@ -227,6 +229,10 @@ class CrossDevice(FedAvg):
         self._timed = (perf is not None or degrade is not None
                        or reg.enabled)
         self._round_ctx = None  # the round span's context (fold worker)
+        # how the wave program runs its client axis; the sgd / fedprox
+        # wave sets it when it is traced, the scaffold and fednova waves
+        # keep their own vmap
+        self._wave_axis = "vmap"
 
         self._wave_fn = self._build_wave_fn(workload, cfg, mesh)
         if perf is not None:
@@ -244,9 +250,14 @@ class CrossDevice(FedAvg):
                 prox_mu=cfg.mu if cfg.local_alg == "fedprox" else 0.0)
 
             def make_stacked(params, wave_data, rng, offset):
+                # resolved here, under the wave program's trace (shapes
+                # are static there), and kept for `wave.dispatch`'s
+                # slot counts
+                self._wave_axis = (cfg.client_axis
+                                   or choose_client_axis(params))
                 stacked, _ = train_cohort(local, params, wave_data, rng,
                                           index_offset=offset,
-                                          client_axis=cfg.client_axis)
+                                          client_axis=self._wave_axis)
                 return stacked, {}
 
             return make_wave_fn(make_stacked, mesh=mesh)
@@ -264,7 +275,8 @@ class CrossDevice(FedAvg):
 
             def make_stacked(params, wave_data, rng, offset):
                 _, aux = train_cohort(nova_local, params, wave_data, rng,
-                                      index_offset=offset)
+                                      index_offset=offset,
+                                      client_axis="vmap")
                 a = jnp.maximum(aux["a_i"], 1e-12)
                 # pseudo-params y_i = x − cum_grad_i/a_i: their weighted
                 # stream mean is x − Σ p_i d_i, so the one mean spine
@@ -493,7 +505,7 @@ class CrossDevice(FedAvg):
                     with self._span("stage.gather"):
                         c_cohort = gather_client_rows(self.c_locals,
                                                       wave.ids, W)
-                with self._span("wave.dispatch"):
+                with self._span("wave.dispatch") as dispatch_sp:
                     offset = jnp.int32(wave.offset)
                     if cfg.local_alg == "scaffold":
                         (stacked, w, mean, total, new_c, c_delta,
@@ -505,6 +517,10 @@ class CrossDevice(FedAvg):
                         stacked, w, mean, total, aux_sums = self._wave_fn(
                             params, wave_data, round_rng, offset)
                         new_c = c_delta = None
+                    if dispatch_sp is not None:
+                        dispatch_sp.set(
+                            slots=W, slots_sequential=(
+                                W if self._wave_axis == "scan" else 0))
                 with self._span("wave.wait", wait="device"):
                     # blocks: the wave ran to completion
                     wave_weight = float(total)

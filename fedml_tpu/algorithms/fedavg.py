@@ -58,13 +58,13 @@ class FedAvgConfig:
     # checkpointer (per-round save cadence needs the host loop) or a
     # _server_update hook (per-round host-side server state, e.g. FedOpt).
     rounds_per_dispatch: int = 1
-    # execution of the cohort's client axis: "vmap" trains all clients
-    # concurrently (per-client conv kernels lower to grouped convs),
-    # "scan" trains them sequentially with dense convs — identical
-    # results (parity-tested); the right engine is hardware-empirical
-    # (bench.py BENCH_R56 grid).  Scan also compiles one client's
-    # program instead of the whole cohort's.
-    client_axis: str = "vmap"
+    # execution of the cohort's client axis: None lets the engine pick
+    # from the model's shapes (`parallel/cohort.choose_client_axis`:
+    # conv models train their clients in sequence with dense convs, the
+    # rest concurrently under vmap); "vmap" / "scan" force one — identical
+    # results (parity-tested), and what the parity tests set.  No CLI flag
+    # reaches it.
+    client_axis: Optional[str] = None
     # evaluate_global processes at most this many clients per compiled
     # call (single-chip and mesh-sharded alike).  The all-clients vmap
     # materializes [C, S, B, ...] activations (an NWP model's logits over

@@ -113,11 +113,11 @@ class FedNova(FedAvg):
     def __init__(self, workload, data, config: FedNovaConfig, mesh=None, sink=None):
         super().__init__(workload, data, config, mesh=mesh, sink=sink)
         cfg = config
-        if cfg.client_axis != "vmap":
+        if cfg.client_axis not in (None, "vmap"):
             # the Nova round has its own train_cohort call sites; a
             # silently-vmapped "scan" request would mislabel the engine
             raise ValueError("client_axis is not wired into FedNova's "
-                             "custom round; drop --client_axis")
+                             "custom round; leave it None")
         local_train = make_fednova_local_trainer(workload, cfg)
         self._gmf_buf = None
 
@@ -128,7 +128,8 @@ class FedNova(FedAvg):
             update (the same two-psum pattern as tree_weighted_psum_mean)."""
             n = cohort_data["num_samples"].astype(jnp.float32)
             _, aux = train_cohort(local_train, global_params, cohort_data,
-                                  rng, index_offset=index_offset)
+                                  rng, index_offset=index_offset,
+                                  client_axis="vmap")
 
             total = jnp.sum(n)
             if psum_axis:

@@ -63,7 +63,8 @@ def make_grouped_round(local_train, group_comm_round: int):
         def body(carry, _):
             p, r = carry
             r, rr = jax.random.split(r)
-            stacked, _ = train_cohort(local_train, p, cohort, rr)
+            stacked, _ = train_cohort(local_train, p, cohort, rr,
+                                      client_axis="vmap")
             p_new = tree_weighted_mean(stacked, safe_w)
             # empty group: no clients -> model unchanged
             p = jax.tree.map(
@@ -124,7 +125,8 @@ def make_two_level_round(local_train, group_comm_round: int, mesh):
             p, r = carry
             r, rr = jax.random.split(r)
             stacked, _ = train_cohort(local_train, p, local, rr,
-                                      index_offset=c * m_loc)
+                                      index_offset=c * m_loc,
+                                      client_axis="vmap")
             # accumulate in f32 and cast back, matching tree_weighted_mean
             # (exact for int leaves, full precision for bf16 params)
             p_new = jax.tree.map(
@@ -168,11 +170,11 @@ class HierarchicalFedAvg(FedAvg):
         cfg = config
         if cfg.group_method != "random":
             raise ValueError(f"unknown group_method {cfg.group_method!r}")
-        if cfg.client_axis != "vmap":
+        if cfg.client_axis not in (None, "vmap"):
             # grouped/two-level rounds vmap inside their own bodies; a
             # silently-ignored "scan" request would mislabel the engine
             raise ValueError("client_axis is not wired into hierarchical "
-                             "FL's grouped rounds; drop --client_axis")
+                             "FL's grouped rounds; leave it None")
         rng = np.random.RandomState(cfg.seed)
         self.group_indexes = rng.randint(0, cfg.group_num, data.client_num)
         if two_level:

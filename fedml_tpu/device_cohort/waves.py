@@ -6,8 +6,9 @@ re-jit every round.  `plan_waves` chops the sampled cohort into
 fixed-size waves (the last one padded with weight-0 slots, the
 `gather_cohort` convention), so every wave of every round hits ONE jit
 cache entry; `make_wave_fn` compiles the wave: local training over the
-stacked client axis (`parallel/cohort.train_cohort` — vmap on one chip,
-shard_map over the mesh's ``clients`` axis), plus the wave SUMMARY the
+stacked client axis (`parallel/cohort.train_cohort` — vmap, or clients in
+sequence for a conv model, on one chip; shard_map over the mesh's
+``clients`` axis), plus the wave SUMMARY the
 host needs for admission/health — the weighted partial mean, the weight
 total, and any per-client aux reductions — computed on device so the
 host never walks the ``[wave, ...]`` stack.

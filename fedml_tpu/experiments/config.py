@@ -156,11 +156,6 @@ class ExperimentConfig:
     #                           --moe_experts (balance loss rides the
     #                           schedule's scan carry)
     pp_microbatches: int = 0  # GPipe microbatches (0 = mesh_stages)
-    client_axis: str = "vmap"  # cohort engine: "vmap" (concurrent
-    #                            clients, grouped convs) | "scan"
-    #                            (sequential clients, dense convs) —
-    #                            identical results, hardware-empirical
-    #                            choice (bench BENCH_R56 grid)
     eval_chunk_clients: int = 1024  # evaluate_global clients per compiled
     #                                 call; bounds eval memory on large
     #                                 corpora (0 = one-shot vmap)

@@ -86,7 +86,6 @@ def _fedavg_cfg_kwargs(cfg: ExperimentConfig) -> Dict[str, Any]:
                 client_optimizer=cfg.client_optimizer, wd=cfg.wd,
                 frequency_of_the_test=freq, seed=cfg.seed,
                 rounds_per_dispatch=cfg.rounds_per_dispatch,
-                client_axis=cfg.client_axis,
                 eval_chunk_clients=cfg.eval_chunk_clients)
 
 

@@ -6,13 +6,16 @@ S2C_SYNC_MODEL to every client process, per-client torch training, C2S
 uploads, an all-received barrier, then a Python aggregation loop
 (FedAvgServerManager.py:45-82, FedAVGAggregator.py:50-87).  Here:
 
-* single chip: `vmap` the local trainer over a stacked client axis — the
-  whole cohort trains in parallel in one jit (what the reference's
-  *sequential* standalone simulator, fedavg_api.py:56-66, wished it could do);
+* single chip: the local trainer runs over a stacked client axis inside
+  one jit — `vmap`, the whole cohort in parallel (what the reference's
+  *sequential* standalone simulator, fedavg_api.py:56-66, wished it could
+  do), or, where the model holds convolutions, one client after another in
+  a `lax.scan` (`choose_client_axis`: grouped convolutions are the slower
+  program on the chip);
 * multi chip: `shard_map` over the mesh's ``clients`` axis — each device
-  trains its shard of the cohort (vmap within), and the weighted aggregation
-  is a `lax.psum` riding ICI.  No threads, queues, pickling, or barriers:
-  the collective IS the barrier.
+  trains its shard of the cohort (the same engine within), and the weighted
+  aggregation is a `lax.psum` riding ICI.  No threads, queues, pickling, or
+  barriers: the collective IS the barrier.
 
 Cohort sizes are static per jit (pad the sampled cohort with weight-0
 clients; see fedml_tpu.data.stacking.gather_cohort), so re-jit pressure is
@@ -35,9 +38,34 @@ CohortData = Dict[str, jax.Array]  # leaves [C, S, B, ...]; "num_samples" [C]
 CohortStep = Callable[..., Tuple[Pytree, Dict[str, jax.Array]]]
 
 
+def choose_client_axis(params: Pytree) -> str:
+    """How `train_cohort` runs the client axis when no engine is named: a
+    pure function of the trained tree's leaf shapes.
+
+    ``"scan"`` when the tree holds a convolution kernel (a rank-4 leaf,
+    flax's ``[kh, kw, in, out]``; dense, LSTM, attention and MoE leaves
+    are rank 1-3), ``"vmap"`` otherwise.  Under ``vmap`` kernels that
+    differ by client turn every convolution into a grouped convolution
+    and every activation carries the clients next to a 16-64 wide
+    channel axis; matmuls become batched matmuls, which the MXU takes as
+    they are, and a B=4 LSTM run client after client would be slower.
+
+    The rule is the one read on a TPU v5e through the CLI and no wider
+    (PERF.md section 6, PR 26 and PR 28), seconds a round ``vmap`` /
+    ``scan``: ResNet-56, 10 silos x B=64, 2.42 / 1.50 at 10,000 rows
+    and 10.1 / 5.9 at 50,000; FEMNIST CNN, cohort 512 in waves of 256 x
+    B=20, 2.32-2.37 / 1.56-1.66.  The sequential program is also the
+    one that agrees with the plain reference (ResNet-56 ``change1_diff``
+    2e-5 against 1e-4 - 5e-3; the vmapped CNN's conv-kernel update reads
+    9-11 % small).  No convolutional shape has been read where ``vmap``
+    wins; one that is goes here, with its reading."""
+    has_conv = any(jnp.ndim(x) == 4 for x in jax.tree.leaves(params))
+    return "scan" if has_conv else "vmap"
+
+
 def train_cohort(local_train, params: Pytree, data: CohortData,
                  rng: jax.Array, index_offset=0, transform_update=None,
-                 client_axis: str = "vmap"):
+                 client_axis: Optional[str] = None):
     """Run ``local_train`` over the stacked client axis.
 
     Per-client rng = fold_in(rng, global cohort slot), so single-chip and
@@ -45,19 +73,20 @@ def train_cohort(local_train, params: Pytree, data: CohortData,
     shared preamble for every cohort-training algorithm (FedAvg cohort step,
     FedNova, gossip) — keep rng/num_samples conventions here only.
 
-    ``client_axis`` picks the execution of that axis; both produce
-    identical stacked outputs:
+    ``client_axis`` is the execution of that axis; both produce
+    identical stacked outputs (bit for bit on the CPU):
 
-    * ``"vmap"`` (default) — all clients train concurrently.  For conv
-      models this batches per-client KERNELS too, which XLA lowers to
-      grouped convolutions: at CIFAR-ResNet channel widths (16/32/64)
-      each group occupies a sliver of the 128-wide MXU tile, so the
-      grouping can dominate the step time.
-    * ``"scan"`` — clients train sequentially via ``lax.scan``; every
-      conv stays a dense, full-batch conv (better MXU tiling per call,
-      no cross-client parallelism).  The right choice is empirical —
-      bench.py measures both for the resnet56 flagship (BENCH_R56 table).
+    * ``"vmap"`` — all clients train concurrently.  For conv models this
+      batches per-client KERNELS too, which XLA lowers to grouped
+      convolutions: at CIFAR-ResNet channel widths (16/32/64) each group
+      occupies a sliver of the 128-wide MXU tile.
+    * ``"scan"`` — clients train one after another via ``lax.scan``;
+      every conv stays a dense conv over one client's batch.
+    * ``None`` (default) — `choose_client_axis` picks from ``params``'
+      shapes, which are static under the trace.
     """
+    if client_axis is None:
+        client_axis = choose_client_axis(params)
     if client_axis not in ("vmap", "scan"):
         raise ValueError(f"client_axis must be 'vmap' or 'scan', "
                          f"got {client_axis!r}")
@@ -94,7 +123,7 @@ def _call_aggregate(aggregate, stacked, weights, global_params, rng):
 def make_cohort_step(local_train, mesh: Optional[Mesh] = None,
                      aggregate=tree_weighted_mean,
                      transform_update=None,
-                     client_axis: str = "vmap") -> CohortStep:
+                     client_axis: Optional[str] = None) -> CohortStep:
     """Build ``step(global_params, cohort_data, rng) -> (new_global, aux)``.
 
     ``local_train(params, client_data, rng) -> (params', metrics)`` is the
@@ -108,8 +137,10 @@ def make_cohort_step(local_train, mesh: Optional[Mesh] = None,
     ``aggregate(stacked_params, weights) -> params`` defaults to the
     sample-weighted FedAvg mean; FedOpt/FedNova swap in their own.
 
-    ``client_axis`` ("vmap" | "scan") — see train_cohort: concurrent
-    clients (grouped convs) vs sequential clients (dense convs).
+    ``client_axis`` (None | "vmap" | "scan") — see train_cohort: chosen
+    from the model's shapes, or forced to concurrent clients (grouped
+    convs) / sequential clients (dense convs; 1.4-1.7 x faster for both
+    conv models read on the chip, `choose_client_axis`).
     """
 
     def _train_cohort(params, data, rng, index_offset=0):
@@ -172,7 +203,7 @@ def make_cohort_step(local_train, mesh: Optional[Mesh] = None,
 
 def make_device_round(local_train, clients_per_round: int,
                       aggregate=tree_weighted_mean, transform_update=None,
-                      client_axis: str = "vmap"):
+                      client_axis: Optional[str] = None):
     """Fully-on-device round: the ENTIRE stacked dataset lives in HBM and
     the sampled cohort is gathered by ids INSIDE the jit — zero per-round
     host<->device traffic (only the [m] ids array crosses).
@@ -207,7 +238,7 @@ def gather_live_cohort(stacked: CohortData, ids, live) -> CohortData:
 
 
 def _device_round_body(local_train, aggregate, transform_update,
-                       client_axis: str = "vmap"):
+                       client_axis: Optional[str] = None):
     """One HBM-resident round: in-jit id gather + live masking + cohort
     train + aggregate.  Shared by make_device_round (K=1, jitted directly)
     and make_scanned_rounds (the lax.scan body), so the two fast paths can
@@ -226,7 +257,8 @@ def _device_round_body(local_train, aggregate, transform_update,
 
 def make_scanned_rounds(local_train, clients_per_round: int,
                         aggregate=tree_weighted_mean,
-                        transform_update=None, client_axis: str = "vmap"):
+                        transform_update=None,
+                        client_axis: Optional[str] = None):
     """K federated rounds per dispatch: `lax.scan` over per-round cohort ids
     with the dataset HBM-resident (make_device_round's gather, iterated on
     device).

@@ -107,15 +107,10 @@ def main():
                   f"{FLAGSHIP_TWIN_KWARGS})")
 
     wl = ClassificationWorkload(resnet56(10), num_classes=10)
-    # scan engine on CPU: compiling the 10-client vmapped resnet56 cohort
-    # takes tens of minutes there; scan compiles ONE client's program
-    # (identical results — parity-tested).  TPU keeps the default.
     cfg = FedAvgConfig(comm_round=rounds, client_num_per_round=10,
                        epochs=epochs, batch_size=64, lr=0.001, wd=0.001,
                        frequency_of_the_test=args.eval_every,
-                       seed=args.seed,
-                       client_axis="scan" if args.platform == "cpu"
-                       else "vmap")
+                       seed=args.seed)
     sink = PartialSink(args.json_out + ".partial",
                        {"rounds": rounds, "epochs": epochs,
                         "samples_per_client": samples, "source": source,

@@ -197,6 +197,49 @@ def test_counts_ride_the_staging_spans(cli_run):
     assert len(padded) == 1 and padded.pop() % 4 == 0
 
 
+def test_slot_counts_ride_the_dispatch_span(cli_run):
+    dispatches = [e for e in cli_run["events"]
+                  if e["name"] == "wave.dispatch"]
+    assert len(dispatches) == 6
+    for d in dispatches:
+        # `lr` holds no convolution: the wave program vmaps its 4 slots
+        assert d["args"]["slots"] == 4
+        assert d["args"]["slots_sequential"] == 0
+
+
+@pytest.mark.parametrize("local_alg,sequential",
+                         [("sgd", 5), ("fednova", 0)])
+def test_dispatch_span_says_how_the_wave_ran_its_clients(local_alg,
+                                                         sequential,
+                                                         tmp_path):
+    """``slots_sequential`` is ``slots`` when the wave program trains its
+    clients one after another (a conv model on the sgd wave, ISSUE 28),
+    else 0 (the fednova wave keeps its own vmap; `lr`, above, holds no
+    convolution); the benchmark's `wave_sequential_slot_share` reads
+    exactly these names."""
+    femnist = load_data("femnist", data_dir=None, batch_size=4,
+                        num_clients=12, samples_per_client=10, seed=0)
+    wl = create_workload("cnn", "femnist", femnist.class_num,
+                         sample_shape_of(femnist))
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    try:
+        CrossDevice(wl, femnist, _cfg(comm_round=1, local_alg=local_alg),
+                    perf=perf).run()
+    finally:
+        perf.close()
+    dispatches = [s for s in perf.tracer.spans
+                  if s["name"] == "wave.dispatch"]
+    assert len(dispatches) == 3      # 12 clients in waves of 5
+    assert [(s["args"]["slots"], s["args"]["slots_sequential"])
+            for s in dispatches] == [(5, sequential)] * 3
+    spec = json.load(open(os.path.join(
+        ROOT, "benchmark", "layer_metrics",
+        "wave_sequential_slot_share.json")))
+    assert spec["reader"] == "benchmark.span_readers:arg_share"
+    assert spec["args"] == {"name": "wave.dispatch",
+                            "part": "slots_sequential", "whole": "slots"}
+
+
 def test_export_keeps_wall_ts_and_raw_monotonic_clock(cli_run):
     other = cli_run["doc"]["otherData"]
     assert other["clock"] == "perf_counter_ns"
