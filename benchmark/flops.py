@@ -8,6 +8,15 @@ weights); recomputed and masked-out work does not count.  Element-wise work
 (norms, activations, pooling, the loss) is left out, as is usual for a
 model-FLOPs utilization, so the figure is a few percent under XLA's own
 count of one forward pass (the unit test holds the two together).
+
+The walk counts what the plain reference computes, and that is what the
+model requires only while the reference computes nothing it then throws
+away.  A plain reference of an expert layer computes every expert for every
+token and masks; the model requires the experts a token is sent to.  Such a
+configuration's ``<name>.py`` exports its own count,
+``forward_macs_per_sample(config, sample_shape)``: the multiply-accumulates
+one sample's forward pass requires, kept with the benchmark beside the model
+it counts.  Where it is there it is used, and the walk otherwise.
 """
 
 from __future__ import annotations
@@ -52,11 +61,13 @@ def _walk(jaxpr) -> float:
     return total
 
 
-def forward_flops_per_sample(model, sample_shape, batch: int = 8) -> float:
-    """Matmul and convolution FLOPs of ``model``'s forward pass, a sample."""
+def forward_flops_per_sample(model, sample_shape, dtype="float32",
+                             batch: int = 8) -> float:
+    """Matmul and convolution FLOPs of ``model``'s forward pass, a sample,
+    for inputs of ``dtype`` (token ids are integers)."""
     import jax
     import jax.numpy as jnp
-    x = jax.ShapeDtypeStruct((batch,) + tuple(sample_shape), jnp.float32)
+    x = jax.ShapeDtypeStruct((batch,) + tuple(sample_shape), jnp.dtype(dtype))
     params = jax.eval_shape(
         lambda: model.init(jax.random.key(0),
                            jnp.zeros(x.shape, x.dtype))["params"])
@@ -65,5 +76,14 @@ def forward_flops_per_sample(model, sample_shape, batch: int = 8) -> float:
     return _walk(jaxpr.jaxpr) / batch
 
 
-def train_flops_per_sample(model, sample_shape) -> float:
-    return 3.0 * forward_flops_per_sample(model, sample_shape)
+def train_flops_per_sample(reference, config: dict, sample_shape,
+                           dtype="float32") -> float:
+    """FLOPs one sample's training step requires: three forward passes'
+    worth.  ``reference`` is the configuration's ``<name>.py``: its own
+    count of required forward multiply-accumulates where it exports one,
+    the walk over its model's jaxpr otherwise."""
+    counted = getattr(reference, "forward_macs_per_sample", None)
+    if counted is not None:
+        return 3.0 * 2.0 * float(counted(config, tuple(sample_shape)))
+    return 3.0 * forward_flops_per_sample(reference.build_model(config),
+                                          sample_shape, dtype)

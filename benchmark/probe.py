@@ -10,8 +10,12 @@ step, CRC, ledger line, sampling of the next cohort) lies between them.
 
 The same wrap hands the comparison what the timed path produced: the global
 model going into the first round and coming out of the first ``keep``
-rounds (device copies, read back after the window), and the cohort ids of
-every round (to count the samples the window consumed).  While the window
+rounds, and the cohort ids of every round (to count the samples the window
+consumed).  The globals are copied to host memory as they are taken, which
+waits for that round's last program; ``keep`` rounds are set-up and lie
+before the window opens (``keep <= first``), so nothing of the harness's
+stays on the chip while ``MemoryWatch`` reads it and the window's rounds are
+entered as if no global had been kept.  While the window
 is open a thread reads the chips' memory counters (``MemoryWatch``), so
 that a cell's memory is what its timed traffic holds and not what set-up
 once took.  With ``trace_dir`` the rounds that follow the window are
@@ -161,6 +165,9 @@ class RoundProbe:
                  trace_dir: Optional[str] = None, n_traced: int = 0,
                  compile_snapshot: Optional[Callable[[], dict]] = None,
                  memory: Optional[MemoryWatch] = None):
+        if keep > first:
+            raise ValueError(f"keep={keep} rounds are set-up's and end "
+                             f"before the window opens at round {first}")
         self.spec = spec
         self.first = first
         self.n_window = n_window
@@ -209,11 +216,11 @@ class RoundProbe:
                 spec.cohorts.append(args[cohort_arg])
             if k == 0 and spec.memory is not None:
                 spec.memory.start()
-            if k == 0 and state_arg is not None:
-                spec.state_in = _device_copy(args[state_arg])
+            if k == 0 and spec.keep and state_arg is not None:
+                spec.state_in = _host_copy(args[state_arg])
             out = original(*args, **kwargs)
             if k < spec.keep and state_out is not None:
-                spec.states_out.append(_device_copy(out[state_out]))
+                spec.states_out.append(_host_copy(out[state_out]))
             return out
 
         return hooked
@@ -272,11 +279,13 @@ class RoundProbe:
                 "traced_cohorts": self.cohorts[b:b + self.n_traced]}
 
 
-def _device_copy(tree):
-    """A copy the program cannot donate away; no host sync."""
+def _host_copy(tree):
+    """A copy in host memory, owned by the harness: the program can
+    neither donate it away nor (on a backend whose arrays live in host
+    memory) write through it.  Waits for the tree to be computed."""
     import jax
-    import jax.numpy as jnp
-    return jax.tree.map(jnp.copy, tree)
+    import numpy as np
+    return jax.tree.map(np.array, tree)
 
 
 def span_wrapper(name: str):

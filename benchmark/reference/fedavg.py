@@ -20,9 +20,15 @@ is what lets it start from the same weights and drop the same units:
   each step:       ck, dropout_key = split(ck)
 
 A client's local run (FedML ``MyModelTrainer``): E=1 pass over its rows in
-order in batches of B (the last one short), mean cross-entropy over the
-batch, gradient clipped to global norm 1, plain SGD.  The round's global is
-the mean of the clients' results weighted by their row counts.
+order in batches of B (the last one short), plain SGD on the batch's mean
+loss.  The round's global is the mean of the clients' results weighted by
+their row counts.  What the loss of a batch is, what norm its gradient is
+clipped to and how the round-0 evaluation is cut into batches is the
+configuration's to state (``task``, read by ``reference/tasks.py``): this
+file holds none of it.  A model that sows terms into flax's ``losses``
+collection while training (an expert layer's balance term, already weighted)
+has them added to the batch's loss; the evaluation leaves them out.  What
+the file does not follow it refuses before any round (``FOLLOWS``).
 
 ``fault`` plants one of the faults the benchmark's comparison has to catch,
 so that their readings can be taken from the reference put in the
@@ -38,6 +44,23 @@ import jax.numpy as jnp
 import numpy as np
 
 FAULTS = ("half_batch", "state_unchanged")
+# the program's options this file follows, by the CLI's own key: any other
+# value is another algorithm, and following this one instead would compare
+# the program with something it was not asked to do
+FOLLOWS = {"client_optimizer": "sgd", "server_opt": "plain", "epochs": 1}
+
+
+def refuse_unfollowed(cli: dict) -> None:
+    """An error that names the key, where the cell's CLI arguments leave
+    out or state otherwise what ``FOLLOWS`` holds."""
+    for key, only in FOLLOWS.items():
+        if key not in cli:
+            raise KeyError(f"the cell's cli states no {key!r}; the "
+                           f"reference follows {key}={only!r} only")
+        if type(only)(cli[key]) != only:
+            raise ValueError(f"the reference follows {key}={only!r} only, "
+                             f"the cell states {key}={cli[key]!r} (see "
+                             f"PERF.md)")
 
 
 def sample_cohort(round_idx: int, population: int, cohort: int) -> np.ndarray:
@@ -56,40 +79,45 @@ def _pad(x: np.ndarray, rows: int) -> np.ndarray:
     return out
 
 
-def make_steps(model, lr: float, clip: float, dtype=None):
-    """(train_step, eval_batch, accumulate) for ``model``.  ``dtype``
-    computes the model in a lower precision: weights and inputs are cast
-    to it, the loss and the update stay float32."""
+def make_steps(model, lr: float, task, dtype=None):
+    """(train_step, eval_batch, accumulate) for ``model`` under ``task``
+    (a ``tasks.Task``).  ``dtype`` computes the model in a lower
+    precision: weights and real-valued inputs are cast to it, the loss and
+    the update stay float32."""
+    clip = task.clip_norm
 
-    def logits_of(params, x, train, rng):
+    def apply(params, x, train, rng):
+        """(logits, what the model sowed into ``losses``)."""
         if dtype is not None:
             params = jax.tree.map(lambda p: p.astype(dtype), params)
-            x = x.astype(dtype)
+            if jnp.issubdtype(x.dtype, jnp.floating):
+                x = x.astype(dtype)
         kw = {"rngs": {"dropout": rng}} if train else {}
-        return model.apply({"params": params}, x, train=train,
-                           **kw).astype(jnp.float32)
-
-    def ce(logits, y):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
+        logits, sown = model.apply({"params": params}, x, train=train,
+                                   mutable=["losses"], **kw)
+        return (logits.astype(jnp.float32),
+                sum(jax.tree.leaves(sown.get("losses", {})), 0.0))
 
     def loss_fn(params, x, y, mask, rng):
-        return (jnp.sum(ce(logits_of(params, x, True, rng), y) * mask)
-                / jnp.maximum(jnp.sum(mask), 1.0))
+        logits, sown = apply(params, x, True, rng)
+        total, weight = task.loss(logits, y, mask)
+        return total / jnp.maximum(weight, 1.0) + sown
 
     @jax.jit
     def train_step(params, x, y, mask, key):
         key, dropout_key = jax.random.split(key)
         grads = jax.grad(loss_fn)(params, x, y, mask, dropout_key)
-        norm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                            for g in jax.tree.leaves(grads)))
-        scale = jnp.where(norm < clip, 1.0, clip / norm)
+        scale = 1.0
+        if clip is not None:
+            norm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                for g in jax.tree.leaves(grads)))
+            scale = jnp.where(norm < clip, 1.0, clip / norm)
         params = jax.tree.map(lambda p, g: p - lr * scale * g, params, grads)
         return params, key
 
     @jax.jit
     def eval_batch(params, x, y, mask):
-        return jnp.sum(ce(logits_of(params, x, False, None), y) * mask)
+        return task.loss(apply(params, x, False, None)[0], y, mask)
 
     @jax.jit
     def accumulate(acc, params, weight):
@@ -98,22 +126,36 @@ def make_steps(model, lr: float, clip: float, dtype=None):
     return train_step, eval_batch, accumulate
 
 
+def eval_batches(clients, rows: int, by: str):
+    """The round-0 evaluation's batches (x, y, mask), each of ``rows``
+    rows: the population's rows one after another, or each client's cut
+    on their own; a short last batch is filled with zero rows."""
+    if by == "population":
+        pools = [(np.concatenate([c[0] for c in clients]),
+                  np.concatenate([c[1] for c in clients]))]
+    else:
+        pools = [c for c in clients if len(c[1])]
+    for xs, ys in pools:
+        for lo in range(0, len(ys), rows):
+            yb = ys[lo:lo + rows]
+            yield (_pad(xs[lo:lo + rows], rows), _pad(yb, rows),
+                   _pad(np.ones(len(yb), np.float32), rows))
+
+
 def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
-        seed: int, rounds: int, cohort: int, batch_size: int, lr: float,
-        epochs: int = 1, clip: float = 1.0, dtype=None,
-        fault: Optional[str] = None, eval_rows: int = 1000,
+        task, seed: int, rounds: int, cohort: int, batch_size: int,
+        lr: float, dtype=None, fault: Optional[str] = None,
         precision: str = "highest",
         log: Callable[[str], None] = lambda s: None) -> dict:
-    """Follow ``rounds`` rounds from the seed.  Returns the globals
-    ``states[0..rounds]`` as host trees and ``loss_r0``, the mean
-    cross-entropy of ``states[1]`` over every client's training rows."""
-    if epochs != 1:
-        raise ValueError("the reference follows E=1 only (see PERF.md)")
+    """Follow ``rounds`` rounds from the seed under ``task`` (a
+    ``tasks.Task``).  Returns the globals ``states[0..rounds]`` as host
+    trees and ``loss_r0``, the task's mean loss of ``states[1]`` over
+    every client's training rows."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
     B = batch_size
     with jax.default_matmul_precision(precision):
-        train_step, eval_batch, accumulate = make_steps(model, lr, clip,
+        train_step, eval_batch, accumulate = make_steps(model, lr, task,
                                                         dtype)
         key = jax.random.key(seed)
         key, init_key = jax.random.split(key)
@@ -151,17 +193,10 @@ def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
             states.append(jax.tree.map(np.asarray, params))
             log(f"reference round {r}: {len(ids)} clients, {int(total)} rows")
             if r == 0:
-                xs = np.concatenate([c[0] for c in clients])
-                ys = np.concatenate([c[1] for c in clients])
-                E = eval_rows
-                parts = []
-                for lo in range(0, len(ys), E):
-                    yb = ys[lo:lo + E]
-                    m = _pad(np.ones(len(yb), np.float32), E)
-                    parts.append(eval_batch(params, _pad(xs[lo:lo + E], E),
-                                            _pad(yb, E), m))
-                loss_r0 = sum(float(v) for v in parts) / len(ys)
-                del xs, ys
+                parts = [eval_batch(params, *b) for b in eval_batches(
+                    clients, task.eval_rows, task.eval_by)]
+                loss_r0 = (sum(float(s) for s, _ in parts)
+                           / sum(float(w) for _, w in parts))
     return {"states": states, "loss_r0": loss_r0}
 
 

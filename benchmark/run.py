@@ -284,10 +284,8 @@ def timed_call(cell: Cell, seed: int, data_dir: str, n: int, watch,
     set-up rounds, ``n`` window rounds, ``n_traced`` rounds under the
     profiler where ``trace_dir`` is given, one more.  Returns the window's
     stamps and cohorts, the program's ledger lines of the window, what the
-    call produced in its first ``KEEP`` rounds (globals g0..gKEEP, read
-    back after the call) and the device's record."""
-    import jax
-    import numpy as np
+    call produced in its first ``KEEP`` rounds (globals g0..gKEEP, taken
+    to the host in set-up) and the device's record."""
     from benchmark.compile_watch import diff
     from benchmark.probe import MemoryWatch, RoundProbe
     run_dir = os.path.join(CACHE, "runs", cell.name)
@@ -301,8 +299,7 @@ def timed_call(cell: Cell, seed: int, data_dir: str, n: int, watch,
                           rounds=probe.rounds_needed, extra=extra), probe)
     win = probe.window()
     device = device_record(cell.chips, memory)
-    states = [jax.tree.map(np.asarray, s)
-              for s in [probe.state_in] + probe.states_out]
+    states = [probe.state_in] + probe.states_out   # host copies already
     probe.state_in, probe.states_out = None, []
     lines = read_jsonl(os.path.join(run_dir, "perf.jsonl"))[KEEP:KEEP + n]
     evals = [r for r in read_jsonl(os.path.join(run_dir, "metrics.jsonl"))
@@ -315,16 +312,20 @@ def timed_call(cell: Cell, seed: int, data_dir: str, n: int, watch,
 
 
 def follow_reference(cell: Cell, clients, pseed: int, **kw) -> dict:
-    """The plain reference over the call's first ``KEEP`` rounds, its
-    products at the precision the configuration states."""
-    from benchmark.reference import fedavg
+    """The plain reference over the call's first ``KEEP`` rounds, under the
+    task the configuration states, its products at the precision the
+    configuration states.  What the reference does not follow (another
+    optimizer, another server step, a task with no plain implementation,
+    a configuration with no task) is an error here, before any round."""
+    from benchmark.reference import fedavg, tasks
     a = cell.cli
+    fedavg.refuse_unfollowed(a)
     kw.setdefault("precision", cell.config["model"]["matmul_precision"])
     return fedavg.run(cell.reference.build_model(cell.config), clients,
-                      seed=pseed, rounds=KEEP,
-                      cohort=int(a["client_num_per_round"]),
+                      task=tasks.from_config(cell.config), seed=pseed,
+                      rounds=KEEP, cohort=int(a["client_num_per_round"]),
                       batch_size=int(a["batch_size"]), lr=float(a["lr"]),
-                      epochs=int(a.get("epochs", 1)), log=say, **kw)
+                      log=say, **kw)
 
 
 def run_cell(bench: dict, workload: str, seed: int, seconds: float,
@@ -365,7 +366,6 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     e2e = window_metrics(edges, samples,
                          setup_s=win["start_wall"] - T_PROCESS_START)
 
-    model = cell.reference.build_model(cell.config)
     args = cell.cli
     ctx = {"cell": cell.name, "chips": cell.chips, "peaks": peaks,
            "edges": edges, "n_rounds": n, "perf_lines": lines,
@@ -373,7 +373,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
            "traced_samples": traced_samples,
            "epochs": int(args.get("epochs", 1)),
            "train_flops_per_sample": flops.train_flops_per_sample(
-               model, clients[0][0].shape[1:]),
+               cell.reference, cell.config, clients[0][0].shape[1:],
+               clients[0][0].dtype),
            "memory_peak_bytes": device["memory_peak_bytes"], "trace": {}}
     result = {"attempted": n, "failed": failed, "device": device}
     if trace:
@@ -427,6 +428,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
               "samples_per_round": samples, "end_to_end": e2e,
               "compiles_in_window": in_window, "recompiles": recompiles,
               "compiles_total": watch.snapshot(), "numbers": numbers,
+              "train_flops_per_sample": ctx["train_flops_per_sample"],
               "program_loss_r0": prog_loss_r0,
               "reference_loss_r0": ref["loss_r0"],
               "reference_s": reference_s,
@@ -438,7 +440,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         json.dump(detail, f)
     print(json.dumps({k: detail[k] for k in (
         "rounds", "t_warm", "end_to_end", "compiles_in_window", "recompiles",
-        "compiles_total", "numbers", "reference_s", "total_s")}))
+        "compiles_total", "numbers", "train_flops_per_sample", "reference_s",
+        "total_s")}))
     result = {"correct": correct, **result,
               "compared": {r["name"]: {"value": r["value"],
                                        "limit": r["limit"]} for r in rows}}

@@ -5,8 +5,9 @@ the compiler's memory analysis.  Costs no chip time; nothing runs.
     JAX_PLATFORMS=cpu python benchmark/tests/compile_for_v5e.py <workload>
 
 The program is built as ``CrossDevice._build_wave_fn`` builds it (the
-sgd/fedprox branch), from the cell's files; the staged wave's shapes are
-the loader's (clients padded to the longest client's step count).
+sgd/fedprox branch), from the cell's files, with the client axis the engine
+chooses itself; the staged wave's shapes are the loader's (clients padded
+to the longest client's step count).
 """
 
 import json
@@ -43,9 +44,10 @@ def main(workload: str, steps: int) -> None:
         int(a["epochs"]))
 
     def make_stacked(params, wave_data, rng, offset):
+        # no client axis named: the engine's own choice from the model's
+        # shapes, as the cell runs it
         stacked, _ = train_cohort(local, params, wave_data, rng,
-                                  index_offset=offset,
-                                  client_axis=a.get("client_axis", "vmap"))
+                                  index_offset=offset)
         return stacked, {}
 
     wave_fn = make_wave_fn(make_stacked)

@@ -1,6 +1,6 @@
 """Test-only entry: a run's control flow end to end on the CPU at a tiny size.
 
-    JAX_PLATFORMS=cpu python benchmark/tests/rehearse.py [femnist|resnet56] [seed]
+    JAX_PLATFORMS=cpu python benchmark/tests/rehearse.py [femnist|resnet56|shakespeare] [seed]
 
 It skips the harness's look for a chip (and nothing else): the tiny
 configuration and traffic files under ``tests/tiny`` go through the same
@@ -20,7 +20,9 @@ FAKE_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
               "hbm_bytes": 16e9}
 CELLS = {"femnist": ("femnist_cnn", "cohort8_wave4", "femnist_cnn.cohort8"),
          "resnet56": ("resnet56_cifar10", "silos3_wave3",
-                      "resnet56_cifar10.silos3")}
+                      "resnet56_cifar10.silos3"),
+         "shakespeare": ("fed_shakespeare_moe", "cohort4_wave2",
+                         "fed_shakespeare_moe.cohort4")}
 
 
 def tiny_bench(which: str) -> dict:

@@ -4,6 +4,8 @@
 
 (the tier-1 command collects ``tests/`` only).  The rehearsal tests drive a
 whole run at a tiny size behind ``rehearse.py``; each takes about a minute.
+``test_reference_seams.py`` holds the tests of what a configuration's files
+state (task, required operations) and of the kept globals.
 """
 
 import contextlib
@@ -267,6 +269,11 @@ FAULTS = {
     # the control: the program's own lower-precision path switched on
     "control_bfloat16": ("--compute_dtype", "bfloat16"),
 }
+# the number that has to refuse each fault, whatever else does: the norm of
+# the difference of the two changes after three rounds, over the whole tree
+REFUSED_BY = {"state_unchanged": "change3_diff", "half_batch": "change3_diff",
+              "control_bfloat16": "change3_diff"}
+SOUND_AT_MOST = 1e-4    # every compared number of a sound run, on the CPU
 
 
 @pytest.mark.parametrize("which", list(rehearse.CELLS))
@@ -288,5 +295,11 @@ def test_rehearsal_is_correct_only_when_sound(fault, which, capfd,
                            "device", "compared"}
     assert list(result)[-1] == "compared"
     assert result["correct"] is (fault == "sound"), result["compared"]
+    if fault == "sound":
+        assert all(row["value"] <= SOUND_AT_MOST
+                   for row in result["compared"].values()), result["compared"]
+    else:
+        row = result["compared"][REFUSED_BY[fault]]
+        assert row["value"] > row["limit"], (fault, result["compared"])
     assert result["failed"] == 0 and result["attempted"] >= run.MIN_ROUNDS
     assert set(result["metrics"]) == {"round_s", "samples_per_s", "setup_s"}

@@ -184,7 +184,7 @@ def test_every_reader_returns_none_on_an_empty_context():
                "trace": {}}
         assert run.call(spec["reader"])(ctx, **spec.get("args", {})) is None
         assert m["workloads"] == ["resnet56_cifar10.silos10"]
-    assert mine == 11
+    assert mine == 12     # ten of PR 27, fold_program_s, PR 28's slot share
 
 
 def test_a_rehearsal_leaves_a_trace_json_the_readers_find(monkeypatch,
