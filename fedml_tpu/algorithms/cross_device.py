@@ -233,6 +233,12 @@ class CrossDevice(FedAvg):
         # wave sets it when it is traced, the scaffold and fednova waves
         # keep their own vmap
         self._wave_axis = "vmap"
+        # every client's count of steps that hold a row, for
+        # `wave.dispatch`'s step counts: read once from the population's
+        # mask, and only where a span will carry them
+        self._real_steps = (
+            np.asarray(data.train["mask"]).any(axis=-1).sum(axis=-1)
+            if self._tracer is not None else None)
 
         self._wave_fn = self._build_wave_fn(workload, cfg, mesh)
         if perf is not None:
@@ -468,6 +474,26 @@ class CrossDevice(FedAvg):
                 c_delta if acc["c_delta"] is None else
                 jax.tree.map(jnp.add, acc["c_delta"], c_delta))
 
+    def _dispatch_counts(self, wave) -> dict:
+        """What rides `wave.dispatch` where a tracer keeps spans, counted
+        on the host with no device read: the wave's static ``slots`` and the
+        client-``steps`` the wave program was handed (slots x steps a
+        slot x epochs); ``slots_sequential`` and ``steps_skipped`` say
+        what the sequential client axis made of them: every slot trained
+        in turn, and every step whose batch holds no row (all of a padded
+        slot's) branched around by the local trainer.  Both are 0 under
+        ``vmap``, where a `cond` lowers to a select over both branches
+        (`make_local_trainer`)."""
+        W, epochs = self.cfg.wave_size, self.cfg.epochs
+        steps = W * self.data.train["mask"].shape[1] * epochs
+        counts = {"slots": W, "slots_sequential": 0,
+                  "steps": steps, "steps_skipped": 0}
+        if self._wave_axis == "scan":
+            counts["slots_sequential"] = W
+            counts["steps_skipped"] = steps - epochs * int(
+                self._real_steps[wave.ids].sum())
+        return counts
+
     def _run_round(self, params, ids, round_rng, round_idx):
         cfg = self.cfg
         W = cfg.wave_size
@@ -517,10 +543,8 @@ class CrossDevice(FedAvg):
                         stacked, w, mean, total, aux_sums = self._wave_fn(
                             params, wave_data, round_rng, offset)
                         new_c = c_delta = None
-                    if dispatch_sp is not None:
-                        dispatch_sp.set(
-                            slots=W, slots_sequential=(
-                                W if self._wave_axis == "scan" else 0))
+                    if self._real_steps is not None:
+                        dispatch_sp.set(**self._dispatch_counts(wave))
                 with self._span("wave.wait", wait="device"):
                     # blocks: the wave ran to completion
                     wave_weight = float(total)

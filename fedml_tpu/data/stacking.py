@@ -142,7 +142,9 @@ def gather_cohort(stacked: Dict[str, Array], client_ids: Sequence[int],
     makes the COMMON case rather than the edge case (pinned in
     tests/test_cross_device.py): a padded slot aliases client 0's rows
     but carries ``mask 0`` and ``num_samples 0``, so the local trainer
-    freezes its params at the round global (every batch fully padded)
+    leaves its params at the round global (every batch holds no row:
+    each step is skipped where the clients train in sequence, computed
+    and thrown away under ``vmap``; `make_local_trainer`)
     and any weighted reduction sees an exact ``+0.0`` — a wave of ALL
     pad slots therefore folds as weight 0, never a 0/0 normalizer.  A
     cohort LARGER than ``pad_to`` is a caller bug (the jit downstream

@@ -81,7 +81,10 @@ def train_cohort(local_train, params: Pytree, data: CohortData,
       convolutions: at CIFAR-ResNet channel widths (16/32/64) each group
       occupies a sliver of the 128-wide MXU tile.
     * ``"scan"`` — clients train one after another via ``lax.scan``;
-      every conv stays a dense conv over one client's batch.
+      every conv stays a dense conv over one client's batch, and a step
+      whose batch holds no row is branched around, not computed
+      (`make_local_trainer`): a client costs its own step count, a
+      padded slot next to nothing.
     * ``None`` (default) — `choose_client_axis` picks from ``params``'
       shapes, which are static under the trace.
     """

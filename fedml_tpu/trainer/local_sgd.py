@@ -113,6 +113,29 @@ def make_local_trainer(workload: Workload,
     (each shard's backward only sees its own logits' contribution to the
     psum'd loss; parallel/sequence.py).
 
+    A step whose batch holds no row (``mask`` sums to 0: a short client
+    padded to the population's step count, every step of a padded cohort
+    slot) leaves params, state and optimizer state as they came; only the
+    key chain advances, so a later step draws the key it would draw
+    anyway.  The step is a `lax.cond` on that sum, and what it costs
+    follows from how the trainer is run:
+
+    * in sequence (one client's jit, the cohort engine's `lax.scan` over a
+      conv model's clients) the predicate is a scalar and XLA emits a
+      conditional: the empty step's forward, backward and update do not
+      run;
+    * under `jax.vmap` the predicate is batched and JAX lowers the `cond`
+      to a select over both branches: every client computes every step
+      and the empty ones are thrown away, as one client alone cannot
+      leave a batched step;
+    * given ``grad_reduce`` the step is computed unconditionally and then
+      frozen by a select: a collective must be entered by every shard.
+
+    ``train_loss_per_step`` reads 0 for an empty step in the first two
+    (the loss function is not asked), and what ``loss_fn`` gives a fully
+    masked batch in the third: the masked mean's 0 plus any term beside
+    it (a mixture's balance term).  The wave engine drops the metric.
+
     ``scan_unroll`` is forwarded to the step `lax.scan` — the default 1
     keeps the compiled program small; bench FLOPs twins pass the full trip
     count so XLA cost analysis (which counts a scan body once) sees every
@@ -149,26 +172,50 @@ def make_local_trainer(workload: Workload,
 
         def step(carry, step_idx):
             trained, state, opt_state, rng = carry
+            # what advances on every step stays outside the branch: the
+            # key chain (a later step draws the key it would draw with no
+            # empty step before it) and the batch's slice
             rng, dropout_rng = jax.random.split(rng)
             batch = jax.tree.map(lambda x: x[step_idx % num_steps], data)
-            (loss, aux), grads = grad_fn(trained, state, batch, dropout_rng)
+            rows = jnp.sum(batch["mask"])
+            got_data = rows > 0
+
+            def do_step(trained, state, opt_state):
+                (loss, aux), grads = grad_fn(trained, state, batch,
+                                             dropout_rng)
+                if grad_reduce is not None:
+                    grads = grad_reduce(grads)
+                if prox_mu:
+                    grads = jax.tree.map(
+                        lambda g, p, p0: g + prox_mu * (p - p0),
+                        grads, trained, init_trained)
+                if clip is not None:
+                    grads, _ = clip.update(grads, clip_state)
+                updates, new_opt_state = optimizer.update(grads, opt_state,
+                                                          trained)
+                new_trained = optax.apply_updates(trained, updates)
+                new_state = aux["state"] if stateful else state
+                return new_trained, new_state, new_opt_state, loss
+
             if grad_reduce is not None:
-                grads = grad_reduce(grads)
-            if prox_mu:
-                grads = jax.tree.map(lambda g, p, p0: g + prox_mu * (p - p0),
-                                     grads, trained, init_trained)
-            if clip is not None:
-                grads, _ = clip.update(grads, clip_state)
-            updates, new_opt_state = optimizer.update(grads, opt_state, trained)
-            new_trained = optax.apply_updates(trained, updates)
-            new_state = aux["state"] if stateful else state
-            # skip the update entirely for fully-padded batches (grads are 0
-            # there anyway for SGD, but Adam's eps would still drift params)
-            got_data = jnp.sum(batch["mask"]) > 0
-            keep = lambda n, o: jax.tree.map(
-                lambda a, b: jnp.where(got_data, a, b), n, o)
-            return (keep(new_trained, trained), keep(new_state, state),
-                    keep(new_opt_state, opt_state), rng), loss
+                # a collective must be entered by every shard, whatever its
+                # own batch holds: compute, then freeze (grads are 0 on a
+                # fully padded batch for SGD, but Adam's eps would still
+                # drift the params)
+                *new, loss = do_step(trained, state, opt_state)
+                trained, state, opt_state = jax.tree.map(
+                    lambda n, o: jnp.where(got_data, n, o),
+                    tuple(new), (trained, state, opt_state))
+                return (trained, state, opt_state, rng), loss
+
+            def keep_carry(trained, state, opt_state):
+                # the zero is made from the mask's sum, not from a literal:
+                # under shard_map both branches must vary over the same axes
+                return trained, state, opt_state, rows * 0
+
+            trained, state, opt_state, loss = jax.lax.cond(
+                got_data, do_step, keep_carry, trained, state, opt_state)
+            return (trained, state, opt_state, rng), loss
 
         total_steps = epochs * num_steps
         (trained, state, _, _), losses = jax.lax.scan(

@@ -205,6 +205,9 @@ def test_slot_counts_ride_the_dispatch_span(cli_run):
         # `lr` holds no convolution: the wave program vmaps its 4 slots
         assert d["args"]["slots"] == 4
         assert d["args"]["slots_sequential"] == 0
+        # ... and so computes every client-step it is handed (ISSUE 33)
+        assert d["args"]["steps"] > 0 and d["args"]["steps"] % 4 == 0
+        assert d["args"]["steps_skipped"] == 0
 
 
 @pytest.mark.parametrize("local_alg,sequential",
@@ -238,6 +241,73 @@ def test_dispatch_span_says_how_the_wave_ran_its_clients(local_alg,
     assert spec["reader"] == "benchmark.span_readers:arg_share"
     assert spec["args"] == {"name": "wave.dispatch",
                             "part": "slots_sequential", "whole": "slots"}
+
+
+# rows of the six clients of `ragged_femnist`, B=4: 5 steps a slot, of
+# which 1, 3, 5, 1, 3, 1 hold a row
+RAGGED_ROWS = (3, 9, 17, 4, 12, 1)
+
+
+@pytest.fixture(scope="module")
+def ragged_femnist():
+    import dataclasses
+    from fedml_tpu.data.stacking import stack_client_data
+    femnist = load_data("femnist", data_dir=None, batch_size=4,
+                        num_clients=len(RAGGED_ROWS), samples_per_client=20,
+                        seed=0)
+    rng = np.random.RandomState(0)
+    xs = [rng.rand(n, 28, 28, 1).astype(np.float32) for n in RAGGED_ROWS]
+    ys = [rng.randint(0, femnist.class_num, n).astype(np.int32)
+          for n in RAGGED_ROWS]
+    return dataclasses.replace(femnist,
+                               train=stack_client_data(xs, ys, batch_size=4))
+
+
+@pytest.mark.parametrize("model,local_alg,epochs,want", [
+    # all six clients in waves of 4: slots 0-3, then slots 4-5 and two
+    # padded ones; a wave is handed 4 x 5 x epochs client-steps and skips
+    # those of them with no row, all of a padded slot's among them
+    ("cnn", "sgd", 1, [(20, 20 - (1 + 3 + 5 + 1)), (20, 20 - (3 + 1))]),
+    ("cnn", "sgd", 2, [(40, 40 - 2 * 10), (40, 40 - 2 * 4)]),
+    ("cnn", "fedprox", 1, [(20, 10), (20, 16)]),
+    # a vmapped wave computes both branches of every step
+    ("lr", "sgd", 1, [(20, 0), (20, 0)]),
+    # ... and these two waves keep a vmap and a local loop of their own
+    ("cnn", "scaffold", 1, [(20, 0), (20, 0)]),
+    ("cnn", "fednova", 1, [(20, 0), (20, 0)]),
+])
+def test_dispatch_span_counts_the_steps_the_trainer_skips(
+        ragged_femnist, model, local_alg, epochs, want, tmp_path):
+    """``steps`` and ``steps_skipped`` on `wave.dispatch` (ISSUE 33) are
+    the hand-counted numbers of a partition with known row counts."""
+    data = ragged_femnist
+    assert data.train["mask"].shape[1:] == (5, 4)
+    wl = create_workload(model, "femnist", data.class_num,
+                         sample_shape_of(data))
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    try:
+        CrossDevice(wl, data, _cfg(
+            comm_round=1, client_num_per_round=6, wave_size=4,
+            epochs=epochs, local_alg=local_alg), perf=perf).run()
+    finally:
+        perf.close()
+    assert [(s["args"]["steps"], s["args"]["steps_skipped"])
+            for s in perf.tracer.spans if s["name"] == "wave.dispatch"] \
+        == want
+
+
+def test_skipped_step_share_reads_the_dispatch_span_as_data():
+    spec = json.load(open(os.path.join(
+        ROOT, "benchmark", "layer_metrics", "wave_skipped_step_share.json")))
+    assert spec["reader"] == "benchmark.span_readers:arg_share"
+    assert spec["args"] == {"name": "wave.dispatch",
+                            "part": "steps_skipped", "whole": "steps"}
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    assert {
+        "name": "wave_skipped_step_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "staging and local training",
+        "moves": "round_s", "workloads": ["resnet56_cifar10.silos10"]} \
+        in bench["per_layer"]
 
 
 def test_export_keeps_wall_ts_and_raw_monotonic_clock(cli_run):
