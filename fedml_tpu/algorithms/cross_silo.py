@@ -235,8 +235,7 @@ class FedAvgServerActor(ServerManager):
         ``encode_once``: broadcast via the transport's ``send_many`` —
         the model bytes serialize ONCE per round no matter how many
         silos are tasked (only the small per-silo header varies).  False
-        restores the seed per-silo encode loop; `scripts/wire_bench.py`
-        measures the two against each other.
+        restores the seed per-silo encode loop.
 
         ``perf``: a `fedml_tpu.obs.perf.PerfRecorder`; when set, every
         round writes one ledger line — phase wall-times
@@ -291,8 +290,7 @@ class FedAvgServerActor(ServerManager):
         buffer entirely (``--agg_mode stream``).  Each admitted upload
         FOLDS into running state on the receive path (the ledger's
         ``fold`` phase) and the barrier-close runs one ``finalize``; no
-        cohort-sized host buffer ever exists, so server peak RSS is
-        flat in cohort size (BENCH_stream.json).  Mutually exclusive
+        cohort-sized host buffer ever exists.  Mutually exclusive
         with ``aggregate_fn`` — the stack path stays behind
         ``--agg_mode stack`` for equivalence pinning (the ``mean``
         results are bit-identical; tests/test_stream_agg.py).

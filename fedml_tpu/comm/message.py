@@ -40,9 +40,8 @@ A torn or truncated frame raises ``ValueError`` from every decode entry
 point — transports catch it, count ``fedml_wire_torn_frames_total``, and
 drop the frame instead of letting a corrupt wire kill a receive thread.
 
-``CODEC_COUNTS`` is the test/bench spy: it counts payload serializations
+``CODEC_COUNTS`` is the test spy: it counts payload serializations
 (the expensive array-section encodes) and per-leaf byte copies, so
-`scripts/wire_bench.py` reports measured copy inventories and
 tests/test_wire.py pins "send_many serializes the shared payload exactly
 once" without reaching into private state.
 """

@@ -87,8 +87,8 @@ def _register_all() -> None:
                      img_twin((784,), 10))
     # the CONVERGENCE-grade MNIST stand-in (class prototypes + noise,
     # LEAF power-law sizes): unlike the shape-only noise twin above, a
-    # model actually learns on it, so benches that gate on
-    # rounds-to-target accuracy (scripts/opt_bench.py) can run hermetic
+    # model actually learns on it, so runs that gate on rounds-to-target
+    # accuracy (tests/test_convergence.py) can run hermetic
     from .synthetic import mnist_learnable_twin
     register_dataset("mnist_learnable_twin", leaf.load_mnist,
                      mnist_learnable_twin)

@@ -2056,7 +2056,7 @@ def compile_cache_dir() -> Optional[str]:
 
 def enable_compile_cache() -> None:
     """Persistent XLA compilation cache — THE one place every entry point
-    (`main`, bench.py, chip_smoke.py) configures it.  Call AFTER platform
+    (`main`, chip_smoke.py) configures it.  Call AFTER platform
     selection (it initializes the backend).  A CPU run is left alone:
     compiles are cheap there and tests churn shapes.  On an accelerator
     the directory is the environment's or `compile_cache_dir`, and every
@@ -2434,9 +2434,7 @@ def main(argv=None) -> Dict[str, Any]:
         raise ValueError(
             "--serve_port starts the serve-while-train frontend, which is "
             f"wired into --algo cross_silo only; --algo {cfg.algo} would "
-            "silently train without serving.  To serve a finished "
-            "checkpoint directory, use scripts/serve_bench.py "
-            "--ckpt_dir instead.")
+            "silently train without serving.")
     if cfg.serve_workers < 1:
         raise ValueError(f"--serve_workers must be >= 1, got "
                          f"{cfg.serve_workers}")

@@ -24,7 +24,7 @@ class RNNOriginalFedAvg(nn.Module):
                             # embed/LSTM/dense (params stay param_dtype f32)
     unroll: int = 1         # lax.scan unroll of the recurrence; >1 only for
                             # FLOPs accounting (XLA cost analysis counts a
-                            # scan body once — see bench.py _honest_flops)
+                            # scan body once)
 
     @nn.compact
     def __call__(self, input_seq, train: bool = False):

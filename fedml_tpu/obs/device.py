@@ -22,14 +22,11 @@ Three instruments, riding the `PerfRecorder` round cadence (one
   or dies on compile-cache discipline).
 * **achieved-FLOP/s + an honest MFU gauge** — XLA ``cost_analysis()``
   FLOPs of the registered hot functions, summed per round and quoted
-  against ONE peak-FLOPS table shared with ``bench.py``
-  (`peak_tflops_for_device` / `compiled_flops` — the offline bench
-  delegates here, pinned by identity in tests/test_device_obs.py, so
-  the bench and the live gauges can never disagree).  The ledger field
-  is named ``mfu`` deliberately: `trend.max_mfu` and the mfu<=1.0
-  timing-trust lint scan it like every committed BENCH artifact.
+  against ONE peak-FLOPS table (`peak_tflops_for_device` /
+  `compiled_flops`).  `trend._validate_device_section` refuses a ledger
+  line whose ``mfu`` is above 1.0.
 
-Honesty contract (the retracted-mfu-1.57 lesson, obs/trend.py):
+Honesty contract (the retracted-mfu-1.57 lesson):
 
 * an unmeasurable quantity ledgers ``null``, never 0;
 * MFU's denominator is the shared device-kind peak table.  The CPU
@@ -59,10 +56,7 @@ from fedml_tpu.obs import telemetry
 log = logging.getLogger(__name__)
 
 # bf16 dense peak by TPU generation (public spec sheets); matched as a
-# substring of jax's device_kind.  Moved here from bench.py so the
-# offline bench and the live device observatory read ONE table (bench
-# imports these back — same drift-proofing as bench._max_mfu ->
-# trend.max_mfu).
+# substring of jax's device_kind.
 PEAK_TFLOPS_BY_KIND = (("v6", 918.0), ("trillium", 918.0), ("v5p", 459.0),
                        ("v5e", 197.0), ("v5lite", 197.0), ("v4", 275.0),
                        ("v3", 123.0), ("v2", 45.0))

@@ -1,8 +1,8 @@
-"""Perf trend gate over flight-recorder ledgers + the timing-trust lint
+"""Perf trend gate over flight-recorder ledgers
 (CLI: ``scripts/perf_trend.py``).
 
-Three checks, each CI-usable (non-zero exit on failure, every verdict
-names the phase/artifact that tripped it):
+Four checks, each CI-usable (non-zero exit on failure, every verdict
+names the phase that tripped it):
 
 * **phase regression** — per-phase medians of the current ``perf.jsonl``
   vs a baseline ledger; a phase beyond ``noise_frac`` AND ``min_abs_s``
@@ -17,84 +17,18 @@ names the phase/artifact that tripped it):
   (relative band + absolute floor, round 0 in scope — compile cost
   lives there).  Pre-device-observatory ledgers compare vacuously, so
   old artifacts never fail the new gate.
-* **mfu lint** — every mfu value in every given JSON artifact must be
-  ≤ 1.0 *or explicitly retracted* (a ``timing_untrusted`` mark on the
-  artifact, or an ``mfu_retracted`` key beside the offending cell).
-  The BENCH_DETAILS mfu-1.57 retraction becomes an automatic check,
-  not an archaeology finding.
 * **health ledger schema** (``--health_ledger``) — the learning-health
   ledger (`obs/health.py`) must carry round/upload accounting, norm
   moments, alignment, and alarm verdicts on every line; a malformed
   ledger fails HERE, not in the reader that trusts it later.
-
-``max_mfu`` here is the single source of truth for "largest MFU
-anywhere in an artifact" (recursive — nested scaling curves included);
-``bench._max_mfu`` delegates to it, so the promotion/carry refusal
-contract and this lint can never disagree about what an artifact
-claims.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob as _glob
 import json
 import statistics
-from typing import Dict, Iterator, List, Optional, Tuple
-
-# markers that make an mfu > 1.0 value an acknowledged retraction
-# instead of a lint violation: artifact-level timing_untrusted (the
-# bench quarantine path writes it), or a sibling mfu_retracted note on
-# the offending cell/any enclosing dict
-RETRACTION_KEYS = ("timing_untrusted", "mfu_retracted")
-
-
-# ---------------------------------------------------------------------------
-# mfu lint
-# ---------------------------------------------------------------------------
-
-def iter_mfu(obj, path: str = "",
-             retracted: bool = False) -> Iterator[Tuple[str, float, bool]]:
-    """Yield ``(json_path, value, retracted)`` for every numeric ``mfu``
-    key anywhere in ``obj``.  ``retracted`` is sticky downward: a
-    retraction marker on any enclosing dict covers its whole subtree."""
-    if isinstance(obj, dict):
-        here = retracted or any(obj.get(k) for k in RETRACTION_KEYS)
-        for k, v in obj.items():
-            if k == "mfu" and isinstance(v, (int, float)):
-                yield f"{path}/mfu", float(v), here
-            else:
-                yield from iter_mfu(v, f"{path}/{k}", here)
-    elif isinstance(obj, list):
-        for i, v in enumerate(obj):
-            yield from iter_mfu(v, f"{path}[{i}]", retracted)
-
-
-def max_mfu(details) -> float:
-    """Largest MFU anywhere in an artifact (recursive; retraction
-    markers do NOT hide values here — an artifact carrying an impossible
-    number stays refusable as evidence even after it owns up to it)."""
-    return max((v for _, v, _ in iter_mfu(details)), default=0.0)
-
-
-def lint_mfu_artifacts(paths: List[str]) -> List[str]:
-    """Violations: unreadable artifacts and unretracted mfu > 1.0 cells.
-    Empty list == lint green."""
-    violations: List[str] = []
-    for path in paths:
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except (OSError, json.JSONDecodeError) as e:
-            violations.append(f"{path}: unreadable ({e})")
-            continue
-        for jpath, value, retracted in iter_mfu(data):
-            if value > 1.0 and not retracted:
-                violations.append(
-                    f"{path}:{jpath} = {value:.3g} > 1.0 — physically "
-                    f"impossible and not marked retracted (add "
-                    f"timing_untrusted or mfu_retracted, or re-capture)")
-    return violations
+from typing import Dict, List, Optional
 
 
 # ---------------------------------------------------------------------------
@@ -236,590 +170,6 @@ def validate_health_ledger(rows: List[dict]) -> List[str]:
     return problems
 
 
-def validate_serve_bench(obj: dict,
-                         allow_smoke: bool = True) -> List[str]:
-    """Schema + honesty check for ``BENCH_serve.json`` v2 (ISSUE 15):
-    the serve path rides the same committed-artifact trend line as every
-    other hot path, so the gate refuses a bench that dropped its
-    acceptance verdicts, lost an arm, mislabeled its backend, or shipped
-    torn responses.  The bench SCRIPT enforces the numeric gates at
-    measurement time and records the verdicts; this validates that an
-    artifact still carries PASSING ones — failed verdicts fail
-    validation unconditionally (a smoke label must not excuse them: the
-    smoke run already records its gates against relaxed thresholds).
-    ``allow_smoke=False`` (the committed-trend-line mode — what
-    ``perf_trend.py --serve_bench`` uses) additionally rejects
-    smoke-labeled artifacts outright, so a /tmp smoke run can never be
-    re-committed as the trend anchor."""
-    problems: List[str] = []
-    if not isinstance(obj, dict):
-        return ["serve bench is not a JSON object"]
-    if obj.get("bench") != "serve":
-        problems.append(f"bench != 'serve' (got {obj.get('bench')!r})")
-    if obj.get("version") != 2:
-        problems.append(f"version != 2 (got {obj.get('version')!r}); "
-                        "v1 artifacts predate the gated-arm format")
-    if obj.get("smoke") and not allow_smoke:
-        problems.append("smoke-labeled artifact on the committed trend "
-                        "line (smoke runs carry relaxed load gates and "
-                        "belong in /tmp, never committed)")
-    arms = obj.get("arms")
-    if not isinstance(arms, dict) or not arms:
-        return problems + ["no arms section"]
-    for name in ("replay", "http", "decode"):
-        if name not in arms:
-            problems.append(f"missing required arm {name!r}")
-    for name, arm in arms.items():
-        if not isinstance(arm, dict):
-            problems.append(f"arm {name!r} is not an object")
-            continue
-        if arm.get("backend") not in ("cpu", "gpu", "tpu"):
-            problems.append(f"arm {name!r}: no honest backend label "
-                            f"(got {arm.get('backend')!r})")
-        gates = arm.get("gates")
-        if not isinstance(gates, dict) or not gates:
-            problems.append(f"arm {name!r}: no recorded gate verdicts")
-            continue
-        for gname, verdict in gates.items():
-            if not isinstance(verdict, dict) or "ok" not in verdict:
-                problems.append(f"arm {name!r}: gate {gname!r} without "
-                                f"an ok verdict")
-            elif not verdict["ok"]:
-                problems.append(f"arm {name!r}: gate {gname!r} FAILED "
-                                f"({verdict})")
-        if "torn_responses" in arm and arm["torn_responses"] != 0:
-            problems.append(f"arm {name!r}: {arm['torn_responses']} torn "
-                            f"responses committed")
-    return problems
-
-
-def validate_release_bench(obj: dict,
-                           allow_smoke: bool = True) -> List[str]:
-    """Schema + honesty check for ``BENCH_release.json`` v1 (ISSUE 16):
-    the train-to-serve release gate rides the same committed-artifact
-    trend line as the serve bench.  The bench SCRIPT enforces the
-    numeric gates at measurement time; this validates an artifact still
-    carries PASSING verdicts for both arms — and re-derives the two
-    claims a regenerated artifact must never lose: zero responses
-    served from the poisoned version, and zero recompiles after
-    warmup.  ``allow_smoke=False`` (the committed-trend-line mode)
-    rejects smoke-labeled artifacts outright."""
-    problems: List[str] = []
-    if not isinstance(obj, dict):
-        return ["release bench is not a JSON object"]
-    if obj.get("bench") != "release":
-        problems.append(f"bench != 'release' (got {obj.get('bench')!r})")
-    if obj.get("version") != 1:
-        problems.append(f"version != 1 (got {obj.get('version')!r})")
-    if obj.get("smoke") and not allow_smoke:
-        problems.append("smoke-labeled artifact on the committed trend "
-                        "line (smoke runs carry relaxed load gates and "
-                        "belong in /tmp, never committed)")
-    arms = obj.get("arms")
-    if not isinstance(arms, dict) or not arms:
-        return problems + ["no arms section"]
-    for name in ("pipeline", "crash_promote"):
-        if name not in arms:
-            problems.append(f"missing required arm {name!r}")
-    for name, arm in arms.items():
-        if not isinstance(arm, dict):
-            problems.append(f"arm {name!r} is not an object")
-            continue
-        if arm.get("backend") not in ("cpu", "gpu", "tpu"):
-            problems.append(f"arm {name!r}: no honest backend label "
-                            f"(got {arm.get('backend')!r})")
-        gates = arm.get("gates")
-        if not isinstance(gates, dict) or not gates:
-            problems.append(f"arm {name!r}: no recorded gate verdicts")
-            continue
-        for gname, verdict in gates.items():
-            if not isinstance(verdict, dict) or "ok" not in verdict:
-                problems.append(f"arm {name!r}: gate {gname!r} without "
-                                f"an ok verdict")
-            elif not verdict["ok"]:
-                problems.append(f"arm {name!r}: gate {gname!r} FAILED "
-                                f"({verdict})")
-    pipe = arms.get("pipeline")
-    if isinstance(pipe, dict) and "error" not in pipe:
-        served = pipe.get("responses_by_version", {})
-        pv = pipe.get("poisoned_version")
-        if pv is not None and served.get(str(pv), 0) != 0:
-            problems.append(f"pipeline: {served[str(pv)]} responses "
-                            f"served from poisoned version {pv}")
-        if pipe.get("recompiles_after_warmup", 0) != 0:
-            problems.append(f"pipeline: "
-                            f"{pipe['recompiles_after_warmup']} "
-                            f"recompiles after warmup committed")
-    return problems
-
-
-def validate_ingest_bench(obj: dict,
-                          allow_smoke: bool = True) -> List[str]:
-    """Schema + honesty check for ``BENCH_ingest.json`` v1 (ISSUE 17):
-    the round critical-path observatory's committed artifact.  The bench
-    SCRIPT enforces the numeric gates at measurement time; this
-    validates an artifact still carries PASSING verdicts — and
-    re-derives the claims a regenerated artifact must never lose: every
-    round of every traffic arm carries a well-formed ``critical_path``
-    record whose attribution covers >= 95%% of the round's wall clock,
-    zero recompiles after warmup with tracing enabled, and a green
-    disabled-mode overhead pin.  ``allow_smoke=False`` (the
-    committed-trend-line mode — ``perf_trend.py --ingest_bench``)
-    rejects smoke-labeled artifacts outright."""
-    problems: List[str] = []
-    if not isinstance(obj, dict):
-        return ["ingest bench is not a JSON object"]
-    if obj.get("bench") != "ingest":
-        problems.append(f"bench != 'ingest' (got {obj.get('bench')!r})")
-    if obj.get("version") != 1:
-        problems.append(f"version != 1 (got {obj.get('version')!r})")
-    if obj.get("smoke") and not allow_smoke:
-        problems.append("smoke-labeled artifact on the committed trend "
-                        "line (smoke runs carry relaxed scale and belong "
-                        "in /tmp, never committed)")
-    arms = obj.get("arms")
-    if not isinstance(arms, dict) or not arms:
-        return problems + ["no arms section"]
-    for name in ("cross_silo", "cross_device", "sharded", "secagg",
-                 "disabled_pin"):
-        if name not in arms:
-            problems.append(f"missing required arm {name!r}")
-    from fedml_tpu.obs import critical_path as _cpath
-    for name, arm in arms.items():
-        if not isinstance(arm, dict):
-            problems.append(f"arm {name!r} is not an object")
-            continue
-        if arm.get("backend") not in ("cpu", "gpu", "tpu"):
-            problems.append(f"arm {name!r}: no honest backend label "
-                            f"(got {arm.get('backend')!r})")
-        gates = arm.get("gates")
-        if not isinstance(gates, dict) or not gates:
-            problems.append(f"arm {name!r}: no recorded gate verdicts")
-            continue
-        for gname, verdict in gates.items():
-            if not isinstance(verdict, dict) or "ok" not in verdict:
-                problems.append(f"arm {name!r}: gate {gname!r} without "
-                                f"an ok verdict")
-            elif not verdict["ok"]:
-                problems.append(f"arm {name!r}: gate {gname!r} FAILED "
-                                f"({verdict})")
-        if name == "disabled_pin":
-            continue   # the pin arm runs no rounds
-        rounds = arm.get("rounds")
-        if not isinstance(rounds, list) or not rounds:
-            problems.append(f"arm {name!r}: no per-round critical_path "
-                            f"records")
-            continue
-        for i, rec in enumerate(rounds):
-            problems += _cpath.validate_record(
-                rec, path=f"arm {name!r} round {i}")
-            cov = rec.get("coverage") if isinstance(rec, dict) else None
-            if isinstance(cov, (int, float)) and cov < 0.95:
-                problems.append(f"arm {name!r} round {i}: attribution "
-                                f"covers {cov:.0%} of the round wall "
-                                f"clock (< 95%)")
-        if arm.get("recompiles_after_warmup", 0) != 0:
-            problems.append(f"arm {name!r}: "
-                            f"{arm['recompiles_after_warmup']} recompiles "
-                            f"after warmup with tracing enabled")
-    problems += _validate_ingest_pipeline(obj.get("pipeline"),
-                                          smoke=bool(obj.get("smoke")))
-    return problems
-
-
-def _validate_ingest_pipeline(pipe, smoke: bool = False) -> List[str]:
-    """Re-derive the `--ingest_pipeline` twins' gates (ISSUE 20) from
-    the committed rows themselves — a regenerated artifact cannot carry
-    a green verdict its own rows contradict.  The claims: every twin's
-    pipelined global is bit-equal to inline (the per-round crc32
-    sequence matches exactly), zero recompiles after warmup, the waves
-    twin hides aggregation behind upload production
-    (fold_overlap_ratio >= 0.99, round wall clock <= 1.15x pure network
-    time), the replicated twin drains the wire at least as fast as
-    inline, and the arena + fused screen key one compile-ledger entry
-    each.  Smoke artifacts skip the noise-sensitive numeric
-    re-derivations (they run at relaxed scale) but never reach the
-    committed trend line — ``allow_smoke=False`` already refused them."""
-    problems: List[str] = []
-    if not isinstance(pipe, dict):
-        return ["no pipeline section (the --ingest_pipeline twins are a "
-                "required part of BENCH_ingest.json)"]
-    twins = pipe.get("twins")
-    if not isinstance(twins, dict):
-        return ["pipeline: no twins section"]
-    for tname in ("waves", "replicated", "sharded"):
-        if tname not in twins:
-            problems.append(f"pipeline: missing required twin {tname!r}")
-    for tname, twin in twins.items():
-        if not isinstance(twin, dict):
-            problems.append(f"pipeline twin {tname!r} is not an object")
-            continue
-        gates = twin.get("gates")
-        if not isinstance(gates, dict) or not gates:
-            problems.append(f"pipeline twin {tname!r}: no gate verdicts")
-            continue
-        for gname, verdict in gates.items():
-            if not isinstance(verdict, dict) or "ok" not in verdict:
-                problems.append(f"pipeline twin {tname!r}: gate "
-                                f"{gname!r} without an ok verdict")
-            elif not verdict["ok"]:
-                problems.append(f"pipeline twin {tname!r}: gate "
-                                f"{gname!r} FAILED ({verdict})")
-        rows_in = (twin.get("inline") or {}).get("rows")
-        rows_pi = (twin.get("pipelined") or {}).get("rows")
-        if not (isinstance(rows_in, list) and rows_in
-                and isinstance(rows_pi, list) and rows_pi):
-            problems.append(f"pipeline twin {tname!r}: missing per-round "
-                            f"rows (the gates must be re-derivable)")
-            continue
-        crc_in = [r.get("global_crc") for r in rows_in]
-        crc_pi = [r.get("global_crc") for r in rows_pi]
-        if crc_in != crc_pi or any(c is None for c in crc_in):
-            problems.append(f"pipeline twin {tname!r}: rows contradict "
-                            f"bit-parity (crc {crc_in} vs {crc_pi})")
-        warm = rows_pi[1:]
-        rec = sum(r.get("recompiles", 0) for r in warm)
-        if rec:
-            problems.append(f"pipeline twin {tname!r}: rows carry {rec} "
-                            f"recompiles after warmup")
-        if smoke:
-            continue   # relaxed-scale rows: structural claims only
-        if tname == "waves" and warm:
-            min_ov = min(r.get("fold_overlap_ratio") or 0.0 for r in warm)
-            if min_ov < 0.99:
-                problems.append(f"pipeline twin 'waves': rows re-derive "
-                                f"fold_overlap_ratio {min_ov:.4f} < 0.99")
-            ratios = [r["round_s"] / r["last_arrival_s"] for r in warm
-                      if r.get("last_arrival_s") and r.get("round_s")]
-            if not ratios or max(ratios) > 1.15:
-                problems.append(
-                    f"pipeline twin 'waves': round wall clock is "
-                    f"{max(ratios) if ratios else 'unknown'}x pure "
-                    f"network time (> 1.15x)")
-        if tname == "replicated" and warm:
-            def _bps(rows):
-                net = sum(r.get("last_arrival_s") or 0.0 for r in rows)
-                return (sum(r.get("bytes_in") or 0 for r in rows) / net
-                        if net > 0 else 0.0)
-            bps_in, bps_pi = _bps(rows_in[1:]), _bps(warm)
-            if bps_in <= 0 or bps_pi < bps_in:
-                problems.append(f"pipeline twin 'replicated': rows "
-                                f"re-derive pipelined wire drain "
-                                f"{bps_pi:.0f} B/s < inline "
-                                f"{bps_in:.0f} B/s")
-        if tname in ("replicated", "sharded"):
-            sizes = (twin.get("pipelined") or {}).get("jit_cache_sizes")
-            keys = sorted(k for k in (sizes or {})
-                          if k.startswith("ingest")
-                          and (k.endswith("_arena")
-                               or k.endswith("_screen")))
-            want = 8 if tname == "sharded" else 2
-            if len(keys) != want or any(sizes[k] != 1 for k in keys):
-                problems.append(f"pipeline twin {tname!r}: arena/screen "
-                                f"jits do not key exactly one ledger "
-                                f"entry each ({keys})")
-    return problems
-
-
-def _opt_rounds_to_target(curve, target):
-    """First (1-based) round count at which the committed accuracy
-    curve reaches the target; None when it never does."""
-    for r, acc in curve:
-        if acc >= target:
-            return int(r) + 1
-    return None
-
-
-def validate_opt_bench(obj: dict, allow_smoke: bool = True) -> List[str]:
-    """Schema + honesty check for ``BENCH_opt.json`` v1 (ISSUE 18): the
-    server-optimizer spine's committed convergence contract.  The bench
-    SCRIPT enforces the gates at measurement time; this validates an
-    artifact still carries PASSING verdicts — and RE-DERIVES the
-    headline claims from the committed per-round accuracy curves rather
-    than trusting the summary numbers: on >= 2 workloads the optimizer
-    arm reaches the workload's stated target accuracy in >= 1.5x fewer
-    rounds than plain FedAvg (same seed, same data), its final accuracy
-    is no worse than plain's minus the stated tolerance, zero recompiles
-    after warmup under ``--perf_strict`` on every arm, and the adaptive
-    controller's decision is on every optimizer-arm ledger round.
-    ``allow_smoke=False`` (the committed-trend-line mode —
-    ``perf_trend.py --opt_bench``) rejects smoke-labeled artifacts
-    outright."""
-    problems: List[str] = []
-    if not isinstance(obj, dict):
-        return ["opt bench is not a JSON object"]
-    if obj.get("bench") != "opt":
-        problems.append(f"bench != 'opt' (got {obj.get('bench')!r})")
-    if obj.get("version") != 1:
-        problems.append(f"version != 1 (got {obj.get('version')!r})")
-    smoke = bool(obj.get("smoke"))
-    if smoke and not allow_smoke:
-        problems.append("smoke-labeled artifact on the committed trend "
-                        "line (smoke runs carry relaxed scale and belong "
-                        "in /tmp, never committed)")
-    wls = obj.get("workloads")
-    if not isinstance(wls, dict) or not wls:
-        return problems + ["no workloads section"]
-    if len(wls) < 2:
-        problems.append(f"only {len(wls)} workload(s); the claim needs "
-                        f">= 2")
-    for name, wl in wls.items():
-        if not isinstance(wl, dict):
-            problems.append(f"workload {name!r} is not an object")
-            continue
-        target = wl.get("target_acc")
-        if not isinstance(target, (int, float)):
-            problems.append(f"workload {name!r}: no target_acc")
-            continue
-        arms = wl.get("arms")
-        if not isinstance(arms, dict) or "plain" not in arms \
-                or len(arms) != 2:
-            problems.append(f"workload {name!r}: needs exactly a "
-                            f"'plain' arm and one optimizer arm")
-            continue
-        opt_name = next(a for a in arms if a != "plain")
-        if opt_name not in ("momentum", "adam", "fedac"):
-            problems.append(f"workload {name!r}: unknown optimizer arm "
-                            f"{opt_name!r}")
-        rtt = {}
-        for aname, arm in arms.items():
-            if not isinstance(arm, dict):
-                problems.append(f"workload {name!r} arm {aname!r}: not "
-                                f"an object")
-                continue
-            if arm.get("backend") not in ("cpu", "gpu", "tpu"):
-                problems.append(f"workload {name!r} arm {aname!r}: no "
-                                f"honest backend label "
-                                f"(got {arm.get('backend')!r})")
-            curve = arm.get("test_acc_by_round")
-            if not (isinstance(curve, list) and curve
-                    and all(isinstance(p, list) and len(p) == 2
-                            for p in curve)):
-                problems.append(f"workload {name!r} arm {aname!r}: no "
-                                f"committed per-round accuracy curve")
-                continue
-            rtt[aname] = _opt_rounds_to_target(curve, target)
-            if arm.get("recompiles_after_warmup", 0) != 0:
-                problems.append(
-                    f"workload {name!r} arm {aname!r}: "
-                    f"{arm['recompiles_after_warmup']} recompiles after "
-                    f"warmup under --perf_strict")
-        gates = wl.get("gates")
-        if not isinstance(gates, dict) or not gates:
-            problems.append(f"workload {name!r}: no recorded gate "
-                            f"verdicts")
-            continue
-        for gname, verdict in gates.items():
-            if not isinstance(verdict, dict) or "ok" not in verdict:
-                problems.append(f"workload {name!r}: gate {gname!r} "
-                                f"without an ok verdict")
-            elif not verdict["ok"]:
-                problems.append(f"workload {name!r}: gate {gname!r} "
-                                f"FAILED ({verdict})")
-        if smoke:
-            continue   # relaxed scale: curves too short to re-derive
-        # re-derive the headline claims from the raw curves
-        if rtt.get("plain") is None:
-            problems.append(f"workload {name!r}: plain never reaches "
-                            f"the target accuracy {target}")
-        if len(rtt) == 2 and None not in rtt.values():
-            p, o = rtt["plain"], rtt[opt_name]
-            thr = float(gates.get("speedup", {}).get("threshold", 1.5))
-            if p < thr * o:
-                problems.append(
-                    f"workload {name!r}: rounds-to-target {p} (plain) "
-                    f"vs {o} ({opt_name}) — ratio {p / o:.2f} < {thr}")
-        finals = {a: arm["test_acc_by_round"][-1][1]
-                  for a, arm in arms.items()
-                  if isinstance(arm, dict)
-                  and isinstance(arm.get("test_acc_by_round"), list)
-                  and arm["test_acc_by_round"]}
-        tol = float(gates.get("final_accuracy_not_worse", {})
-                    .get("tolerance", 0.02))
-        if len(finals) == 2 \
-                and finals[opt_name] < finals["plain"] - tol:
-            problems.append(
-                f"workload {name!r}: {opt_name} final accuracy "
-                f"{finals[opt_name]:.3f} worse than plain "
-                f"{finals['plain']:.3f} - {tol}")
-        opt_arm = arms.get(opt_name)
-        if isinstance(opt_arm, dict):
-            n_adapt = opt_arm.get("adapt_rounds")
-            n_ledger = opt_arm.get("ledger_rounds")
-            if not (isinstance(n_adapt, int) and isinstance(n_ledger, int)
-                    and n_ledger > 0 and n_adapt == n_ledger):
-                problems.append(
-                    f"workload {name!r}: controller decisions on "
-                    f"{n_adapt!r} of {n_ledger!r} ledger rounds — the "
-                    f"adaptive decision must be visible on every round")
-    return problems
-
-
-def validate_degrade_bench(obj: dict, allow_smoke: bool = True) -> List[str]:
-    """Schema + honesty check for ``BENCH_degrade.json`` v1 (ISSUE 19):
-    the sustained-degradation soak's committed survivability contract.
-    The soak SCRIPT (scripts/degrade_soak.py) enforces the gates at
-    measurement time; this validates an artifact still carries PASSING
-    verdicts — and RE-DERIVES the headline claims from the committed
-    per-round rows rather than trusting the summary numbers:
-
-    * ZERO network- or unknown-attributed trust strikes (the fault
-      attribution invariant — flaky links never look Byzantine);
-    * the adaptive deadline undercuts the static timeout cap on >= 80%%
-      of warm rounds (rounds past ``warmup_rounds``), and round
-      wall-clock tracks it (wall <= deadline + slack on those rounds);
-    * bounded starvation — no honest silo's rounds-since-last-accept
-      ever exceeded the stated bound (debt-priority re-tasking works);
-    * the degraded arm's final global lands within the stated tolerance
-      of the chaos-free clean arm;
-    * zero recompiles after warmup under ``--perf_strict`` on every
-      measured arm;
-    * the mid-soak kill resumed to the SAME derived deadline (the
-      deadline is a pure function of ledgered history).
-
-    ``allow_smoke=False`` (the committed-trend-line mode —
-    ``perf_trend.py --degrade_bench``) rejects smoke-labeled artifacts
-    outright."""
-    problems: List[str] = []
-    if not isinstance(obj, dict):
-        return ["degrade bench is not a JSON object"]
-    if obj.get("bench") != "degrade":
-        problems.append(f"bench != 'degrade' (got {obj.get('bench')!r})")
-    if obj.get("version") != 1:
-        problems.append(f"version != 1 (got {obj.get('version')!r})")
-    smoke = bool(obj.get("smoke"))
-    if smoke and not allow_smoke:
-        problems.append("smoke-labeled artifact on the committed trend "
-                        "line (smoke runs carry relaxed scale and belong "
-                        "in /tmp, never committed)")
-    arms = obj.get("arms")
-    if not isinstance(arms, dict):
-        return problems + ["no arms section"]
-    for req in ("clean", "static", "degrade"):
-        if req not in arms or not isinstance(arms[req], dict):
-            problems.append(f"missing arm {req!r} (needs clean, static "
-                            f"and degrade)")
-    for aname, arm in arms.items():
-        if isinstance(arm, dict) and arm.get("backend") not in (
-                "cpu", "gpu", "tpu"):
-            problems.append(f"arm {aname!r}: no honest backend label "
-                            f"(got {arm.get('backend')!r})")
-    gates = obj.get("gates")
-    if not isinstance(gates, dict) or not gates:
-        problems.append("no recorded gate verdicts")
-        gates = {}
-    for gname, verdict in gates.items():
-        if not isinstance(verdict, dict) or "ok" not in verdict:
-            problems.append(f"gate {gname!r} without an ok verdict")
-        elif not verdict["ok"]:
-            problems.append(f"gate {gname!r} FAILED ({verdict})")
-    deg = arms.get("degrade")
-    if not isinstance(deg, dict):
-        return problems
-    # -- attribution invariant: re-derive from the committed totals -----
-    sft = deg.get("strike_fault_totals")
-    if not isinstance(sft, dict):
-        problems.append("degrade arm: no strike_fault_totals — the "
-                        "zero-network-strikes claim cannot be re-derived")
-    else:
-        for cls in ("network", "unknown"):
-            if sft.get(cls, 0) != 0:
-                problems.append(
-                    f"degrade arm: {sft[cls]} {cls}-attributed trust "
-                    f"strike(s) — connectivity faults must NEVER strike")
-    # -- recompile silence on every measured arm ------------------------
-    for aname in ("static", "degrade"):
-        arm = arms.get(aname)
-        if isinstance(arm, dict) \
-                and arm.get("recompiles_after_warmup", 0) != 0:
-            problems.append(
-                f"arm {aname!r}: {arm['recompiles_after_warmup']} "
-                f"recompiles after warmup under --perf_strict")
-    if smoke:
-        return problems   # relaxed scale: too few rounds to re-derive
-    # -- adaptive deadline vs the static cap, from the raw rows ---------
-    cap = obj.get("round_timeout_s")
-    warmup = int(obj.get("warmup_rounds", 0) or 0)
-    rows = deg.get("rounds")
-    if not (isinstance(rows, list) and rows
-            and all(isinstance(r, dict) for r in rows)):
-        problems.append("degrade arm: no committed per-round rows")
-    elif isinstance(cap, (int, float)):
-        warm = [r for r in rows
-                if isinstance(r.get("round"), int)
-                and r["round"] >= warmup
-                and isinstance(r.get("deadline_s"), (int, float))]
-        if not warm:
-            problems.append(f"degrade arm: no warm rounds past "
-                            f"warmup_rounds={warmup} carry a derived "
-                            f"deadline")
-        else:
-            thr = float(gates.get("adaptive_beats_static", {})
-                        .get("threshold", 0.8))
-            under = sum(1 for r in warm if r["deadline_s"] < float(cap))
-            frac = under / len(warm)
-            if frac < thr:
-                problems.append(
-                    f"adaptive deadline < static cap {cap}s on only "
-                    f"{frac:.0%} of {len(warm)} warm rounds "
-                    f"(claim needs >= {thr:.0%})")
-            slack = float(gates.get("deadline_tracks_wall", {})
-                          .get("slack_s", 0.5))
-            # partition-hold rounds legitimately exceed the deadline
-            # (bounded by partition_max_holds) — excluded from tracking
-            nohold = [r for r in warm if not r.get("holds")]
-            tracked = sum(1 for r in nohold
-                          if isinstance(r.get("wall_s"), (int, float))
-                          and r["wall_s"] <= r["deadline_s"] + slack)
-            if nohold and tracked / len(nohold) < thr:
-                problems.append(
-                    f"round wall-clock within deadline+{slack}s on only "
-                    f"{tracked}/{len(nohold)} warm hold-free rounds — the "
-                    f"adaptive deadline is not tracking real round cost")
-    else:
-        problems.append("no round_timeout_s (static cap) committed — "
-                        "the adaptive-beats-static claim cannot be "
-                        "re-derived")
-    # -- bounded starvation, from the committed per-silo maxima ---------
-    starve = deg.get("max_rounds_since_accept")
-    bound = gates.get("bounded_starvation", {}).get("bound")
-    if not isinstance(starve, dict) or not starve:
-        problems.append("degrade arm: no max_rounds_since_accept — the "
-                        "bounded-starvation claim cannot be re-derived")
-    elif isinstance(bound, (int, float)):
-        for silo, worst in starve.items():
-            if worst > bound:
-                problems.append(
-                    f"honest silo {silo} went {worst} rounds without an "
-                    f"accepted upload (bound {bound})")
-    # -- convergence vs the chaos-free clean arm ------------------------
-    delta = deg.get("final_delta_vs_clean")
-    tol = gates.get("convergence_vs_clean", {}).get("tolerance")
-    if not isinstance(delta, (int, float)):
-        problems.append("degrade arm: no final_delta_vs_clean")
-    elif isinstance(tol, (int, float)) and delta > tol:
-        problems.append(f"degraded final global {delta} from the clean "
-                        f"arm (tolerance {tol})")
-    # -- the kill re-derived the SAME deadline --------------------------
-    res = deg.get("resume")
-    if not isinstance(res, dict):
-        problems.append("degrade arm: no resume section — the mid-soak "
-                        "kill + deadline-determinism claim is missing")
-    else:
-        pre, post = res.get("deadline_pre_kill"), \
-            res.get("deadline_post_resume")
-        if not (isinstance(pre, (int, float))
-                and isinstance(post, (int, float))):
-            problems.append("degrade arm resume: deadline_pre_kill / "
-                            "deadline_post_resume not both recorded")
-        elif abs(pre - post) > 1e-9:
-            problems.append(
-                f"resumed round re-derived deadline {post}s != {pre}s "
-                f"pre-kill — the deadline is not a pure function of "
-                f"ledgered history")
-    return problems
-
-
 def phase_medians(rows: List[dict],
                   skip_first: bool = True) -> Dict[str, float]:
     """Median per-phase seconds across the ledger (plus ``round_s``).
@@ -933,21 +283,12 @@ def compare_ledgers(current: List[dict], baseline: List[dict],
 # CLI
 # ---------------------------------------------------------------------------
 
-def _expand(patterns: List[str]) -> List[str]:
-    paths: List[str] = []
-    for pat in patterns:
-        # a pattern matching nothing passes through verbatim — the lint
-        # then reports it unreadable, loudly
-        paths.extend(sorted(_glob.glob(pat)) or [pat])
-    return paths
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="perf_trend",
-        description="Perf regression gate over flight-recorder ledgers "
-                    "(+ the mfu<=1.0 timing-trust lint). Exit 0 = pass, "
-                    "1 = regression/lint failure, 2 = missing inputs.")
+        description="Perf regression gate over flight-recorder ledgers. "
+                    "Exit 0 = pass, 1 = regression or malformed ledger, "
+                    "2 = missing inputs.")
     p.add_argument("--ledger", default=None,
                    help="current run's perf.jsonl")
     p.add_argument("--baseline", default=None,
@@ -958,9 +299,6 @@ def main(argv=None) -> int:
                         "as a regression (default 0.25 = +25%%)")
     p.add_argument("--min_abs_ms", type=float, default=5.0,
                    help="absolute floor (ms) a regression must also exceed")
-    p.add_argument("--lint_mfu", nargs="*", default=None, metavar="GLOB",
-                   help="JSON artifacts (globs ok) to lint for "
-                        "unretracted mfu > 1.0")
     p.add_argument("--no_recompile_gate", action="store_true",
                    help="skip the recompiles-after-round-0 gate")
     p.add_argument("--no_device_gate", action="store_true",
@@ -976,51 +314,11 @@ def main(argv=None) -> int:
                    help="health.jsonl to schema-validate (obs/health.py): "
                         "a malformed health ledger fails the gate, not "
                         "the reader that trusts it later")
-    p.add_argument("--serve_bench", default=None,
-                   help="BENCH_serve.json (v2) to validate: required "
-                        "arms present, honest backend labels, recorded "
-                        "gate verdicts all passing, zero torn responses")
-    p.add_argument("--release_bench", default=None,
-                   help="BENCH_release.json (v1) to validate: both arms "
-                        "present, honest backend labels, recorded gate "
-                        "verdicts all passing, zero responses from the "
-                        "poisoned version, zero recompiles after warmup")
-    p.add_argument("--ingest_bench", default=None,
-                   help="BENCH_ingest.json (v1) to validate: every "
-                        "traffic arm present with per-round "
-                        "critical_path records covering >= 95%% of each "
-                        "round, honest backend labels, passing gate "
-                        "verdicts, zero recompiles after warmup, and a "
-                        "green disabled-mode overhead pin")
-    p.add_argument("--opt_bench", default=None,
-                   help="BENCH_opt.json (v1) to validate: >= 2 workloads "
-                        "each with a plain arm and one optimizer arm, "
-                        "honest backend labels, passing gate verdicts, "
-                        "and the headline claims RE-DERIVED from the "
-                        "committed accuracy curves — rounds-to-target "
-                        "ratio >= 1.5, final accuracy not worse, zero "
-                        "recompiles after warmup, controller decisions "
-                        "on every optimizer-arm round")
-    p.add_argument("--degrade_bench", default=None,
-                   help="BENCH_degrade.json (v1) to validate: clean/"
-                        "static/degrade arms present with honest backend "
-                        "labels, passing gate verdicts, and the headline "
-                        "claims RE-DERIVED from the committed per-round "
-                        "rows — zero network-attributed strikes, "
-                        "adaptive deadline < static cap on >= 80%% of "
-                        "warm rounds, bounded honest-silo starvation, "
-                        "final global within tolerance of the clean "
-                        "arm, zero recompiles after warmup, and the "
-                        "mid-soak kill re-deriving the same deadline")
     args = p.parse_args(argv)
-    if args.ledger is None and not args.lint_mfu \
-            and args.health_ledger is None and args.serve_bench is None \
-            and args.release_bench is None and args.ingest_bench is None \
-            and args.opt_bench is None and args.degrade_bench is None:
+    if args.ledger is None and args.health_ledger is None:
         p.print_usage()
-        print("perf_trend: nothing to do (pass --ledger, --health_ledger, "
-              "--serve_bench, --release_bench, --ingest_bench, "
-              "--opt_bench, --degrade_bench and/or --lint_mfu)")
+        print("perf_trend: nothing to do (pass --ledger and/or "
+              "--health_ledger)")
         return 2
 
     failures: List[str] = []
@@ -1103,107 +401,6 @@ def main(argv=None) -> int:
                          if not v.get("ok"))
             print(f"health ledger: {len(health_rows)} rounds, schema OK, "
                   f"{alarms} alarm verdict(s) fired")
-
-    if args.serve_bench is not None:
-        try:
-            with open(args.serve_bench) as f:
-                serve_obj = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"perf_trend: cannot read serve bench: {e}")
-            return 2
-        # committed-trend-line mode: a smoke artifact must not anchor it
-        problems = validate_serve_bench(serve_obj, allow_smoke=False)
-        failures += [f"serve bench: {x}" for x in problems]
-        if not problems:
-            arms = serve_obj.get("arms", {})
-            rps = arms.get("replay", {}).get("throughput_rps")
-            occ = arms.get("decode", {}).get("occupancy_ratio")
-            print(f"serve bench: {len(arms)} arm(s) green "
-                  f"(replay {rps} req/s, decode occupancy ratio {occ})")
-
-    if args.release_bench is not None:
-        try:
-            with open(args.release_bench) as f:
-                release_obj = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"perf_trend: cannot read release bench: {e}")
-            return 2
-        # committed-trend-line mode: a smoke artifact must not anchor it
-        problems = validate_release_bench(release_obj, allow_smoke=False)
-        failures += [f"release bench: {x}" for x in problems]
-        if not problems:
-            arms = release_obj.get("arms", {})
-            pipe = arms.get("pipeline", {})
-            print(f"release bench: {len(arms)} arm(s) green "
-                  f"({pipe.get('promotions')} promotions, poisoned "
-                  f"v{pipe.get('poisoned_version')} contained, p99 "
-                  f"{pipe.get('latency_ms', {}).get('p99')}ms)")
-
-    if args.ingest_bench is not None:
-        try:
-            with open(args.ingest_bench) as f:
-                ingest_obj = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"perf_trend: cannot read ingest bench: {e}")
-            return 2
-        # committed-trend-line mode: a smoke artifact must not anchor it
-        problems = validate_ingest_bench(ingest_obj, allow_smoke=False)
-        failures += [f"ingest bench: {x}" for x in problems]
-        if not problems:
-            arms = ingest_obj.get("arms", {})
-            bindings = sorted({r.get("binding")
-                               for a in arms.values()
-                               for r in (a.get("rounds") or [])})
-            twins = (ingest_obj.get("pipeline") or {}).get("twins", {})
-            waves = twins.get("waves", {})
-            ov = (waves.get("gates", {}).get("fold_overlap", {})
-                  .get("min"))
-            print(f"ingest bench: {len(arms)} arm(s) green "
-                  f"(bindings seen: {bindings}); {len(twins)} pipeline "
-                  f"twin(s) bit-equal (waves fold overlap {ov})")
-
-    if args.opt_bench is not None:
-        try:
-            with open(args.opt_bench) as f:
-                opt_obj = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"perf_trend: cannot read opt bench: {e}")
-            return 2
-        # committed-trend-line mode: a smoke artifact must not anchor it
-        problems = validate_opt_bench(opt_obj, allow_smoke=False)
-        failures += [f"opt bench: {x}" for x in problems]
-        if not problems:
-            wls = opt_obj.get("workloads", {})
-            arms = sorted({a for wl in wls.values()
-                           for a in wl.get("arms", {}) if a != "plain"})
-            print(f"opt bench: {len(wls)} workload(s) green "
-                  f"(optimizer arms: {arms})")
-
-    if args.degrade_bench is not None:
-        try:
-            with open(args.degrade_bench) as f:
-                degrade_obj = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"perf_trend: cannot read degrade bench: {e}")
-            return 2
-        # committed-trend-line mode: a smoke artifact must not anchor it
-        problems = validate_degrade_bench(degrade_obj, allow_smoke=False)
-        failures += [f"degrade bench: {x}" for x in problems]
-        if not problems:
-            deg = degrade_obj.get("arms", {}).get("degrade", {})
-            sft = deg.get("strike_fault_totals", {})
-            print(f"degrade bench: 3 arm(s) green "
-                  f"({len(deg.get('rounds') or [])} degraded rounds, "
-                  f"strikes by fault {sft}, final delta vs clean "
-                  f"{deg.get('final_delta_vs_clean')})")
-
-    if args.lint_mfu:
-        paths = _expand(args.lint_mfu)
-        violations = lint_mfu_artifacts(paths)
-        failures += [f"mfu lint: {v}" for v in violations]
-        if not violations:
-            print(f"mfu lint: {len(paths)} artifact(s) green "
-                  f"(every mfu <= 1.0 or explicitly retracted)")
 
     if failures:
         for f_ in failures:

@@ -462,7 +462,7 @@ class SecAggServer:
         self._c_share_frames = reg.counter("fedml_secagg_share_frames_total")
         # envelopes = per-pair Shamir shares relayed (inside adverts) or
         # revealed (inside unmask answers): the O(N^2) [flat] vs O(N^2/E)
-        # [grouped] agreement-traffic quantity BENCH_secagg.json pins —
+        # [grouped] agreement-traffic quantity —
         # frame counts alone are O(N) either way and cannot show it
         self._c_share_env = reg.counter("fedml_secagg_share_envelopes_total")
         self._c_reconstruct = {

@@ -32,9 +32,6 @@ layers plus a bench harness:
                               held-out eval regression — fail rolls back
                               (the live slot never moved) with cooldown/
                               backoff, all crash-consistent
-    scripts/serve_bench.py    open-loop load generator → BENCH_serve.json
-    scripts/release_bench.py  gated release pipeline under live load →
-                              BENCH_release.json
 
 Everything is instrumented through the PR 2 telemetry registry under
 ``fedml_serve_*`` (see the README metric table) and designed to survive

@@ -39,8 +39,7 @@ accelerator-bound hot loop.
 
 Memory: per shard, O(model/S) accumulator + O(model/S) reference; with
 a mesh (``model`` axis), each shard's state is committed to its own
-device, so per-DEVICE memory scales ~1/S (BENCH_shard.json measures
-exactly this from the live buffers).
+device, so per-DEVICE memory scales ~1/S.
 """
 
 from __future__ import annotations

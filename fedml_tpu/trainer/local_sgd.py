@@ -137,9 +137,8 @@ def make_local_trainer(workload: Workload,
     it (a mixture's balance term).  The wave engine drops the metric.
 
     ``scan_unroll`` is forwarded to the step `lax.scan` — the default 1
-    keeps the compiled program small; bench FLOPs twins pass the full trip
-    count so XLA cost analysis (which counts a scan body once) sees every
-    step (bench.py _honest_flops)."""
+    keeps the compiled program small; the full trip count lets XLA cost
+    analysis (which counts a scan body once) see every step."""
     clip = (optax.clip_by_global_norm(workload.grad_clip_norm)
             if workload.grad_clip_norm is not None else None)
     stateful = workload.stateful

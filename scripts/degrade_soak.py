@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Sustained-degradation survivability soak (ISSUE 19) → BENCH_degrade.json.
+"""Sustained-degradation survivability soak (ISSUE 19).
 
 Three arms over the same deterministic 6-silo federation (silo 5 is a
 NaN-spewing attacker the admission pipeline rejects, silo 6 is
@@ -35,9 +35,7 @@ Invariants (any failure exits 1, with the gate named):
       actually fired), and the kill actually landed.
 
 Determinism: chaos and kills derive from --seed.  ``--smoke`` is the
-CI twin (reduced rounds/windows, artifact labeled smoke=true —
-``perf_trend.py --degrade_bench`` refuses to anchor the committed
-trend line on it).
+CI twin (reduced rounds/windows, report labeled smoke=true).
 
 Usage:
   python scripts/degrade_soak.py [--smoke] [--seed N] [--out PATH]
@@ -301,7 +299,7 @@ def main(argv=None):
                     help="reduced CI twin (artifact labeled smoke=true)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", type=str, default="",
-                    help="write BENCH_degrade.json here")
+                    help="write the JSON report here")
     args = ap.parse_args(argv)
     cfg = _cfg(args.smoke)
     backend = jax.default_backend()

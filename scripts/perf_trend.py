@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""Perf regression gate + timing-trust lint for flight-recorder ledgers.
+"""Perf regression gate for flight-recorder ledgers.
 
     python scripts/perf_trend.py --ledger RUN/perf.jsonl \
-        --baseline PERF_demo.jsonl --lint_mfu 'BENCH_*.json'
+        --baseline OTHER_RUN/perf.jsonl --health_ledger RUN/health.jsonl
 
-Exit 0 = pass, 1 = named regression / lint violation, 2 = bad inputs —
+Exit 0 = pass, 1 = named regression / malformed ledger, 2 = bad inputs —
 wire it into CI beside the test tiers (scripts/test_fast.sh).
 """
 import os

@@ -11,25 +11,17 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-# encode-once wire path under faults: the smoke bench drives a real
-# federation through dup/reorder/corrupt chaos with the admission screen
-# armed.  Smoke output goes to /tmp — BENCH_wire.json is a FULL run's
-# artifact and must not be overwritten by smoke numbers.
-env JAX_PLATFORMS=cpu python scripts/wire_bench.py --smoke \
-    --out /tmp/BENCH_wire_smoke.json
-
 # process-kill arm (ISSUE 12): the seeded kill/disk-fault matrix with
-# the invariant checker — link chaos above exercises the WIRE; this
-# exercises process death, crash-at-a-point, and disk faults against
+# the invariant checker — the pytest suites below put chaos on the WIRE;
+# this exercises process death, crash-at-a-point, and disk faults against
 # the round journal's recovery contract
 env JAX_PLATFORMS=cpu python scripts/soak.py --smoke \
     --out /tmp/soak_smoke.json
 
 # sustained-degradation arm (ISSUE 19): the degrade spine (adaptive
 # deadlines, quorum holds, fault attribution) under flapping links, a
-# round-bounded partition, and a mid-soak kill+respawn.  Smoke output
-# goes to /tmp — the committed BENCH_degrade.json is the full soak's
-# artifact and perf_trend.py --degrade_bench refuses smoke labels.
+# round-bounded partition, and a mid-soak kill+respawn.  The script
+# exits 1 on its own gates; its output goes to /tmp.
 env JAX_PLATFORMS=cpu python scripts/degrade_soak.py --smoke \
     --out /tmp/bench_degrade_smoke.json
 

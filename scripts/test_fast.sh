@@ -12,8 +12,7 @@
 #
 # The fast tier covers every non-slow test file under tests/, including
 # the serving layer (tests/test_serve.py — registry hot-swap, batching,
-# shedding, HTTP frontend); sustained-load serve cases are @slow and run
-# via scripts/serve_bench.py / run_serve_demo.sh instead.
+# shedding, HTTP frontend); sustained-load serve cases are @slow.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 [ -f tests/test_serve.py ]         # fast tier must include the serve suite
@@ -42,7 +41,7 @@ grep -q "fused=True" tests/test_shard_spine.py  # fused-finalize parity too
 # poisoned-round containment)
 [ -f tests/test_release.py ]
 # ISSUE 17 critical-path observatory: attribution sweep, binding
-# constraints, disabled-mode zero-allocation pin, ingest-bench schema
+# constraints, disabled-mode zero-allocation pin
 [ -f tests/test_critical_path.py ]
 # ISSUE 18 server-optimizer spine: seam parity vs optax/fedac math,
 # plain bit-identity, sharded state round-trip, crash kill->resume with

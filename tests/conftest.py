@@ -16,6 +16,8 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
 
+import json  # noqa: E402
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
@@ -50,3 +52,33 @@ def identity_lm_data(vocab=12, clients=4, samples=16, seq=8, batch=8,
     train = stack_client_data(xs, ys, batch_size=batch)
     return FederatedData(client_num=clients, class_num=vocab, train=train,
                          test=train)
+
+
+def trace_events(path):
+    """A ``trace.json`` and its complete (``X``) events."""
+    with open(path) as f:
+        doc = json.load(f)
+    return doc, [e for e in doc["traceEvents"] if e["ph"] == "X"]
+
+
+@pytest.fixture(scope="module", params=["inline", "ingest_pipeline"])
+def cli_run(request, tmp_path_factory):
+    """One short ``--algo cross_device --perf`` run through the CLI: its
+    ``trace.json`` and ``perf.jsonl``.  ``ingest_pipeline`` is the run with
+    every timing site live (`fold.drain` / `barrier_wait` exist only
+    there, `health` only under ``--health``)."""
+    from fedml_tpu.experiments.main import main
+    run_dir = str(tmp_path_factory.mktemp(request.param))
+    argv = ["--algo", "cross_device", "--model", "lr", "--dataset", "mnist",
+            "--client_num_in_total", "12", "--client_num_per_round", "10",
+            "--wave_size", "4", "--comm_round", "2", "--batch_size", "4",
+            "--health", "true", "--log_stdout", "false", "--perf", "true",
+            "--run_dir", run_dir]
+    if request.param == "ingest_pipeline":
+        argv += ["--ingest_pipeline", "true"]
+    main(argv)
+    doc, events = trace_events(os.path.join(run_dir, "trace.json"))
+    with open(os.path.join(run_dir, "perf.jsonl")) as f:
+        ledger = [json.loads(line) for line in f]
+    return {"doc": doc, "events": events, "ledger": ledger,
+            "pipelined": request.param == "ingest_pipeline"}

@@ -205,34 +205,45 @@ def test_perf_recorder_emits_critical_path_on_every_line(tmp_path):
     assert "fedml_ingest_uploads_total" in reg.snapshot()["counters"]
 
 
-def test_live_federation_rounds_carry_critical_path(tmp_path):
+@pytest.mark.parametrize("traced", [False, True])
+def test_live_federation_rounds_carry_critical_path(traced, tmp_path):
     """End to end on the actor path: a local 2-silo federation with the
     flight recorder writes a critical_path record on every ledger line,
-    with one arrival per upload and >= 95% coverage."""
+    with one arrival per upload and >= 95% coverage; traced, the
+    streaming receive path also leaves its per-upload `ingest:` spans."""
     from fedml_tpu.algorithms.cross_silo import (FedAvgClientActor,
                                                  FedAvgServerActor)
+    from fedml_tpu.core.stream_agg import StreamingAggregator
     reg = telemetry.TelemetryRegistry()
     perf = PerfRecorder(str(tmp_path / "perf.jsonl"), registry=reg)
     hub = LocalHub(codec_roundtrip=True)
     rng = np.random.RandomState(0)
     params = {"w": rng.randn(3, 2).astype(np.float32)}
-    server = FedAvgServerActor(hub.transport(0), params,
-                               client_num_in_total=2,
-                               client_num_per_round=2,
-                               num_rounds=2, perf=perf)
-    server.register_handlers()
 
     def train_fn(p, client_idx, round_idx):
         import jax
         return jax.tree.map(lambda v: v + 1.0, p), 10
 
-    silos = [FedAvgClientActor(i, hub.transport(i), train_fn)
-             for i in (1, 2)]
-    for s in silos:
-        s.register_handlers()
-    server.start()
-    hub.pump()
+    tracer = trace.enable() if traced else None     # actors read it once
+    try:
+        server = FedAvgServerActor(
+            hub.transport(0), params, client_num_in_total=2,
+            client_num_per_round=2, num_rounds=2, perf=perf,
+            stream_agg=StreamingAggregator(params) if traced else None)
+        server.register_handlers()
+        silos = [FedAvgClientActor(i, hub.transport(i), train_fn)
+                 for i in (1, 2)]
+        for s in silos:
+            s.register_handlers()
+        server.start()
+        hub.pump()
+    finally:
+        trace.disable()
     perf.close()
+    if traced:
+        names = [s["name"] for s in tracer.spans]
+        assert names.count("ingest:fold") == 4      # one an upload
+        assert any(n.startswith("recv:") for n in names)
     rows = trend.load_ledger(perf.path)
     assert len(rows) == 2
     assert trend.validate_ledger(rows) == []
@@ -343,95 +354,6 @@ def test_trend_gate_rejects_malformed_critical_path():
            "coverage": 1.0, "round_s": 0.2}
     problems = trend.validate_ledger([_row(0, bad)])
     assert problems and all("critical_path" in p for p in problems)
-
-
-# ---------------------------------------------------------------------------
-# BENCH_ingest schema gate
-# ---------------------------------------------------------------------------
-
-def _ingest_bench(**over):
-    rec = {"binding": "fold", "attribution": {"fold": 0.2},
-           "coverage": 1.0, "round_s": 0.2, "uploads": 2,
-           "fold_overlap_ratio": 0.5}
-    arm = {"backend": "cpu", "rounds": [dict(rec), dict(rec)],
-           "recompiles_after_warmup": 0,
-           "gates": {"coverage": {"ok": True, "min": 1.0}}}
-    obj = {"bench": "ingest", "version": 1, "smoke": False,
-           "arms": {"cross_silo": dict(arm), "cross_device": dict(arm),
-                    "sharded": dict(arm), "secagg": dict(arm),
-                    "disabled_pin": {"backend": "cpu", "gates":
-                                     {"overhead": {"ok": True}}}},
-           "pipeline": {"twins": {n: _pipeline_twin(n) for n in
-                                  ("waves", "replicated", "sharded")}}}
-    obj.update(over)
-    return obj
-
-
-def _pipeline_twin(name):
-    """Minimal green `--ingest_pipeline` twin: bit-equal crc sequences,
-    0 recompiles, rows that re-derive the waves overlap/wall-clock and
-    replicated wire-drain gates, one arena+screen ledger entry each."""
-    def _row(r):
-        return {"round": r, "global_crc": 7 + r,
-                "fold_overlap_ratio": 0.995, "last_arrival_s": 0.1,
-                "round_s": 0.1, "bytes_in": 1000, "recompiles": 0}
-    twin = {"gates": {"bit_equal_finals": {"ok": True}},
-            "inline": {"rows": [_row(0), _row(1)]},
-            "pipelined": {"rows": [_row(0), _row(1)]}}
-    if name == "sharded":
-        twin["pipelined"]["jit_cache_sizes"] = {
-            f"ingest_s{s}_{kind}": 1
-            for s in range(4) for kind in ("arena", "screen")}
-    elif name == "replicated":
-        twin["pipelined"]["jit_cache_sizes"] = {"ingest_arena": 1,
-                                                "ingest_screen": 1}
-    return twin
-
-
-def test_validate_ingest_bench_accepts_committed_shape():
-    assert trend.validate_ingest_bench(_ingest_bench()) == []
-
-
-def test_validate_ingest_bench_rejects_failures():
-    # a failed gate verdict is never excused, even on a smoke artifact
-    obj = _ingest_bench(smoke=True)
-    obj["arms"]["cross_silo"]["gates"]["coverage"] = {"ok": False}
-    assert any("FAILED" in p for p in trend.validate_ingest_bench(obj))
-    # a smoke label is refused on the committed trend line
-    assert any("smoke" in p for p in trend.validate_ingest_bench(
-        _ingest_bench(smoke=True), allow_smoke=False))
-    # a dropped arm is a schema failure
-    obj = _ingest_bench()
-    del obj["arms"]["secagg"]
-    assert any("secagg" in p for p in trend.validate_ingest_bench(obj))
-    # low coverage is re-derived from the records, not trusted to gates
-    obj = _ingest_bench()
-    obj["arms"]["sharded"]["rounds"][0]["coverage"] = 0.5
-    obj["arms"]["sharded"]["rounds"][0]["attribution"] = {"fold": 0.1}
-    assert any("covers" in p for p in trend.validate_ingest_bench(obj))
-    # recompiles after warmup with tracing on break the cost contract
-    obj = _ingest_bench()
-    obj["arms"]["cross_device"]["recompiles_after_warmup"] = 1
-    assert any("recompiles" in p for p in trend.validate_ingest_bench(obj))
-    # the --ingest_pipeline twins are required, and their bit-parity is
-    # re-derived from the crc rows — a green verdict cannot survive
-    # rows that contradict it
-    obj = _ingest_bench()
-    del obj["pipeline"]
-    assert any("pipeline" in p for p in trend.validate_ingest_bench(obj))
-    obj = _ingest_bench()
-    obj["pipeline"]["twins"]["waves"]["pipelined"]["rows"][1][
-        "global_crc"] = 999
-    assert any("bit-parity" in p for p in trend.validate_ingest_bench(obj))
-    obj = _ingest_bench()
-    obj["pipeline"]["twins"]["waves"]["pipelined"]["rows"][1][
-        "fold_overlap_ratio"] = 0.5
-    assert any("fold_overlap" in p
-               for p in trend.validate_ingest_bench(obj))
-    obj = _ingest_bench()
-    obj["pipeline"]["twins"]["replicated"]["pipelined"][
-        "jit_cache_sizes"]["ingest_arena"] = 2
-    assert any("ledger" in p for p in trend.validate_ingest_bench(obj))
 
 
 # ---------------------------------------------------------------------------

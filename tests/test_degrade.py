@@ -5,9 +5,8 @@ debt, the closed fault-attribution vocabulary with its hard invariant
 feed, checkpointed determinism of every derivation, and the resume-path
 straggler-timer audit.
 
-Fast tier only — the full chaos + partition + kill soak rides
-scripts/degrade_soak.py (committed as BENCH_degrade.json and re-derived
-by ``perf_trend.py --degrade_bench``).
+Fast tier only — the full chaos + partition + kill soak is
+scripts/degrade_soak.py, which exits 1 on its own gates.
 """
 
 import dataclasses

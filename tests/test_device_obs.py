@@ -10,9 +10,8 @@ acceptance pins:
   old ledgers WITHOUT the section keep validating;
 * sentry cache-key diff: a real forced re-jit fires a verdict that
   NAMES the arg shape that changed;
-* honest MFU: <= 1.0 by construction on the CPU backend, with FLOPs
-  and peak provably shared with bench.py (delegation pinned by
-  identity);
+* honest MFU: no peak, so no MFU, on the CPU backend; one peak table,
+  every kind of it a case;
 * trend device gates: pass on identical ledgers, fail (exit 1, named)
   on a seeded compile-time or device-memory regression, and skip
   vacuously on pre-device-observatory ledgers;
@@ -40,18 +39,8 @@ def _reg():
 
 
 # ---------------------------------------------------------------------------
-# shared peak table / FLOPs accounting (bench delegation)
+# the peak table
 # ---------------------------------------------------------------------------
-
-def test_bench_delegates_peak_and_flops_by_identity():
-    """The offline bench and the live gauges must read ONE peak table
-    and ONE cost-analysis probe — pinned by identity, not by equal
-    outputs, so a copy-paste fork cannot drift silently."""
-    import bench
-    assert bench._peak_for_device is device_obs.peak_tflops_for_device
-    assert bench._compiled_flops is device_obs.compiled_flops
-    assert bench._PEAK_BY_KIND is device_obs.PEAK_TFLOPS_BY_KIND
-
 
 class _FakeDev:
     def __init__(self, kind, platform="tpu"):
@@ -59,20 +48,23 @@ class _FakeDev:
         self.platform = platform
 
 
-def test_peak_table_kind_match_and_env_override(monkeypatch):
+@pytest.mark.parametrize("kind,peak", [
+    ("TPU v5e", 197.0), ("TPU v5 lite", 197.0), ("TPU v5p chip", 459.0),
+    ("TPU v6e", 918.0), ("trillium", 918.0), ("TPU v4", 275.0),
+    ("TPU v3", 123.0),
+])
+def test_peak_table_kind_match_and_env_override(kind, peak, monkeypatch):
     monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
-    assert peak_tflops_for_device(_FakeDev("TPU v5 lite")) == 197.0
-    assert peak_tflops_for_device(_FakeDev("TPU v4")) == 275.0
+    assert peak_tflops_for_device(_FakeDev(kind)) == peak
     monkeypatch.setenv("BENCH_PEAK_TFLOPS", "42.5")
-    assert peak_tflops_for_device(_FakeDev("TPU v4")) == 42.5
+    assert peak_tflops_for_device(_FakeDev(kind)) == 42.5
     assert "env override" in device_obs.peak_and_source(None)[1]
 
 
 def test_no_default_peak(monkeypatch):
     """The module's "null, never 0" contract, applied to the peak: the
     CPU backend has NO peak (so no MFU), and an accelerator whose kind
-    the table lacks is an error — never the v5e number by default (how
-    BENCH_device.json came to report an "MFU" on a CPU)."""
+    the table lacks is an error — never the v5e number by default."""
     monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
     assert not hasattr(device_obs, "DEFAULT_PEAK_TFLOPS")
     cpu = _FakeDev("cpu", platform="cpu")

@@ -317,11 +317,12 @@ def _drift_train_fn(scale=0.01):
 
 
 def _run_sync(mode, tmp_path, name, n_silos=4, n_rounds=3, admission=None,
-              attack=None, attacker=2, deaf=(), norm_clip=5.0):
+              attack=None, attacker=2, deaf=(), norm_clip=5.0,
+              observed=True):
     hub = LocalHub(codec_roundtrip=True)
     init = _params()
     health = HealthAccumulator(
-        ledger_path=str(tmp_path / f"{name}.jsonl"))
+        ledger_path=str(tmp_path / f"{name}.jsonl")) if observed else None
     kw = {}
     if mode == "stream":
         kw["stream_agg"] = StreamingAggregator(init, method="mean",
@@ -375,6 +376,16 @@ class TestLiveHealthEquivalence:
         stack, stream = _lines(tmp_path, "stack"), _lines(tmp_path, "stream")
         assert len(stack) == len(stream) == 3
         assert stack == stream
+
+    @pytest.mark.parametrize("mode", ["stack", "stream"])
+    def test_health_observes_and_never_perturbs(self, mode, tmp_path):
+        """The same global, bit for bit, with the observatory on and off."""
+        on, _ = _run_sync(mode, tmp_path, "on")
+        off, _ = _run_sync(mode, tmp_path, "off", observed=False)
+        assert on.round_idx == off.round_idx == 3
+        for a, b in zip(jax.tree.leaves(on.params),
+                        jax.tree.leaves(off.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_identical_lines_with_dropped_straggler(self, tmp_path):
         _run_sync("stack", tmp_path, "stack", deaf=(4,))

@@ -10,9 +10,9 @@ dead-letter attribution, pipelined==inline bit-parity over the live
 pump-mode federation (replicated, sharded, secagg ring-fold), the
 kill-mid-queue journal-recovery composition (queued-but-unfolded
 frames stay un-journaled, so recovery re-tasks exactly those silos),
-the config-gate matrix, and the one-ledger-entry compile pin.  The
-measured claims (fold overlap >= 0.99, wall clock <= 1.15x network
-time, wire speed) ride scripts/ingest_bench.py -> BENCH_ingest.json.
+the config-gate matrix, and the one-ledger-entry compile pin.  Fold
+overlap, wall clock against network time and wire speed are timings: no
+cell of the benchmark runs this path yet (PERF.md section 7).
 """
 
 import threading
@@ -353,6 +353,33 @@ class TestBitParity:
         inline, piped = run(False), run(True)
         assert piped.round_idx == inline.round_idx == 2
         assert _leaves_equal(piped.params, inline.params)
+
+    def test_cross_device_waves(self):
+        """The wave engine folds a finished wave on the pipeline's worker
+        while the next wave trains: the same global as folding inline."""
+        from fedml_tpu.algorithms.cross_device import (CrossDevice,
+                                                       CrossDeviceConfig)
+        from fedml_tpu.data import load_data
+        from fedml_tpu.experiments.models import (create_workload,
+                                                  sample_shape_of)
+        data = load_data("mnist", data_dir=None, batch_size=4,
+                         num_clients=12, seed=0)
+        wl = create_workload("lr", "mnist", data.class_num,
+                             sample_shape_of(data))
+        cfg = CrossDeviceConfig(comm_round=3, client_num_per_round=10,
+                                epochs=1, batch_size=4, wave_size=4,
+                                seed=0, frequency_of_the_test=10)
+
+        def run(pipelined):
+            ing = _make_pipeline(num_shards=1, depth=8) \
+                if pipelined else None
+            try:
+                return CrossDevice(wl, data, cfg, ingest=ing).run()
+            finally:
+                if ing is not None:
+                    ing.stop()
+
+        assert _leaves_equal(run(True), run(False))
 
     def test_secagg_ring_fold(self):
         """Masked uploads ride the pipeline WITHOUT an arena (uint32 by

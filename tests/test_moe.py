@@ -212,3 +212,14 @@ def test_moe_lm_learns_federatedly():
         params, m = step(params, cohort, jax.random.key(r))
         losses.append(float(m["train_loss_per_step"].mean()))
     assert losses[-1] < losses[0] * 0.7, losses
+
+
+def test_auto_group_and_block_helpers():
+    from fedml_tpu.models.moe import _auto_group
+    assert _auto_group(1024) == 512     # largest divisor <= 512
+    assert _auto_group(96) == 96        # <= target: itself (loop hit)
+    assert _auto_group(1031) == 1031    # prime > target: n_tok fallback
+    from fedml_tpu.models.transformer import _auto_block
+    assert _auto_block(2048, threshold=1024) == 512
+    assert _auto_block(512, threshold=1024) is None   # dense is fine
+    assert _auto_block(1031, threshold=1024) is None  # prime, no divisor

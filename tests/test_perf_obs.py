@@ -10,9 +10,7 @@ fedml_tpu/obs/trend.py) — the ISSUE 6 acceptance pins:
   re-jit, hard-fails under strict mode BEFORE a misleading clean
   ledger line can be written;
 * trend gate: passes on identical ledgers, fails (named phase,
-  non-zero exit) on a seeded +50% regression, and the mfu <= 1.0 lint
-  refuses unretracted impossible values — the exact contract
-  ``bench._max_mfu`` delegates to;
+  non-zero exit) on a seeded +50% regression;
 * SLO evaluator: breach counters + the serve frontend's
   ``/healthz?deep=1`` path (200 holding, 503 + verdict on breach).
 """
@@ -292,62 +290,6 @@ def test_trend_schema_validation_names_missing_keys(tmp_path):
     assert any("recompiles" in p for p in problems)
     assert any("wire" in p for p in problems)
     assert trend.validate_ledger([]) == ["ledger is empty"]
-
-
-# ---------------------------------------------------------------------------
-# mfu lint (+ the bench delegation contract)
-# ---------------------------------------------------------------------------
-
-def test_mfu_lint_refuses_unretracted_over_one(tmp_path, capsys):
-    bad = tmp_path / "BENCH_bad.json"
-    bad.write_text(json.dumps(
-        {"configs": {"a": {"mfu": 1.57}, "b": {"mfu": 0.3}}}))
-    violations = trend.lint_mfu_artifacts([str(bad)])
-    assert len(violations) == 1 and "1.57" in violations[0]
-    assert trend.main(["--lint_mfu", str(bad)]) == 1
-    assert "mfu lint" in capsys.readouterr().out
-
-
-def test_mfu_lint_retraction_markers_are_sticky_downward(tmp_path):
-    ok = tmp_path / "BENCH_ok.json"
-    ok.write_text(json.dumps({
-        "cohort_scaling": {"128": {
-            "mfu": 1.57,
-            "mfu_retracted": "timing retracted"}},
-        "quarantined": {"timing_untrusted": "broken timer",
-                        "nested": [{"mfu": 3.08}]},
-        "configs": {"a": {"mfu": 0.9}}}))
-    assert trend.lint_mfu_artifacts([str(ok)]) == []
-    assert trend.main(["--lint_mfu", str(ok)]) == 0
-
-
-def test_mfu_lint_unreadable_artifact_is_a_violation(tmp_path):
-    missing = str(tmp_path / "nope.json")
-    garbage = tmp_path / "garbage.json"
-    garbage.write_text("{not json")
-    violations = trend.lint_mfu_artifacts([missing, str(garbage)])
-    assert len(violations) == 2
-    assert all("unreadable" in v for v in violations)
-
-
-def test_max_mfu_recursive_and_ignores_retraction():
-    art = {"configs": {"a": {"mfu": 0.3}},
-           "cohort_scaling": {"128": {"mfu": 1.57, "mfu_retracted": "yes"}},
-           "deep": [{"nested": {"mfu": 0.7}}]}
-    # retraction markers make the LINT green but never hide the value
-    # from max_mfu — a refused artifact stays refused
-    assert trend.max_mfu(art) == pytest.approx(1.57)
-    assert trend.max_mfu({}) == 0.0
-
-
-def test_bench_max_mfu_delegates_to_trend():
-    """bench's promotion refusal and the CI lint must share one scan —
-    a nested cell counts in both or neither."""
-    import bench
-    art = {"configs": {"a": {"mfu": 0.3}},
-           "cohort_scaling": {"64": {"mfu": 0.9}},
-           "scaling_curve_v2": [{"mfu": 1.2}]}     # nested, non-canonical
-    assert bench._max_mfu(art) == trend.max_mfu(art) == pytest.approx(1.2)
 
 
 # ---------------------------------------------------------------------------

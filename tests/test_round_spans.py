@@ -3,26 +3,22 @@
 One timing site per boundary (`obs.trace.TimedSpan` through
 `CrossDevice._span`): the span tree of a round, the ledger phases fed from
 the same intervals, the profiler annotations under the span names, the
-repaired tracer clock, and the names the benchmark's trace reduction finds
-programs by.  PERF.md section 3 lists every span with the metric that
-reads it.
+repaired tracer clock.  PERF.md section 3 lists every span with the metric
+that reads it; tests/test_benchmark_contract.py holds the names the
+benchmark's data files read, the programs' module names among them.
 """
 
 import glob
 import json
 import os
-import re
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import trace_events
 
 from fedml_tpu.algorithms.cross_device import CrossDevice, CrossDeviceConfig
-from fedml_tpu.core.stream_agg import StreamingAggregator, zeros_acc_like
 from fedml_tpu.data import load_data
-from fedml_tpu.data.stacking import gather_cohort
-from fedml_tpu.experiments.main import main
 from fedml_tpu.experiments.models import create_workload, sample_shape_of
 from fedml_tpu.obs import trace
 from fedml_tpu.obs.perf import PerfRecorder
@@ -54,33 +50,10 @@ def _cfg(**kw):
     return CrossDeviceConfig(**base)
 
 
-def _events(path):
-    with open(path) as f:
-        doc = json.load(f)
-    return doc, [e for e in doc["traceEvents"] if e["ph"] == "X"]
-
-
 # ---------------------------------------------------------------------------
-# the CLI: --perf writes run_dir/trace.json, one tree a round
+# the CLI: --perf writes run_dir/trace.json, one tree a round (`cli_run`,
+# tests/conftest.py)
 # ---------------------------------------------------------------------------
-
-@pytest.fixture(scope="module", params=["inline", "ingest_pipeline"])
-def cli_run(request, tmp_path_factory):
-    run_dir = str(tmp_path_factory.mktemp(request.param))
-    argv = ["--algo", "cross_device", "--model", "lr", "--dataset", "mnist",
-            "--client_num_in_total", "12", "--client_num_per_round", "10",
-            "--wave_size", "4", "--comm_round", "2", "--batch_size", "4",
-            "--health", "true", "--log_stdout", "false", "--perf", "true",
-            "--run_dir", run_dir]
-    if request.param == "ingest_pipeline":
-        argv += ["--ingest_pipeline", "true"]
-    main(argv)
-    doc, events = _events(os.path.join(run_dir, "trace.json"))
-    with open(os.path.join(run_dir, "perf.jsonl")) as f:
-        ledger = [json.loads(line) for line in f]
-    return {"doc": doc, "events": events, "ledger": ledger,
-            "pipelined": request.param == "ingest_pipeline"}
-
 
 def _by_round(events):
     roots = [e for e in events if e["name"] == "round"]
@@ -410,7 +383,7 @@ def test_kept_spans_are_capped_newest_kept(monkeypatch, tmp_path):
         tr.record_span(f"s{i}", 0.001)
     assert [s["name"] for s in tr.spans] == ["s3", "s4", "s5", "s6"]
     tr.export(str(tmp_path / "t.json"))
-    doc, events = _events(str(tmp_path / "t.json"))
+    doc, events = trace_events(str(tmp_path / "t.json"))
     assert doc["otherData"]["dropped_spans"] == 3 and len(events) == 4
 
 
@@ -488,37 +461,3 @@ def test_trace_json_is_one_runs_own(tmp_path):
     finally:
         trace.disable()
     assert shared.spans and not stale.exists()
-
-
-# ---------------------------------------------------------------------------
-# the names the benchmark's trace reduction finds programs by
-# ---------------------------------------------------------------------------
-
-def _module_name(lowered) -> str:
-    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
-
-
-def test_program_names_the_trace_reduction_reads(workload, data):
-    bench = os.path.join(ROOT, "benchmark")
-    hooks = json.load(open(os.path.join(bench, "hooks",
-                                        "cross_device.json")))
-    fold_modules = json.load(open(os.path.join(
-        bench, "layer_metrics", "fold_program_s.json")))["args"]["modules"]
-    eng = CrossDevice(workload, data, _cfg())
-    params = jax.tree.map(jnp.asarray, workload.init(
-        jax.random.key(0), jax.tree.map(
-            lambda v: v[0, 0],
-            {k: data.train[k] for k in ("x", "y", "mask")})))
-    wave_data = gather_cohort(data.train, [1, 2, 3], pad_to=5)
-    wave = eng._wave_fn.lower(params, wave_data, jax.random.key(1),
-                              jnp.int32(0))
-    assert _module_name(wave) == hooks["wave_program"] == "jit_wave_fn"
-
-    agg = StreamingAggregator(params)
-    acc = zeros_acc_like(params)
-    stacked = jax.tree.map(lambda p: jnp.stack([p] * 5), params)
-    names = [_module_name(agg._fold_wave_fn.lower(
-        acc, jnp.float32(0), stacked, jnp.ones(5, jnp.float32), params)),
-        _module_name(agg._finalize_fn.lower(acc, jnp.float32(1), params,
-                                            0))]
-    assert names == fold_modules == ["jit__fold_wave", "jit__finalize"]

@@ -603,6 +603,24 @@ class TestCliParity:
         assert abs(grouped["test_loss"] - plain["test_loss"]) < 1e-3
         assert abs(pairwise["train_acc"] - plain["train_acc"]) < 1e-6
 
+    def test_grouped_masking_relays_fewer_share_envelopes(self):
+        """Agreement traffic is O(N^2) pairwise shares flat and O(N^2/E)
+        grouped (frames are O(N) either way and cannot show it)."""
+        from fedml_tpu.obs import telemetry
+
+        def envelopes(*extra):
+            reg = telemetry.enable()
+            try:
+                _cli("--secagg", *extra, "--agg_mode", "stream")
+                return reg.snapshot()["counters"][
+                    "fedml_secagg_share_envelopes_total"]
+            finally:
+                telemetry.disable()
+
+        flat = envelopes("pairwise")
+        grouped = envelopes("grouped", "--edge_aggregators", "2")
+        assert 0 < grouped < flat
+
     def test_incompatible_combos_fail_at_config_time(self):
         with pytest.raises(ValueError, match="async"):
             _cli("--secagg", "pairwise", "--agg_mode", "stream",

@@ -523,33 +523,3 @@ def test_http_deadline_propagates_to_429():
         assert status == 429 and body["reason"] == "deadline"
     finally:
         frontend.stop()
-
-
-@pytest.mark.slow
-def test_sustained_load_acceptance(tmp_path):
-    """The serve_bench v2 acceptance in miniature: the --smoke arm set
-    (replay + http + decode, fresh subprocesses each) runs green, the
-    artifact validates against the trend gate's schema, and the smoke
-    replay arm still sheds nothing and tears nothing."""
-    import subprocess
-    import sys
-
-    from fedml_tpu.obs.trend import validate_serve_bench
-    out = str(tmp_path / "BENCH_serve_smoke.json")
-    proc = subprocess.run(
-        [sys.executable, "scripts/serve_bench.py", "--smoke",
-         "--out", out],
-        capture_output=True, text=True, timeout=900,
-        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
-             "HOME": "/tmp"},
-        cwd=str(__import__("pathlib").Path(__file__).parent.parent))
-    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
-    bench = json.load(open(out))
-    assert bench["version"] == 2 and bench["smoke"] is True
-    assert validate_serve_bench(bench) == []
-    replay = bench["arms"]["replay"]
-    assert replay["torn_responses"] == 0
-    assert replay["latency_ms"]["p99"] <= replay["deadline_ms"]
-    decode = bench["arms"]["decode"]
-    assert decode["occupancy_ratio"] >= 2.0
-    assert decode["recompiles_after_warmup"] == 0

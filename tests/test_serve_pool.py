@@ -3,8 +3,7 @@ registry with unchanged hot-swap semantics, torn-read-free responses
 under concurrent publish (the checksum/fingerprint trick from the wire
 tests), worker-labeled telemetry, tiered shedding wired to the SAME
 SloEvaluator verdicts as deep-healthz, shed-reason accounting under
-saturation, the shared-socket fallback, and the BENCH_serve v2 schema
-gate (`obs/trend.validate_serve_bench`).
+saturation, and the shared-socket fallback.
 """
 
 import http.client
@@ -18,7 +17,6 @@ import pytest
 
 from fedml_tpu.obs import telemetry
 from fedml_tpu.obs.perf import SloEvaluator
-from fedml_tpu.obs.trend import validate_serve_bench
 from fedml_tpu.serve.batcher import MicroBatcher, ShedError, TierGate
 from fedml_tpu.serve.pool import ServeWorkerPool
 from fedml_tpu.serve.registry import ModelRegistry
@@ -409,63 +407,3 @@ class TestServeConfigGates:
         with pytest.raises(ValueError, match="best_effort_headroom"):
             main(["--algo", "cross_silo", "--serve_port", "8351",
                   "--serve_best_effort_headroom", "1.5"])
-
-
-# -- BENCH_serve v2 schema gate ---------------------------------------------
-
-def _bench_v2(**over):
-    arm = {"backend": "cpu", "torn_responses": 0,
-           "gates": {"g": {"ok": True}}}
-    obj = {"bench": "serve", "version": 2, "smoke": False,
-           "arms": {"replay": dict(arm), "http": dict(arm),
-                    "decode": dict(arm)}}
-    obj.update(over)
-    return obj
-
-
-def test_validate_serve_bench_accepts_committed_shape():
-    assert validate_serve_bench(_bench_v2()) == []
-
-
-def test_validate_serve_bench_rejects_failed_gate_and_missing_arm():
-    bad = _bench_v2()
-    bad["arms"]["replay"]["gates"]["g"] = {"ok": False, "value": 1}
-    assert any("FAILED" in p for p in validate_serve_bench(bad))
-    noarm = _bench_v2()
-    del noarm["arms"]["decode"]
-    assert any("decode" in p for p in validate_serve_bench(noarm))
-    v1 = {"bench": "serve", "throughput_rps": 1500.0}
-    assert validate_serve_bench(v1), "v1 artifact must not validate"
-    torn = _bench_v2()
-    torn["arms"]["http"]["torn_responses"] = 2
-    assert any("torn" in p for p in validate_serve_bench(torn))
-    nolabel = _bench_v2()
-    del nolabel["arms"]["http"]["backend"]
-    assert any("backend" in p for p in validate_serve_bench(nolabel))
-
-
-def test_validate_serve_bench_failed_gate_not_excused_by_smoke_label():
-    """A smoke label must not waive failed gate verdicts, and the
-    committed-trend-line mode (allow_smoke=False, what perf_trend uses)
-    rejects smoke artifacts outright — a /tmp smoke run can never be
-    re-committed as the trend anchor."""
-    smoked = _bench_v2(smoke=True)
-    smoked["arms"]["replay"]["gates"]["g"] = {"ok": False}
-    assert any("FAILED" in p for p in validate_serve_bench(smoked))
-    clean_smoke = _bench_v2(smoke=True)
-    assert validate_serve_bench(clean_smoke) == []
-    assert any("smoke" in p for p in
-               validate_serve_bench(clean_smoke, allow_smoke=False))
-
-
-def test_committed_bench_serve_passes_the_gate():
-    import pathlib
-    path = pathlib.Path(__file__).parent.parent / "BENCH_serve.json"
-    obj = json.loads(path.read_text())
-    assert validate_serve_bench(obj, allow_smoke=False) == [], (
-        "committed BENCH_serve.json fails its own trend gate")
-    assert obj["arms"]["replay"]["throughput_rps"] >= 10000
-    assert obj["arms"]["decode"]["occupancy_ratio"] >= 2.0
-    assert obj["arms"]["decode"]["recompiles_after_warmup"] == 0
-    assert any("decode_step" in n
-               for n in obj["arms"]["decode"]["compile_ledger"])

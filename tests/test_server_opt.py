@@ -22,6 +22,8 @@ The seam contract, pinned:
 * Every incompatible flag combination fails loudly at config time.
 """
 
+import json
+
 import jax
 import numpy as np
 import optax
@@ -480,3 +482,51 @@ class TestConfigGates:
         server = _run_stream(init, 2, server_opt=opt, jr=jr)
         assert server.round_idx == 2
         assert "srvopt=adam" in server._journal_mode()
+
+
+# ---------------------------------------------------------------------------
+# the CLI: the seam and the controller in the ledger, round for round
+# ---------------------------------------------------------------------------
+
+def _cli_arm(run_dir, rounds, *extra):
+    """One `--algo cross_silo --agg_mode stream` run on the synthetic(0.5,
+    0.5) twin: its accuracy curve and its perf ledger."""
+    from fedml_tpu.experiments.main import main
+    main(["--algo", "cross_silo", "--agg_mode", "stream", "--model", "lr",
+          "--dataset", "synthetic", "--lr", "0.003", "--epochs", "1",
+          "--batch_size", "10", "--client_num_in_total", "8",
+          "--client_num_per_round", "8", "--comm_round", str(rounds),
+          "--frequency_of_the_test", "1", "--seed", "0",
+          "--log_stdout", "false", "--perf", "true",
+          "--perf_strict", "true", "--run_dir", str(run_dir), *extra])
+    with open(run_dir / "metrics.jsonl") as f:
+        curve = sorted((r["round"], r["test_acc"])
+                       for r in map(json.loads, f) if "test_acc" in r)
+    with open(run_dir / "perf.jsonl") as f:
+        return curve, [json.loads(line) for line in f]
+
+
+@pytest.mark.parametrize("rounds", [
+    3, pytest.param(30, marks=pytest.mark.slow)])
+def test_cli_ledger_names_the_optimizer_and_every_pacing_decision(
+        rounds, tmp_path):
+    """Server adam with the adaptive controller against plain FedAvg, same
+    seed and data: every ledger line of the optimizer arm names the
+    optimizer and carries the controller's decision with its reasons,
+    neither arm recompiles after round 0, and over 30 rounds the optimizer
+    arm ends no less accurate and reaches 45 % no later."""
+    plain, plain_rows = _cli_arm(tmp_path / "plain", rounds)
+    adam, rows = _cli_arm(tmp_path / "adam", rounds, "--server_opt", "adam",
+                          "--server_lr", "0.1", "--adaptive", "true",
+                          "--health", "true")
+    assert len(rows) == len(plain_rows) == rounds
+    for r in rows:
+        assert r["server_opt"] == "adam"
+        assert r["adapt"]["reasons"]
+    assert not any(r["recompiles"] for r in rows[1:] + plain_rows[1:])
+    if rounds == 30:
+        def rounds_to(curve, target=0.45):
+            return next(r + 1 for r, acc in curve if acc >= target)
+
+        assert adam[-1][1] >= plain[-1][1] - 0.02
+        assert rounds_to(adam) <= rounds_to(plain)
