@@ -19,6 +19,13 @@ had never been wired together:
   one chip; shard_map over `parallel/mesh.py`'s ``clients`` axis on a
   mesh — FedJAX's vmapped client simulation, arXiv 2108.02117, grafted
   onto the live loop);
+* a wave's rows are STAGED ONE WAVE AHEAD: as soon as a wave's program
+  is dispatched, one worker thread gathers and hands over the next
+  wave's rows (the same round's, or — its ids are a function of the
+  round index — the next round's first), so the host gather runs beside
+  the chip instead of before it.  The loop takes staged rows only if
+  their ids are the ids it now asks for; anything else (a widened
+  cohort, a debt-carrying client, a resumed run) gathers inline;
 * each wave's stacked updates fold DEVICE-SIDE into the PR 7
   `StreamingAggregator` at wave completion (`fold_wave`: a sequential
   slot-order scan, bit-identical to per-upload folds and to a
@@ -47,6 +54,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional
 
 import jax
@@ -101,12 +109,13 @@ class CrossDevice(FedAvg):
     optional ``mesh`` shards WAVE TRAINING over its ``clients`` axis;
     eval stays the chunked single-chip sweep (`eval_chunk_clients`
     bounds its memory), so cohort size never needs to divide the mesh —
-    only ``wave_size`` does."""
+    only ``wave_size`` does.  ``stage_ahead=False`` (Python only, for
+    the parity tests) gathers every wave inline."""
 
     def __init__(self, workload, data, config: CrossDeviceConfig,
                  mesh=None, sink=None, perf=None, health=None, slo=None,
                  publish=None, server_opt=None, controller=None,
-                 degrade=None, ingest=None):
+                 degrade=None, ingest=None, stage_ahead: bool = True):
         cfg = config
         if cfg.local_alg not in LOCAL_ALGS:
             raise ValueError(f"--local_alg must be one of {LOCAL_ALGS}, "
@@ -229,6 +238,11 @@ class CrossDevice(FedAvg):
         self._timed = (perf is not None or degrade is not None
                        or reg.enabled)
         self._round_ctx = None  # the round span's context (fold worker)
+        # staging one wave ahead: the worker (started with the first wave
+        # staged, joined by `run`) and the one wave it holds or is making
+        self._stage_ahead = stage_ahead
+        self._stage_pool: Optional[ThreadPoolExecutor] = None
+        self._staged = None     # a Future of (ids, pad_to, rows)
         # how the wave program runs its client axis; the sgd / fedprox
         # wave sets it when it is traced, the scaffold and fednova waves
         # keep their own vmap
@@ -474,19 +488,27 @@ class CrossDevice(FedAvg):
                 c_delta if acc["c_delta"] is None else
                 jax.tree.map(jnp.add, acc["c_delta"], c_delta))
 
-    def _dispatch_counts(self, wave) -> dict:
+    def _dispatch_counts(self, wave, prefetched: bool) -> dict:
         """What rides `wave.dispatch` where a tracer keeps spans, counted
         on the host with no device read: the wave's static ``slots`` and the
         client-``steps`` the wave program was handed (slots x steps a
-        slot x epochs); ``slots_sequential`` and ``steps_skipped`` say
+        slot x epochs).  ``slots_sequential`` and ``steps_skipped`` say
         what the sequential client axis made of them: every slot trained
         in turn, and every step whose batch holds no row (all of a padded
         slot's) branched around by the local trainer.  Both are 0 under
         ``vmap``, where a `cond` lowers to a select over both branches
-        (`make_local_trainer`)."""
+        (`make_local_trainer`).  ``slots_prefetched`` is the wave's slots
+        when its rows were taken from the stager (gathered one wave
+        ahead) and 0 when they were gathered inline; ``slots_staged`` is
+        the whole it is a share of, ``slots`` again under a name that
+        came with it: the benchmark's share reader picks its spans by
+        the whole's name, and a program before the stager carries
+        ``slots`` without the part."""
         W, epochs = self.cfg.wave_size, self.cfg.epochs
         steps = W * self.data.train["mask"].shape[1] * epochs
         counts = {"slots": W, "slots_sequential": 0,
+                  "slots_staged": W,
+                  "slots_prefetched": W if prefetched else 0,
                   "steps": steps, "steps_skipped": 0}
         if self._wave_axis == "scan":
             counts["slots_sequential"] = W
@@ -494,7 +516,70 @@ class CrossDevice(FedAvg):
                 self._real_steps[wave.ids].sum())
         return counts
 
+    # -- staging one wave ahead ----------------------------------------------
+    def _stage_next(self, waves, wi, round_idx) -> None:
+        """Called once wave ``wi``'s program is dispatched: have the
+        worker gather and hand over the wave that follows it, the same
+        round's or the first of round ``round_idx + 1`` (none past the
+        last round).  At most one wave is ever staged: the loop takes
+        (or drops) it before it asks for the next."""
+        if not self._stage_ahead:
+            return
+        if wi + 1 < len(waves):
+            ids = waves[wi + 1].ids
+        elif round_idx + 1 < self.cfg.comm_round:
+            ids = None          # the worker samples the next round
+        else:
+            return
+        if self._stage_pool is None:
+            self._stage_pool = ThreadPoolExecutor(
+                1, thread_name_prefix="fedml-stage")
+        self._staged = self._stage_pool.submit(
+            self._stage_wave, ids, round_idx + 1, self._round_ctx)
+
+    def _stage_wave(self, ids, next_round, round_ctx):
+        """On the worker.  ``ids`` None: the first wave of ``next_round``
+        as this moment's sampler would draw it (state the running round
+        has yet to write, a controller's verdict or a client's debt,
+        makes the real draw differ, and the loop then gathers inline).
+        Only the population's rows are read here, which no round writes;
+        the rows gathered are this wave's alone until the loop takes or
+        drops them."""
+        W = self.cfg.wave_size
+        # explicit parent, as `fold_wave`'s: the round this runs beside
+        with self._span("stage.prefetch", parent=round_ctx):
+            if ids is None:
+                ids = plan_waves(self._sample_round(next_round), W)[0].ids
+            # stage.gather and stage.put open inside, under this span
+            return ids, W, gather_cohort(self.data.train, ids, pad_to=W)
+
+    def _take_staged(self, ids, pad_to):
+        """The staged wave's rows if they are what is asked for now (the
+        same ids, the same ``pad_to``), else None: a miss, whose rows are
+        dropped.  Waits for the worker where it is still at it, and
+        re-raises here what it raised."""
+        staged, self._staged = self._staged, None
+        if staged is None:
+            return None
+        staged_ids, staged_pad, rows = staged.result()
+        if staged_pad == pad_to and np.array_equal(staged_ids, ids):
+            return rows
+        return None
+
+    def _stop_staging(self) -> None:
+        """Drop what is staged and join the worker."""
+        self._staged = None
+        if self._stage_pool is not None:
+            self._stage_pool.shutdown(wait=True)
+            self._stage_pool = None
+
     def _run_round(self, params, ids, round_rng, round_idx):
+        """One round over the cohort ``ids``: every wave trained, screened
+        and folded, then finalize and the server step.  A wave's rows
+        come from the stager when it holds exactly that wave (`ids` as
+        the default sampler draws them: every wave but a run's first),
+        and are gathered inline otherwise, so a caller may pass any
+        cohort; the values computed are the same either way."""
         cfg = self.cfg
         W = cfg.wave_size
         waves = plan_waves(ids, W)
@@ -524,9 +609,13 @@ class CrossDevice(FedAvg):
             if wave.n_live == 0:
                 continue  # empty-cohort edge: nothing sampled
             with self._span("wave", "wave", self._h_wave) as wave_sp:
-                # stage.gather and stage.put open inside gather_cohort
-                wave_data = gather_cohort(self.data.train, wave.ids,
-                                          pad_to=W)
+                wave_data = self._take_staged(wave.ids, W)
+                prefetched = wave_data is not None
+                if not prefetched:
+                    # a miss: stage.gather and stage.put open inside
+                    # gather_cohort, under this span
+                    wave_data = gather_cohort(self.data.train, wave.ids,
+                                              pad_to=W)
                 if cfg.local_alg == "scaffold":
                     with self._span("stage.gather"):
                         c_cohort = gather_client_rows(self.c_locals,
@@ -543,8 +632,11 @@ class CrossDevice(FedAvg):
                         stacked, w, mean, total, aux_sums = self._wave_fn(
                             params, wave_data, round_rng, offset)
                         new_c = c_delta = None
+                    # the chip is busy from here: stage the wave after
+                    self._stage_next(waves, wi, round_idx)
                     if self._real_steps is not None:
-                        dispatch_sp.set(**self._dispatch_counts(wave))
+                        dispatch_sp.set(
+                            **self._dispatch_counts(wave, prefetched))
                 with self._span("wave.wait", wait="device"):
                     # blocks: the wave ran to completion
                     wave_weight = float(total)
@@ -644,15 +736,19 @@ class CrossDevice(FedAvg):
             # later jax outputs must key ONE wave jit entry (the PR 5
             # double-compile class)
             params = jax.tree.map(jnp.asarray, params)
-        for round_idx in range(start_round, cfg.comm_round):
-            # the round's root span, one trace id a round; always a live
-            # site (never the null context): the metrics row's round_s is
-            # read from it
-            with trace.TimedSpan(self._tracer, "round", self.perf,
-                                 parent=None,
-                                 round=round_idx) as round_sp:
-                params, rng = self._round(params, rng, round_idx, round_sp,
-                                          checkpointer)
+        try:
+            for round_idx in range(start_round, cfg.comm_round):
+                # the round's root span, one trace id a round; always a
+                # live site (never the null context): the metrics row's
+                # round_s is read from it
+                with trace.TimedSpan(self._tracer, "round", self.perf,
+                                     parent=None,
+                                     round=round_idx) as round_sp:
+                    params, rng = self._round(params, rng, round_idx,
+                                              round_sp, checkpointer)
+        finally:
+            # the last round stages nothing; a round that raised may have
+            self._stop_staging()
         if checkpointer is not None:
             checkpointer.flush()
         if self.ingest is not None:

@@ -148,7 +148,17 @@ def gather_cohort(stacked: Dict[str, Array], client_ids: Sequence[int],
     and any weighted reduction sees an exact ``+0.0`` — a wave of ALL
     pad slots therefore folds as weight 0, never a 0/0 normalizer.  A
     cohort LARGER than ``pad_to`` is a caller bug (the jit downstream
-    would silently retrace on the odd-sized stack) and fails loudly."""
+    would silently retrace on the odd-sized stack) and fails loudly.
+
+    Threads and ownership: ``stacked`` is only read, so any thread may
+    call this beside any other (the cross-device engine calls it on its
+    staging worker, one wave ahead of the loop, and inline on a miss).
+    Every call gathers into fresh host arrays that belong to the
+    returned device arrays alone: ``jnp.asarray`` may return before the
+    copy is done (TPU) or alias the numpy memory (CPU), so nothing here
+    keeps, reuses or writes them afterwards, and a caller must not
+    either.  The two spans open under whatever site is open on the
+    calling thread (`trace.child`)."""
     ids = np.asarray(client_ids, dtype=np.int64)
     if pad_to is not None and len(ids) > pad_to:
         raise ValueError(
@@ -160,8 +170,9 @@ def gather_cohort(stacked: Dict[str, Array], client_ids: Sequence[int],
     if pad_to is not None and n_live < pad_to:
         ids = np.concatenate([ids, np.zeros(pad_to - n_live, np.int64)])
     live = (np.arange(len(ids)) < n_live).astype(np.float32)
-    # two spans under the caller's (the round's ``wave``), where one is
-    # open: the numpy row gather, then the hand-over to the device
+    # two spans under the caller's (the round's ``wave``, or the staging
+    # worker's ``stage.prefetch``), where one is open: the numpy row
+    # gather, then the hand-over to the device
     with trace.child("stage.gather") as sp:
         rows = {k: v[ids] for k, v in stacked.items()}
         if sp is not None:

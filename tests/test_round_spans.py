@@ -3,7 +3,9 @@
 One timing site per boundary (`obs.trace.TimedSpan` through
 `CrossDevice._span`): the span tree of a round, the ledger phases fed from
 the same intervals, the profiler annotations under the span names, the
-repaired tracer clock.  PERF.md section 3 lists every span with the metric
+repaired tracer clock; since ISSUE 35 a wave's rows are staged one wave
+ahead on a worker, under `stage.prefetch` (tests/test_stage_prefetch.py
+holds the mechanism).  PERF.md section 3 lists every span with the metric
 that reads it; tests/test_benchmark_contract.py holds the names the
 benchmark's data files read, the programs' module names among them.
 """
@@ -72,27 +74,45 @@ def test_every_round_is_one_tree_under_one_root(cli_run):
             hops = 0
             while e["args"]["parent_id"] is not None:
                 parent = by_id[e["args"]["parent_id"]]  # no orphan
-                # a child lies inside its parent, on the raw clock
+                # a child lies inside its parent, on the raw clock;
+                # `stage.prefetch` starts inside the round it runs beside
+                # and may end across its edge (the worker stages the next
+                # round's first wave)
+                ends = (e["args"]["t0_ns"] + e["args"]["dur_ns"],
+                        parent["args"]["t0_ns"] + parent["args"]["dur_ns"])
                 assert e["args"]["t0_ns"] >= parent["args"]["t0_ns"]
-                assert (e["args"]["t0_ns"] + e["args"]["dur_ns"]
-                        <= parent["args"]["t0_ns"]
-                        + parent["args"]["dur_ns"])
+                assert e["args"]["t0_ns"] <= ends[1]
+                assert ends[0] <= ends[1] or e["name"] == "stage.prefetch"
                 e, hops = parent, hops + 1
                 assert hops < 8
             assert e is roots[0]
 
 
+def _paths(members):
+    """(parent's name, name) of every span of one round."""
+    by_id = {e["args"]["span_id"]: e for e in members}
+    paths = set()
+    for e in members:
+        parent = by_id.get(e["args"]["parent_id"])
+        paths.add((parent["name"] if parent else None, e["name"]))
+    return paths
+
+
 def test_span_names_of_a_round_are_the_contract(cli_run):
-    for members in _by_round(cli_run["events"]).values():
-        by_id = {e["args"]["span_id"]: e for e in members}
-        paths = set()
-        for e in members:
-            parent = by_id.get(e["args"]["parent_id"])
-            paths.add((parent["name"] if parent else None, e["name"]))
+    for round_idx, members in _by_round(cli_run["events"]).items():
+        paths = _paths(members)
+        # the run's first wave is gathered inline, under `wave` (a miss);
+        # every later one on the staging worker, under `stage.prefetch`
+        # (a hit), and the last round stages nothing past itself
+        staging = {("round", "stage.prefetch"),
+                   ("stage.prefetch", "stage.gather"),
+                   ("stage.prefetch", "stage.put")}
+        inline = {("wave", "stage.gather"), ("wave", "stage.put")}
+        assert staging <= paths
+        assert inline & paths == (inline if round_idx == 0 else set())
         want = {(None, "round"), ("round", "round.sample"),
                 ("round", "round.pin"), ("round", "round.host_copy"),
-                ("round", "wave"), ("wave", "stage.gather"),
-                ("wave", "stage.put"), ("wave", "wave.dispatch"),
+                ("round", "wave"), ("wave", "wave.dispatch"),
                 ("wave", "wave.wait"), ("round", "fold_wave"),
                 ("fold_wave", "admission.copy"),
                 ("fold_wave", "admission.screen"),
@@ -123,6 +143,55 @@ def test_fold_wave_hangs_under_its_round_on_either_thread(cli_run):
             == {root["args"]["span_id"]}
         on_worker = {e["tid"] != root["tid"] for e in folds}
         assert on_worker == {cli_run["pipelined"]}
+
+
+def test_stage_prefetch_hangs_under_the_round_it_ran_in(cli_run):
+    """On the staging worker's thread no span is active: the explicit
+    parent ties a prefetch to the round it ran beside, as `fold_wave`'s
+    does on the ingest worker.  Round 0 stages its waves 1 and 2 and
+    round 1's wave 0; round 1 its waves 1 and 2 and nothing past the
+    last round."""
+    rounds = _by_round(cli_run["events"])
+    for round_idx, members in rounds.items():
+        root = [e for e in members if e["name"] == "round"][0]
+        staged = [e for e in members if e["name"] == "stage.prefetch"]
+        assert len(staged) == (3 if round_idx == 0 else 2)
+        for e in staged:
+            assert e["args"]["parent_id"] == root["args"]["span_id"]
+            assert e["tid"] != root["tid"]
+            assert (root["args"]["t0_ns"] <= e["args"]["t0_ns"]
+                    <= root["args"]["t0_ns"] + root["args"]["dur_ns"])
+            assert "phase" not in e["args"]      # no ledger phase
+        # one gather and one put under each, on the worker's thread
+        by_parent = {}
+        for e in members:
+            by_parent.setdefault(e["args"]["parent_id"], []).append(e)
+        for e in staged:
+            kids = by_parent[e["args"]["span_id"]]
+            assert sorted(k["name"] for k in kids) \
+                == ["stage.gather", "stage.put"]
+            assert {k["tid"] for k in kids} == {e["tid"]}
+
+
+def test_a_miss_holds_four_children_and_a_hit_two(cli_run):
+    """`stage.gather + stage.put + wave.dispatch + wave.wait = wave` on a
+    wave gathered inline (the run's first); a hit holds the last two
+    and whatever it waited for the worker."""
+    events = cli_run["events"]
+    waves = sorted((e for e in events if e["name"] == "wave"),
+                   key=lambda e: e["args"]["t0_ns"])
+    assert len(waves) == 6
+    for i, wave in enumerate(waves):
+        kids = [e for e in events
+                if e["args"]["parent_id"] == wave["args"]["span_id"]]
+        names = sorted(k["name"] for k in kids)
+        if i == 0:
+            assert names == ["stage.gather", "stage.put", "wave.dispatch",
+                             "wave.wait"]
+        else:
+            assert names == ["wave.dispatch", "wave.wait"]
+        assert sum(k["args"]["dur_ns"] for k in kids) \
+            <= wave["args"]["dur_ns"]
 
 
 def test_leaf_spans_of_one_thread_do_not_overlap(cli_run):
@@ -160,7 +229,12 @@ def test_each_ledger_phase_is_the_sum_of_its_spans(cli_run):
 def test_counts_ride_the_staging_spans(cli_run):
     gathers = [e for e in cli_run["events"] if e["name"] == "stage.gather"]
     puts = [e for e in cli_run["events"] if e["name"] == "stage.put"]
+    # still one gather and one put a wave, wherever they ran
     assert len(gathers) == len(puts) == 6
+    by_id = {e["args"]["span_id"]: e for e in cli_run["events"]}
+    assert sorted(by_id[e["args"]["parent_id"]]["name"] for e in gathers) \
+        == sorted(by_id[e["args"]["parent_id"]]["name"] for e in puts) \
+        == ["stage.prefetch"] * 5 + ["wave"]
     for g in gathers:
         assert g["args"]["bytes"] > 0
         assert 0 < g["args"]["rows_real"] <= g["args"]["rows_padded"]
@@ -174,6 +248,13 @@ def test_slot_counts_ride_the_dispatch_span(cli_run):
     dispatches = [e for e in cli_run["events"]
                   if e["name"] == "wave.dispatch"]
     assert len(dispatches) == 6
+    dispatches.sort(key=lambda e: e["args"]["t0_ns"])
+    # every wave but the run's first took its rows from the stager
+    # (ISSUE 35): the default sampler's ids are what the worker drew
+    assert [d["args"]["slots_prefetched"] for d in dispatches] \
+        == [0, 4, 4, 4, 4, 4]
+    assert all(d["args"]["slots_staged"] == d["args"]["slots"]
+               for d in dispatches)
     for d in dispatches:
         # `lr` holds no convolution: the wave program vmaps its 4 slots
         assert d["args"]["slots"] == 4
@@ -283,6 +364,25 @@ def test_skipped_step_share_reads_the_dispatch_span_as_data():
         in bench["per_layer"]
 
 
+def test_prefetch_hit_share_reads_the_dispatch_span_as_data():
+    spec = json.load(open(os.path.join(
+        ROOT, "benchmark", "layer_metrics",
+        "stage_prefetch_hit_share.json")))
+    assert spec["reader"] == "benchmark.span_readers:arg_share"
+    # the whole is `slots` under a name a program before ISSUE 35 does
+    # not carry: `arg_share` picks its spans by the whole's name and
+    # raises on one that lacks the part, which the parent's would
+    assert spec["args"] == {"name": "wave.dispatch",
+                            "part": "slots_prefetched",
+                            "whole": "slots_staged"}
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    assert {
+        "name": "stage_prefetch_hit_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "staging and local training",
+        "moves": "round_s", "workloads": ["resnet56_cifar10.silos10"]} \
+        == bench["per_layer"][-1]
+
+
 def test_export_keeps_wall_ts_and_raw_monotonic_clock(cli_run):
     other = cli_run["doc"]["otherData"]
     assert other["clock"] == "perf_counter_ns"
@@ -334,8 +434,8 @@ def test_spans_are_profiler_annotations_on_the_host_plane(workload, data,
             if p.name == "/host:CPU"]
     names = {e.name for p in host for line in p.lines for e in line.events}
     assert {s["name"] for s in perf.tracer.spans} <= names
-    assert {"round", "wave", "stage.gather", "stage.put", "wave.dispatch",
-            "wave.wait", "fold_wave", "finalize.dispatch",
+    assert {"round", "wave", "stage.prefetch", "stage.gather", "stage.put",
+            "wave.dispatch", "wave.wait", "fold_wave", "finalize.dispatch",
             "round.sync"} <= names
 
 
