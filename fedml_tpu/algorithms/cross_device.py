@@ -26,13 +26,19 @@ had never been wired together:
   the chip instead of before it.  The loop takes staged rows only if
   their ids are the ids it now asks for; anything else (a widened
   cohort, a debt-carrying client, a resumed run) gathers inline;
-* each wave's stacked updates fold DEVICE-SIDE into the PR 7
-  `StreamingAggregator` at wave completion (`fold_wave`: a sequential
-  slot-order scan, bit-identical to per-upload folds and to a
-  single-wave round) — never a ``[cohort, ...]`` host stack, so server
-  memory stays O(model) + one O(wave) device buffer at ANY cohort size;
-* per-wave admission screens (structure / finite / norm against the
-  wave summary, `device_cohort.WaveAdmission`), the PR 8 health sketch
+* each wave folds DEVICE-SIDE into the PR 7 `StreamingAggregator` at
+  wave completion — never a ``[cohort, ...]`` host stack.  Where nothing
+  reads one client's result (plain or fedprox local training in
+  sequence, no per-upload clip or noise, no poison seam) the wave
+  program carries the slot-order weighted sum itself and the fold takes
+  that one summary (`fold_sum`): no tree a client is made, so a GB-size
+  tree trains in waves.  Otherwise the stacked results fold
+  (`fold_wave`: a sequential slot-order scan, bit-identical to
+  per-upload folds and to a single-wave round) and server memory is
+  O(model) + one O(wave) device buffer at ANY cohort size;
+* per-wave admission screens (structure / finite / norm,
+  `device_cohort.WaveAdmission`) on statistics the wave program computes
+  beside its summary, so the host walks no tree; the PR 8 health sketch
   and PR 9 compile ledger ride every wave, and perf.jsonl gains a
   ``wave`` phase — drift and re-jits at 100k scale are named, not
   guessed;
@@ -54,6 +60,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional
 
@@ -68,10 +75,15 @@ from fedml_tpu.algorithms.fedavg import (FedAvg, FedAvgConfig,
 from fedml_tpu.core.sampling import sample_clients, sample_clients_jax
 from fedml_tpu.core.stream_agg import StreamingAggregator
 from fedml_tpu.data.stacking import gather_cohort
-from fedml_tpu.device_cohort import (WaveAdmission, make_scaffold_wave_fn,
-                                     make_wave_fn, plan_waves)
+from fedml_tpu.device_cohort import (WaveAdmission, admission_stats,
+                                     make_scaffold_wave_fn,
+                                     make_summed_wave_fn, make_wave_fn,
+                                     mean_of_sum, plan_waves)
 from fedml_tpu.obs import telemetry, trace
-from fedml_tpu.parallel.cohort import choose_client_axis, train_cohort
+from fedml_tpu.parallel.cohort import (choose_client_axis,
+                                       device_memory_bytes, train_cohort,
+                                       train_cohort_sum,
+                                       wave_outgrows_device)
 from fedml_tpu.parallel.mesh import placement_of
 from fedml_tpu.trainer.local_sgd import make_local_trainer
 from fedml_tpu.trainer.workload import make_client_optimizer
@@ -254,11 +266,32 @@ class CrossDevice(FedAvg):
             np.asarray(data.train["mask"]).any(axis=-1).sum(axis=-1)
             if self._tracer is not None else None)
 
+        # the round's global on the host, kept by the round that made it:
+        # (the device tree it mirrors, the copy `round.crc` took)
+        self._mirror = None
+        # the worker that takes that copy and its CRC beside the next
+        # round's wave program, and the one job it holds or is at
+        self._crc_pool: Optional[ThreadPoolExecutor] = None
+        self._crc_job = None
+        # set once the next round's first wave is dispatched: the job's
+        # transfer starts behind that launch, not in front of it
+        self._crc_go = threading.Event()
+        # whether the wave leaves the device as its sum (`_ensure_bound`
+        # decides, from the tree): the program that does, where the
+        # configuration allows one
+        self._summed = False
+        self._summed_any_round = False
+        self._summed_fn = None
         self._wave_fn = self._build_wave_fn(workload, cfg, mesh)
+        self._stats_fn = jax.jit(admission_stats)
+        self._mean_fn = jax.jit(mean_of_sum)
         if perf is not None:
             # the wave program is THE hot jit of this engine: recompile
             # sentry + (under --device_obs) compile ledger / MFU gauge
             self._wave_fn = perf.instrument_jit("wave_train", self._wave_fn)
+            if self._summed_fn is not None:
+                self._summed_fn = perf.instrument_jit("wave_train_summed",
+                                                      self._summed_fn)
 
     # -- wave program construction ------------------------------------------
     def _build_wave_fn(self, workload, cfg, mesh):
@@ -269,17 +302,37 @@ class CrossDevice(FedAvg):
                 workload, opt, cfg.epochs,
                 prox_mu=cfg.mu if cfg.local_alg == "fedprox" else 0.0)
 
+            def counted(metrics, wave_data):
+                # what the workload's loss counted (an expert layer's
+                # tokens), a client: the wave summary weights every aux
+                # by the client's rows, so each is divided by them first
+                # and the weighted sum is the plain sum over live clients
+                w = jnp.maximum(wave_data["num_samples"].astype(
+                    jnp.float32), 1.0)
+                return {k: v / w.reshape((-1,) + (1,) * (v.ndim - 1))
+                        for k, v in metrics.get("counters", {}).items()}
+
             def make_stacked(params, wave_data, rng, offset):
                 # resolved here, under the wave program's trace (shapes
                 # are static there), and kept for `wave.dispatch`'s
                 # slot counts
-                self._wave_axis = (cfg.client_axis
-                                   or choose_client_axis(params))
-                stacked, _ = train_cohort(local, params, wave_data, rng,
-                                          index_offset=offset,
-                                          client_axis=self._wave_axis)
-                return stacked, {}
+                self._wave_axis = self._choose_axis(params)
+                stacked, metrics = train_cohort(
+                    local, params, wave_data, rng, index_offset=offset,
+                    client_axis=self._wave_axis)
+                return stacked, counted(metrics, wave_data)
 
+            def train_summed(params, wave_data, rng, offset):
+                wave_sum, total, metrics = train_cohort_sum(
+                    local, params, wave_data, rng, index_offset=offset)
+                return wave_sum, total, counted(metrics, wave_data)
+
+            if (mesh is None and not cfg.norm_clip
+                    and not cfg.agg_noise_std and not cfg.wave_adversary):
+                # nothing reads one client's result: where the clients
+                # also train in sequence (`_ensure_bound`), the wave
+                # leaves the device as its sum
+                self._summed_fn = make_summed_wave_fn(train_summed)
             return make_wave_fn(make_stacked, mesh=mesh)
 
         if cfg.local_alg == "fednova":
@@ -314,6 +367,13 @@ class CrossDevice(FedAvg):
         from fedml_tpu.algorithms.scaffold import make_scaffold_local
         local = make_scaffold_local(workload, cfg.lr, cfg.epochs)
         return make_scaffold_wave_fn(local, cfg.lr)
+
+    def _choose_axis(self, params) -> str:
+        """The sgd / fedprox wave's client axis: the one named, else
+        `choose_client_axis` of the tree, the wave and this device."""
+        cfg = self.cfg
+        return cfg.client_axis or choose_client_axis(
+            params, cfg.wave_size, device_memory_bytes())
 
     # -- sampling -------------------------------------------------------------
     def _sample_round(self, round_idx: int) -> np.ndarray:
@@ -361,6 +421,20 @@ class CrossDevice(FedAvg):
     def _ensure_bound(self, params) -> None:
         if self.stream is None:
             cfg = self.cfg
+            if self._summed_fn is not None:
+                # the sum stands for the clients' results only where they
+                # train one after another (its order is the fold's) and
+                # every leaf accumulates in its own dtype
+                self._wave_axis = self._choose_axis(params)
+                self._summed = (self._wave_axis == "scan" and all(
+                    jnp.issubdtype(x.dtype, jnp.floating)
+                    for x in jax.tree.leaves(params)))
+                # a round of several waves adds wave sums, which orders
+                # its additions by wave and not by slot: kept to trees
+                # whose stacked wave could not be held anyway
+                self._summed_any_round = self._summed and \
+                    wave_outgrows_device(params, cfg.wave_size,
+                                         device_memory_bytes())
             self.stream = StreamingAggregator(
                 params, method="mean", kind="params",
                 norm_clip=cfg.norm_clip, noise_std=cfg.agg_noise_std,
@@ -406,7 +480,7 @@ class CrossDevice(FedAvg):
 
     def _fold_one(self, round_idx, wi, wave, stacked, w, mean,
                   wave_weight, aux_sums, new_c, c_delta, host_params,
-                  acc) -> None:
+                  acc, stats=None, total=None) -> None:
         """Post-wave work for ONE completed wave: admission screen →
         stream fold → health sketch → local-alg accumulation.  Runs
         inline, or (``--ingest_pipeline``) on the single fold worker in
@@ -423,18 +497,34 @@ class CrossDevice(FedAvg):
         with self._span("fold_wave", parent=self._round_ctx):
             self._screen_and_fold(
                 round_idx, wi, wave, stacked, w, mean, wave_weight,
-                aux_sums, new_c, c_delta, host_params, acc)
+                aux_sums, new_c, c_delta, host_params, acc, stats, total)
 
     def _screen_and_fold(self, round_idx, wi, wave, stacked, w, mean,
                          wave_weight, aux_sums, new_c, c_delta,
-                         host_params, acc):
-        """`_fold_one`'s body, under its span."""
+                         host_params, acc, stats, total):
+        """`_fold_one`'s body, under its span.  ``stacked`` is the wave's
+        results a client, or, where the wave left the device as its sum
+        (``self._summed``), that sum, ``mean`` then None and ``total``
+        its weight on the device.  The screen reads ``stats``, a few
+        numbers the wave program made beside its summary; the wave's
+        mean comes to the host only for the poison seam and the health
+        sketch."""
         cfg = self.cfg
-        with self._span("admission.copy", "admission", wait="device"):
-            mean_host = jax.tree.map(np.asarray, mean)
+        summed = mean is None
+        attack = self._wave_attacks.get((round_idx, wi))
+        mean_host = None
+        if attack is not None or self.health is not None:
+            with self._span("admission.copy", "admission", wait="device"):
+                if mean is None:
+                    mean = self._mean_fn(stacked, total,
+                                         self.stream.reference)
+                mean_host = jax.device_get(mean)
         with self._span("admission.screen", "admission"):
-            attack = self._wave_attacks.get((round_idx, wi))
-            if attack is not None:
+            if attack is None:
+                verdict = self.admission.screen(
+                    stacked if mean is None else mean,
+                    stats=jax.device_get(stats))
+            else:
                 # poison the WAVE SUMMARY pre-admission: the screen, the
                 # health sketch, and the fold all see the attacked mean —
                 # exactly what a compromised wave aggregation would ship
@@ -444,7 +534,7 @@ class CrossDevice(FedAvg):
                                                 seed=cfg.seed)
                 logger.warning("round %d wave %d POISONED (%s:%g)",
                                round_idx, wi, attack.kind, attack.param)
-            verdict = self.admission.screen(mean_host, host_params)
+                verdict = self.admission.screen(mean_host, host_params)
         if not verdict.ok:
             logger.warning("round %d wave %d REJECTED (%s): %d "
                            "clients' work discarded", round_idx, wi,
@@ -466,6 +556,10 @@ class CrossDevice(FedAvg):
                         jnp.asarray(m, dtype=s.dtype), s.shape),
                     mean_host, stacked)
                 self.stream.fold_wave(poisoned, w)
+            elif summed:
+                # the wave's own slot-order sum is the summary the fold
+                # takes (`mean`, where made above, was made from it)
+                self.stream.fold_sum(stacked, w, total)
             else:
                 self.stream.fold_wave(stacked, w)
         acc["folded"] += 1
@@ -515,6 +609,23 @@ class CrossDevice(FedAvg):
             counts["steps_skipped"] = steps - epochs * int(
                 self._real_steps[wave.ids].sum())
         return counts
+
+    @staticmethod
+    def _expert_counts(aux_sums) -> dict:
+        """What an expert model's layers counted over the wave's steps
+        (`models.moe.SharedExpertMoE`, summed over layers, steps and
+        clients), for `wave.dispatch`: the ``tokens`` routed, their
+        ``expert_assignments`` (tokens x experts a token),
+        ``expert_assignments_held`` (those whose expert this chip holds),
+        and the sums over layer-steps of the fullest held expert's tokens
+        and of the mean held expert's (``expert_load_max`` /
+        ``expert_load_mean``).  Nothing for a model that counts none."""
+        if "moe" not in aux_sums:
+            return {}
+        names = ("tokens", "expert_assignments", "expert_assignments_held",
+                 "expert_load_max", "expert_load_mean")
+        return dict(zip(names, (float(v) for v in
+                                jax.device_get(aux_sums["moe"]))))
 
     # -- staging one wave ahead ----------------------------------------------
     def _stage_next(self, waves, wi, round_idx) -> None:
@@ -566,12 +677,52 @@ class CrossDevice(FedAvg):
             return rows
         return None
 
+    # -- the global's CRC, one round behind -----------------------------------
+    def _start_crc(self, params):
+        """Hand the round's new global to the worker: ONE batched transfer
+        to the host (every leaf's started before any is awaited), its
+        `tree_crc`, and the copy kept as the next round's host copy
+        (`_run_round`).  Returns the job, a future of the CRC.  At most
+        one job is in flight: the one before it, which ran beside this
+        round's wave program, is joined first and re-raises here.  The
+        job waits for ``_crc_go`` (the next round's first dispatch, or
+        the run's end): forty transfers started in front of a wave
+        program's launch held it back 0.1-0.2 s (my chip run, PR 37)."""
+        if self._crc_job is not None:
+            self._crc_job.result()
+        self._crc_go.clear()
+        if self._crc_pool is None:
+            self._crc_pool = ThreadPoolExecutor(
+                1, thread_name_prefix="fedml-crc")
+        self._crc_job = self._crc_pool.submit(self._crc_of, params,
+                                              self._round_ctx)
+        return self._crc_job
+
+    def _crc_of(self, params, round_ctx) -> int:
+        """On the worker.  Reads ``params``, which no round writes."""
+        from fedml_tpu.utils.journal import tree_crc
+        self._crc_go.wait(10.0)
+        # explicit parent, as `stage.prefetch`'s: the round it closes
+        with self._span("round.crc", parent=round_ctx):
+            host = jax.device_get(params)
+            crc = tree_crc(host)
+            if self.health is not None or self._wave_attacks:
+                # kept only for a reader: the pair holds the device
+                # tree too
+                self._mirror = (params, host)
+        return crc
+
     def _stop_staging(self) -> None:
         """Drop what is staged and join the worker."""
         self._staged = None
         if self._stage_pool is not None:
             self._stage_pool.shutdown(wait=True)
             self._stage_pool = None
+        self._crc_go.set()
+        if self._crc_pool is not None:
+            self._crc_pool.shutdown(wait=True)    # the last line lands
+            self._crc_pool = None
+        self._crc_job = None
 
     def _run_round(self, params, ids, round_rng, round_idx):
         """One round over the cohort ``ids``: every wave trained, screened
@@ -587,19 +738,38 @@ class CrossDevice(FedAvg):
         # opened around this call (none when a caller drives rounds itself)
         self._round_ctx = (self._tracer.current_context()
                            if self._tracer is not None else None)
+        # the host's copy of this global, if the round that made it kept
+        # one (looked up before the pin, which may hand back another tree)
+        needs_host = self.health is not None or bool(self._wave_attacks)
+        if needs_host and self._crc_job is not None:
+            self._crc_go.set()          # no launch to wait behind: now
+            self._crc_job.result()      # the worker may still be at it
+        mirror, self._mirror = self._mirror, None
+        host_params = (mirror[1] if mirror is not None
+                       and mirror[0] is params else None)
         with self._span("round.pin"):
             params = self._pin_placement(params)
             self._ensure_bound(params)
             self.admission.round_start()
             self.stream.reset(params)
         with self._span("round.host_copy", wait="device"):
-            host_params = jax.tree.map(np.asarray, params)
+            # read by the health sketch and the poison seam alone: the
+            # kept copy where there is one, else one batched transfer
+            # (every leaf's started before any is awaited), else nothing
+            if not needs_host:
+                host_params = None
+            elif host_params is None:
+                host_params = jax.device_get(params)
         if self.health is not None:
             self.health.round_start(round_idx, host_params,
                                     expected=range(1, len(waves) + 1))
         # cross-wave accumulators: one mutable dict so the fold worker
         # (--ingest_pipeline) and the inline path share the same code;
         # the main thread reads it only after the pre-finalize drain
+        # the wave as its sum: a one-wave round (the sum is the slot-order
+        # fold, bit for bit), or any round of a tree too large to stack
+        summed = self._summed and (len(waves) == 1
+                                   or self._summed_any_round)
         acc = {"tau": 0.0,             # fednova: Σ n_i·tau_i across waves
                "c_delta": None,        # scaffold: Σ live·(c_i+ − c_i)
                "folded": 0, "live": 0}
@@ -628,12 +798,22 @@ class CrossDevice(FedAvg):
                                              offset, self.c_global,
                                              c_cohort)
                         aux_sums = {}
+                        stats = self._stats_fn(mean, params)
+                    elif summed:
+                        # `stacked` is the wave's sum from here on
+                        stacked, w, total, aux_sums, stats = \
+                            self._summed_fn(params, wave_data, round_rng,
+                                            offset)
+                        mean = new_c = c_delta = None
                     else:
                         stacked, w, mean, total, aux_sums = self._wave_fn(
                             params, wave_data, round_rng, offset)
                         new_c = c_delta = None
-                    # the chip is busy from here: stage the wave after
+                        stats = self._stats_fn(mean, params)
+                    # the chip is busy from here: stage the wave after,
+                    # and let the last round's CRC job start its transfer
                     self._stage_next(waves, wi, round_idx)
+                    self._crc_go.set()
                     if self._real_steps is not None:
                         dispatch_sp.set(
                             **self._dispatch_counts(wave, prefetched))
@@ -642,6 +822,8 @@ class CrossDevice(FedAvg):
                     wave_weight = float(total)
                     if wi == len(waves) - 1:
                         wave_devices = placement_of(stacked)["devices"]
+                    if self._real_steps is not None:
+                        dispatch_sp.set(**self._expert_counts(aux_sums))
             self._c_waves.inc()
             if self.degrade is not None:
                 # every live client completed with the wave: feed the
@@ -664,11 +846,11 @@ class CrossDevice(FedAvg):
                 self.ingest.submit_wait(0, functools.partial(
                     self._fold_one, round_idx, wi, wave, stacked, w,
                     mean, wave_weight, aux_sums, new_c, c_delta,
-                    host_params, acc))
+                    host_params, acc, stats, total))
             else:
                 self._fold_one(round_idx, wi, wave, stacked, w, mean,
                                wave_weight, aux_sums, new_c, c_delta,
-                               host_params, acc)
+                               host_params, acc, stats, total)
 
         if self.ingest is not None:
             # rendezvous: every queued fold lands before finalize reads
@@ -711,7 +893,7 @@ class CrossDevice(FedAvg):
         self._c_rounds.inc()
         if self.health is not None:
             self.health.round_end(
-                round_idx, new_global=jax.tree.map(np.asarray, new_params),
+                round_idx, new_global=jax.device_get(new_params),
                 cohort=len(ids), waves=len(waves), folded_waves=folded)
         return new_params, {"waves": len(waves), "folded_waves": folded,
                             "clients": live_clients,
@@ -746,9 +928,17 @@ class CrossDevice(FedAvg):
                                      round=round_idx) as round_sp:
                     params, rng = self._round(params, rng, round_idx,
                                               round_sp, checkpointer)
+            self._crc_go.set()
+            if self._crc_job is not None:
+                self._crc_job.result()      # the last round's; re-raises
         finally:
             # the last round stages nothing; a round that raised may have
             self._stop_staging()
+            # nothing model-sized outlives the loop: the engine sits in
+            # reference cycles (its wave closures) and is collected late
+            self._mirror = None
+            if self.stream is not None:
+                self.stream.release()
         if checkpointer is not None:
             checkpointer.flush()
         if self.ingest is not None:
@@ -788,10 +978,10 @@ class CrossDevice(FedAvg):
             # bench's bit-parity gate compares this sequence between
             # the inline and pipelined twins (utils.journal.tree_crc
             # — the same checksum the crash journal trusts)
-            from fedml_tpu.utils.journal import tree_crc
-            with self._span("round.crc"):
-                extra["global_crc"] = tree_crc(
-                    jax.tree.map(np.asarray, params))
+            # taken by the worker beside the next round's wave program
+            # (the copy and `zlib.crc32` both release the GIL); the
+            # ledger line below is written when it is there
+            extra["global_crc"] = self._start_crc(params)
             if self.server_opt is not None:
                 extra["server_opt"] = self.server_opt.name
             if decision is not None:

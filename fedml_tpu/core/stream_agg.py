@@ -195,6 +195,13 @@ class StreamingAggregator:
                     body, (acc, wsum), (stacked, weights))
                 return acc, wsum
 
+            def _fold_sum(acc, wsum, wave_sum, wave_weight):
+                # a wave that left the device as its own slot-order sum
+                # (`fold_sum`): one add a leaf
+                return (jax.tree.map(jnp.add, acc, wave_sum),
+                        wsum + wave_weight)
+
+            self._fold_sum_fn = jax.jit(_fold_sum, donate_argnums=(0, 1))
             self._fold_fn = jax.jit(
                 _fold, donate_argnums=(0, 1))
             self._fold_wave_fn = jax.jit(
@@ -250,6 +257,7 @@ class StreamingAggregator:
             # uncalled jit contributes 0, so per-upload-only rounds keep
             # the historical cache==1 pin and wave-only rounds read 1 too
             n += int(self._fold_wave_fn._cache_size())
+            n += int(self._fold_sum_fn._cache_size())
         return n
 
     # -- crash consistency (utils/journal.py) --------------------------------
@@ -332,6 +340,12 @@ class StreamingAggregator:
         if self._res_weights is not None:
             self._res_weights[:] = 0.0
         self._g_reservoir.set(0)
+
+    def release(self) -> None:
+        """Between runs: drop the round's reference and accumulator, so
+        that nothing model-sized (on the device, or mirrored on the host
+        inside those arrays) outlives the loop that used this object."""
+        self._reference = self._acc = self._wsum = None
 
     def _pad_template(self):
         """What an unfolded reservoir slot holds: the reference — the
@@ -430,6 +444,42 @@ class StreamingAggregator:
         self.count += live
         # slot-order sequential host adds — the per-upload path's exact
         # weight_total arithmetic (np.sum's pairwise order would differ)
+        for w in w_host:
+            self.weight_total += float(w)
+
+    def fold_sum(self, wave_sum, weights, wave_weight) -> None:
+        """Fold one compiled wave that comes as its SUM: ``wave_sum`` is
+        ``sum_i w_i * update_i`` over the wave's slots in slot order, in
+        the accumulator's dtype (`parallel/cohort.train_cohort_sum`), and
+        ``wave_weight`` the running sum of the weights made beside it
+        (a device scalar).  The round's first such wave IS the
+        accumulator (nothing is added, nothing copied), so a one-wave
+        round closes on the very bits `fold_wave` gives the stacked
+        results; a later wave is added leaf by leaf, which orders a
+        many-wave round's additions by wave and agrees with the
+        slot-order fold to rounding.  Only a fold that reads no single
+        upload can take a sum: ``norm_clip`` clips each one and is
+        refused here.  ``weights`` are the slots' host weights, for the
+        counts `fold_wave` keeps."""
+        if self._reference is None:
+            raise RuntimeError("fold_sum() before reset(): the round's "
+                               "reference is not set")
+        if self.method != "mean" or self.norm_clip > 0:
+            raise RuntimeError(
+                "fold_sum: a pre-summed wave suits the plain streaming "
+                "mean only; a clip or an order-statistic rule reads each "
+                "upload (fold_wave / fold)")
+        w_host = np.asarray(weights, np.float32)
+        live = int((w_host > 0).sum())
+        if self._acc is None:
+            self._acc = wave_sum
+            self._wsum = jnp.asarray(wave_weight, jnp.float32)
+        else:
+            self._acc, self._wsum = self._fold_sum_fn(
+                self._acc, self._wsum, wave_sum,
+                jnp.asarray(wave_weight, jnp.float32))
+        self._c_folds.inc(live)
+        self.count += live
         for w in w_host:
             self.weight_total += float(w)
 

@@ -115,6 +115,11 @@ def _register_all() -> None:
                      partial(synthetic_federated_dataset,
                              sample_shape=(10000,), class_num=500,
                              multilabel=True))
+    from . import token_shards
+    register_dataset("token_shards", token_shards.load_token_shards,
+                     partial(synthetic_federated_dataset,
+                             sample_shape=(64,), sequence_vocab=100,
+                             class_num=100))
     for ds in ("cifar10", "cifar100", "cinic10"):
         register_dataset(
             ds,

@@ -9,8 +9,12 @@ loop's O(model) aggregation (`core/stream_agg.py`).
 """
 
 from fedml_tpu.device_cohort.waves import (Wave, WaveAdmission,
+                                           admission_stats,
                                            make_scaffold_wave_fn,
-                                           make_wave_fn, plan_waves)
+                                           make_summed_wave_fn,
+                                           make_wave_fn, mean_of_sum,
+                                           plan_waves)
 
-__all__ = ["Wave", "WaveAdmission", "make_wave_fn",
-           "make_scaffold_wave_fn", "plan_waves"]
+__all__ = ["Wave", "WaveAdmission", "admission_stats", "make_wave_fn",
+           "mean_of_sum",
+           "make_summed_wave_fn", "make_scaffold_wave_fn", "plan_waves"]

@@ -7,11 +7,15 @@ fixed-size waves (the last one padded with weight-0 slots, the
 `gather_cohort` convention), so every wave of every round hits ONE jit
 cache entry; `make_wave_fn` compiles the wave: local training over the
 stacked client axis (`parallel/cohort.train_cohort` — vmap, or clients in
-sequence for a conv model, on one chip; shard_map over the mesh's
-``clients`` axis), plus the wave SUMMARY the
-host needs for admission/health — the weighted partial mean, the weight
-total, and any per-client aux reductions — computed on device so the
-host never walks the ``[wave, ...]`` stack.
+sequence for a conv model or a tree too large to hold once a client, on
+one chip; shard_map over the mesh's ``clients`` axis), plus the wave
+SUMMARY the host needs for admission/health — the weighted partial mean,
+the weight total, any per-client aux reductions, and the admission
+screen's statistics (`admission_stats`: is the mean finite, how far is
+each leaf from the round's global) — computed on device so the host
+never walks the ``[wave, ...]`` stack, nor the mean.  `make_summed_wave_fn`
+is the wave of an engine that reads nothing of one client's result: the
+clients train in sequence and only their weighted sum leaves the program.
 
 Per-client rng = fold_in(round_rng, global cohort slot) via the wave's
 ``offset`` (a traced scalar, so chunking does not retrace): a
@@ -97,11 +101,61 @@ def _wave_summary(stacked: Pytree, w: jax.Array, aux: Dict[str, jax.Array],
                               axis=0)).astype(x.dtype)
 
     mean = jax.tree.map(_mean, stacked)
-    aux_sums = {k: allsum(jnp.sum(
-        v.astype(jnp.float32)
-        * w.reshape((-1,) + (1,) * (v.ndim - 1)), axis=0))
-        for k, v in aux.items()}
-    return mean, total, aux_sums
+    return mean, total, {k: allsum(v) for k, v in _aux_sums(aux, w).items()}
+
+
+def _aux_sums(aux: Dict[str, jax.Array], w: jax.Array):
+    """Per-client aux arrays -> their sums weighted by the clients' ``w``."""
+    return {k: jnp.sum(v.astype(jnp.float32)
+                       * w.reshape((-1,) + (1,) * (v.ndim - 1)), axis=0)
+            for k, v in aux.items()}
+
+
+def mean_of_sum(wave_sum: Pytree, total: jax.Array, like: Pytree) -> Pytree:
+    """A summed wave's mean, in ``like``'s dtypes (an all-pad wave's
+    total 0 divides by `_wave_summary`'s guard)."""
+    guard = jnp.maximum(total, 1e-6)
+    return jax.tree.map(
+        lambda a, p: (a / guard.astype(a.dtype)).astype(p.dtype),
+        wave_sum, like)
+
+
+def admission_stats(mean: Pytree, reference: Pytree) -> Dict[str, jax.Array]:
+    """What `WaveAdmission.screen` needs of a wave's mean, computed where
+    the mean lives: ``finite`` (every floating leaf finite) and
+    ``leaf_sumsq``, each leaf's ``sum((mean - reference)^2)`` in float32
+    (the host adds them in float64 and takes the root)."""
+    leaves = list(zip(jax.tree.leaves(mean), jax.tree.leaves(reference)))
+    finite = jnp.stack([jnp.all(jnp.isfinite(m)) for m, _ in leaves
+                        if jnp.issubdtype(m.dtype, jnp.floating)]
+                       or [jnp.bool_(True)])
+    return {"finite": jnp.all(finite),
+            "leaf_sumsq": jnp.stack([
+                jnp.sum(jnp.square(m.astype(jnp.float32)
+                                   - r.astype(jnp.float32)))
+                for m, r in leaves])}
+
+
+def make_summed_wave_fn(train_summed: Callable):
+    """Compile one wave of which only the sum is read:
+    ``wave_fn(params, wave_data, rng, offset) -> (wave_sum, weights,
+    wave_weight, aux_sums, stats)``.
+
+    ``train_summed(params, wave_data, rng, offset) -> (sum, weight total,
+    aux)`` trains the wave's clients one after another and carries
+    ``sum_i w_i * result_i`` (`parallel/cohort.train_cohort_sum`); ``aux``
+    maps names to per-client arrays, reduced here to weighted sums as
+    `make_wave_fn` reduces them.  ``wave_sum`` is what
+    `StreamingAggregator.fold_sum` takes: no tree a client, and no mean,
+    is made.  ``stats`` are `admission_stats` of the wave's mean (divided
+    out leaf by leaf inside the reductions, never kept)."""
+    @jax.jit
+    def wave_fn(params, wave_data, rng, offset):
+        wave_sum, total, aux = train_summed(params, wave_data, rng, offset)
+        w = wave_data["num_samples"].astype(jnp.float32)
+        return (wave_sum, w, total, _aux_sums(aux, w), admission_stats(
+            mean_of_sum(wave_sum, total, params), params))
+    return wave_fn
 
 
 def make_wave_fn(make_stacked: Callable, mesh: Optional[Mesh] = None):
@@ -273,15 +327,29 @@ class WaveAdmission:
         return norm_outlier_threshold(self._norms, self.norm_k,
                                       self.norm_min_history)
 
-    def screen(self, wave_mean, global_params) -> AdmissionVerdict:
+    def screen(self, wave_mean, global_params=None,
+               stats=None) -> AdmissionVerdict:
         """Screen one wave's summary against the round's global.  Order
-        matters: structure before any tree math (the pipeline's rule)."""
+        matters: structure before any tree math (the pipeline's rule).
+
+        With ``stats`` (`admission_stats`, read from the device) the mean
+        is not walked: ``wave_mean`` then only has to show its structure
+        (device arrays or shapes do), the finite flag is the device's and
+        the norm is the root of the leaves' squared distances, added in
+        float64.  Without, ``wave_mean`` is a host tree and the walk is
+        the live pipeline's own."""
         try:
             fp_ok = params_fingerprint(wave_mean) == self.fingerprint
         except Exception:  # noqa: BLE001 — unhashable garbage summary
             fp_ok = False
         if not fp_ok:
             return self._reject("fingerprint")
+        if stats is not None:
+            if not bool(stats["finite"]):
+                return self._reject("nonfinite")
+            norm = float(np.sqrt(np.sum(
+                np.asarray(stats["leaf_sumsq"], np.float64))))
+            return self._screen_norm(norm)
         if not _all_finite(wave_mean):
             return self._reject("nonfinite")
         if self._ref_cache[0] is not global_params:
@@ -290,7 +358,10 @@ class WaveAdmission:
             self._ref_cache = (global_params,
                                [np.asarray(leaf, np.float64)
                                 for leaf in _leaves(global_params)])
-        norm = _update_norm(wave_mean, self._ref_cache[1])
+        return self._screen_norm(
+            _update_norm(wave_mean, self._ref_cache[1]))
+
+    def _screen_norm(self, norm: float) -> AdmissionVerdict:
         if self.norm_screen:
             thresh = self.norm_threshold()
             if thresh is not None and norm > thresh:

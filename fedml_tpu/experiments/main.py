@@ -97,7 +97,8 @@ def _make_workload(cfg: ExperimentConfig, data):
                            compute_dtype=cfg.compute_dtype,
                            attn_block_size=cfg.attn_block_size,
                            attn_flash=cfg.attn_flash,
-                           moe_experts=cfg.moe_experts)
+                           moe_experts=cfg.moe_experts,
+                           model_config=cfg.model_config)
 
 
 def _make_checkpointer(cfg: ExperimentConfig):

@@ -23,7 +23,8 @@ from fedml_tpu.trainer.workload import (
     ClassificationWorkload, NWPWorkload, TagPredictionWorkload, Workload)
 
 # next-word/char-prediction datasets -> NWP trainer flavor
-_NWP_DATASETS = {"shakespeare", "fed_shakespeare", "stackoverflow_nwp"}
+_NWP_DATASETS = {"shakespeare", "fed_shakespeare", "stackoverflow_nwp",
+                 "token_shards"}
 
 
 def create_workload(model_name: str, dataset: str, class_num: int,
@@ -31,14 +32,19 @@ def create_workload(model_name: str, dataset: str, class_num: int,
                     compute_dtype: str = "",
                     attn_block_size: int = 0,
                     attn_flash: bool = False,
-                    moe_experts: int = 0) -> Workload:
+                    moe_experts: int = 0,
+                    model_config: str = "") -> Workload:
     """main_fedavg.py:224-259 switch, flax edition.
 
     ``compute_dtype="bfloat16"`` enables MXU-native mixed precision on the
     classification workloads (f32 master params, bf16 model compute).
     ``attn_block_size`` > 0 gives the transformer flash-style kv blocking
     (O(T*block) attention memory) for long-context train/eval;
-    ``attn_flash`` swaps in the TPU pallas flash kernel instead."""
+    ``attn_flash`` swaps in the TPU pallas flash kernel instead.
+    ``model_config`` names a JSON file of a published architecture's keys
+    (`models.transformer.LatentMoEArch`): the transformer is then built
+    from them, every width the file's, over a next-token dataset whose
+    vocabulary is the file's ``vocab_held``."""
     import jax.numpy as jnp
     dtype = jnp.dtype(compute_dtype) if compute_dtype else None
     if (attn_block_size or attn_flash or moe_experts) \
@@ -48,6 +54,26 @@ def create_workload(model_name: str, dataset: str, class_num: int,
     if attn_block_size and attn_flash:
         raise ValueError("--attn_block_size and --attn_flash are mutually "
                          "exclusive attention backends; pick one")
+    arch = None
+    if model_config:
+        import json
+        from fedml_tpu.models.transformer import LatentMoEArch
+        if model_name != "transformer" or dataset not in _NWP_DATASETS:
+            raise ValueError("--model_config describes --model transformer "
+                             "over a next-token dataset "
+                             f"({sorted(_NWP_DATASETS)})")
+        if attn_flash or moe_experts:
+            raise ValueError("--attn_flash/--moe_experts are the "
+                             "learned-position transformer's; a "
+                             "--model_config states its own attention and "
+                             "experts")
+        with open(model_config) as f:
+            arch = LatentMoEArch.from_dict(json.load(f))
+        if arch.vocab_held != class_num:
+            raise ValueError(
+                f"--model_config holds {arch.vocab_held} rows of the "
+                f"vocabulary and --dataset {dataset} draws its ids from "
+                f"{class_num}")
     if attn_flash:
         # refuse HERE, at config time, what the kernel would otherwise
         # refuse at trace time inside the first training jit
@@ -76,7 +102,7 @@ def create_workload(model_name: str, dataset: str, class_num: int,
             model = TransformerLM(vocab_size=class_num, dtype=dtype,
                                   block_size=attn_block_size or None,
                                   use_flash=attn_flash,
-                                  moe_experts=moe_experts)
+                                  moe_experts=moe_experts, arch=arch)
         elif dataset == "stackoverflow_nwp":
             model = RNNStackOverflow(dtype=dtype)          # rnn.py:39-70
         else:

@@ -128,3 +128,221 @@ class SwitchFFN(nn.Module):
         comb = (disp * gate[..., None, None]).astype(dt)
         yt = jnp.einsum("gnec,gecd->gnd", comb, ye)
         return yt.reshape(b, t, d).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# top-k sigmoid routing with a selection bias, no dropped token, a shared
+# expert, and a layer that holds a share of the experts
+
+def route_sigmoid_topk(logits, select_bias, k: int, scale: float,
+                       normalize: bool = True):
+    """``(chosen [..., k] int32, weights [..., k] f32)`` of the
+    auxiliary-loss-free router (``topk_method: noaux_tc`` with one group):
+    scores are ``sigmoid(logits)`` over ALL experts, the ``k`` chosen are
+    the top ``k`` of ``score + select_bias``, and the weights are the
+    chosen experts' own scores (the bias only selects), normalised over
+    the ``k`` chosen and scaled.  The choice is an index, so no gradient
+    reaches ``select_bias``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, chosen = jax.lax.top_k(scores + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), w * scale
+
+
+def plan_held_tiles(chosen, first_held: int, held: int, tile: int):
+    """Where each (token, choice) goes in a layout sorted by held expert.
+
+    ``chosen`` [N, k] are expert ids over all experts; the experts
+    ``first_held .. first_held + held - 1`` live here.  The layout has one
+    run of rows an expert, each padded to a whole number of ``tile`` rows,
+    so a tile belongs to one expert; its length ``L`` is the worst case
+    (every choice of every token held) and only the first ``n_active``
+    tiles hold a row.  Returns ``dest`` [N, k] (row of each choice, ``L``
+    for a choice whose expert is not held), ``row_token`` [L] (the token
+    in each row, ``N`` for a padding row), ``tile_expert`` [L / tile],
+    ``n_active`` and ``counts`` [held] (tokens each held expert takes)."""
+    n, k = chosen.shape
+    cap = n * min(k, held)
+    length = -(-cap // tile) * tile + held * tile
+    local = chosen.reshape(-1) - first_held
+    is_held = (local >= 0) & (local < held)
+    onehot = ((local[:, None] == jnp.arange(held)[None, :])
+              & is_held[:, None]).astype(jnp.int32)            # [A, held]
+    running = jnp.cumsum(onehot, axis=0)
+    counts = running[-1]
+    rank = jnp.sum(running * onehot, axis=-1) - 1
+    padded = -(-counts // tile) * tile
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    dest = jnp.where(is_held,
+                     starts[jnp.clip(local, 0, held - 1)] + rank, length)
+    token = jnp.arange(n * k, dtype=jnp.int32) // k
+    row_token = jnp.full((length + 1,), n, jnp.int32).at[dest].set(
+        token)[:length]
+    tile_expert = jnp.clip(jnp.searchsorted(
+        ends, jnp.arange(length // tile) * tile, side="right"),
+        0, held - 1).astype(jnp.int32)
+    return (dest.reshape(n, k), row_token, tile_expert,
+            (ends[-1] // tile).astype(jnp.int32), counts)
+
+
+def _silu_gate(xt, wg, wu):
+    g = xt @ wg
+    u = xt @ wu
+    return g, u, jax.nn.silu(g) * u
+
+
+@jax.custom_vjp
+def grouped_gated_mlp(xp, wg, wu, wd, row_token, tile_expert, n_active):
+    """The grouped product over the experts held: ``rows[j] =
+    W_down[e](silu(W_gate[e] x) * W_up[e] x)`` for every tile ``j`` of
+    ``plan_held_tiles``' layout, ``e`` the tile's expert, ``x`` the rows
+    of ``xp`` [N + 1, d] (its last row zeros, what a padding row reads)
+    the tile names.  Only the ``n_active`` tiles that hold a row are
+    computed: a loop with a data-dependent trip count, which is why the
+    gradient is written out below and not traced.  Returns [L + 1, d],
+    the last row zeros (what a choice that is not held reads)."""
+    return _grouped_fwd_rows(xp, wg, wu, wd, row_token, tile_expert,
+                             n_active)
+
+
+def _grouped_fwd_rows(xp, wg, wu, wd, row_token, tile_expert, n_active):
+    tile = row_token.shape[0] // tile_expert.shape[0]
+    d = wd.shape[-1]
+
+    def body(j, rows):
+        tok = jax.lax.dynamic_slice(row_token, (j * tile,), (tile,))
+        e = tile_expert[j]
+        _, _, h = _silu_gate(xp[tok], wg[e], wu[e])
+        return jax.lax.dynamic_update_slice(rows, (h @ wd[e]).astype(
+            rows.dtype), (j * tile, 0))
+
+    rows = jnp.zeros((row_token.shape[0] + 1, d), xp.dtype)
+    return jax.lax.fori_loop(0, n_active, body, rows)
+
+
+def _grouped_fwd(xp, wg, wu, wd, row_token, tile_expert, n_active):
+    rows = _grouped_fwd_rows(xp, wg, wu, wd, row_token, tile_expert,
+                             n_active)
+    return rows, (xp, wg, wu, wd, row_token, tile_expert, n_active)
+
+
+def _grouped_bwd(res, d_rows):
+    xp, wg, wu, wd, row_token, tile_expert, n_active = res
+    tile = row_token.shape[0] // tile_expert.shape[0]
+
+    def body(j, carry):
+        dxp, dwg, dwu, dwd = carry
+        tok = jax.lax.dynamic_slice(row_token, (j * tile,), (tile,))
+        e = tile_expert[j]
+        xt = xp[tok]
+        dy = jax.lax.dynamic_slice(d_rows, (j * tile, 0),
+                                   (tile, d_rows.shape[1]))
+        g, u, h = _silu_gate(xt, wg[e], wu[e])
+        dh = dy @ wd[e].T
+        sig = jax.nn.sigmoid(g)
+        dg = dh * u * (sig * (1.0 + g * (1.0 - sig)))
+        du = dh * g * sig
+        dwd = dwd.at[e].add((h.T @ dy).astype(dwd.dtype))
+        dwg = dwg.at[e].add((xt.T @ dg).astype(dwg.dtype))
+        dwu = dwu.at[e].add((xt.T @ du).astype(dwu.dtype))
+        dxt = dg @ wg[e].T + du @ wu[e].T
+        return dxp.at[tok].add(dxt.astype(dxp.dtype)), dwg, dwu, dwd
+
+    dxp, dwg, dwu, dwd = jax.lax.fori_loop(
+        0, n_active, body, tuple(jnp.zeros_like(a)
+                                 for a in (xp, wg, wu, wd)))
+    # the padding row of xp is a constant zero: nothing flows into it
+    return dxp.at[-1].set(0), dwg, dwu, dwd, None, None, None
+
+
+grouped_gated_mlp.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+class GatedMLP(nn.Module):
+    """``W_down(silu(W_gate x) * W_up x)``, no biases."""
+    d_ff: int
+    init_std: float = 0.02
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(n, name):
+            return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name,
+                            kernel_init=nn.initializers.normal(
+                                self.init_std))
+        h = nn.silu(dense(self.d_ff, "gate")(x)) * dense(self.d_ff, "up")(x)
+        return dense(x.shape[-1], "down")(h)
+
+
+class SharedExpertMoE(nn.Module):
+    """The expert layer of the DeepSeek-V3 family as one chip of an
+    expert-parallel deployment holds it: ``experts_total`` routed experts
+    of which ``experts_held`` (ids ``first_held ..``) live here, ``top_k``
+    a token by `route_sigmoid_topk`, and ``n_shared`` shared experts every
+    token passes.  The router has its published width and the weights are
+    normalised over all ``top_k`` chosen, held or not; the layer returns
+    its own experts' part of the result plus the shared expert's, and
+    what the absent experts would add is left out (their chips', in a
+    deployment).  No capacity and no dropped token: the chosen (token,
+    expert) pairs are gathered by expert into `grouped_gated_mlp`.
+
+    Sows ``moe_stats/counts``, float32 ``[tokens, assignments, held
+    assignments, largest held expert's tokens, mean held expert's
+    tokens]`` of this call (read only by a caller that asks for the
+    collection)."""
+    experts_total: int
+    experts_held: int
+    first_held: int
+    top_k: int
+    d_ff: int
+    n_shared: int = 1
+    scale: float = 1.0
+    normalize: bool = True
+    init_std: float = 0.02
+    tile: int = 512
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, d = x.shape
+        n, held = b * t, self.experts_held
+        if not 0 <= self.first_held <= self.experts_total - held:
+            raise ValueError(
+                f"experts {self.first_held}..{self.first_held + held - 1} "
+                f"are not among the {self.experts_total} routed")
+        init = nn.initializers.normal(self.init_std)
+        xt = x.reshape(n, d)
+        router = self.param("router", init, (d, self.experts_total),
+                            jnp.float32)
+        bias = self.param("select_bias", init, (self.experts_total,),
+                          jnp.float32)
+        logits = jnp.dot(xt.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        chosen, w = route_sigmoid_topk(logits, bias, self.top_k, self.scale,
+                                       self.normalize)
+        wg = self.param("experts_gate", init, (held, d, self.d_ff),
+                        jnp.float32)
+        wu = self.param("experts_up", init, (held, d, self.d_ff),
+                        jnp.float32)
+        wd = self.param("experts_down", init, (held, self.d_ff, d),
+                        jnp.float32)
+        dt = self.dtype or x.dtype
+        tile = min(self.tile, max(8, -(-n // 8) * 8))
+        dest, row_token, tile_expert, n_active, counts = plan_held_tiles(
+            chosen, self.first_held, held, tile)
+        xp = jnp.concatenate([xt.astype(dt), jnp.zeros((1, d), dt)])
+        rows = grouped_gated_mlp(xp, wg.astype(dt), wu.astype(dt),
+                                 wd.astype(dt), row_token, tile_expert,
+                                 n_active)
+        y = jnp.sum(rows[dest] * w[..., None].astype(dt), axis=1)
+        if self.n_shared:
+            y = y + GatedMLP(self.n_shared * self.d_ff, self.init_std,
+                             self.dtype, name="shared")(xt)
+        load = counts.astype(jnp.float32)
+        self.sow("moe_stats", "counts", jnp.stack([
+            jnp.float32(n), jnp.float32(n * self.top_k), jnp.sum(load),
+            jnp.max(load), jnp.mean(load)]))
+        return y.reshape(b, t, d).astype(x.dtype)

@@ -23,10 +23,23 @@ collections), so the serving scheduler jits one step over a fixed
 every slot may sit at a DIFFERENT sequence index, which is exactly what
 continuous batching needs (a finished slot restarts at position 0 and the
 ``kv_idx <= position`` mask hides the previous occupant's stale rows).
+
+With ``arch`` (a `LatentMoEArch`, the published configuration keys of the
+DeepSeek-V3 / GLM-4.7 family) the same model is built from other parts:
+RMSNorm before each half of a block and before the head, rotary positions,
+gated SiLU MLPs without biases, latent attention (`LatentAttention`: queries
+and keys/values through low-rank latents, one rotary key shared by all
+heads), ``first_k_dense_replace`` dense blocks and then expert blocks
+(`models.moe.SharedExpertMoE`: the share of the routed experts this chip
+holds, and the shared expert), an untied head, one `jax.checkpoint` a block,
+and ``num_nextn_predict_layers`` multi-token prediction modules whose loss
+term is sown into ``losses``.  Training only: the decode cache and the ring
+are the learned-position model's.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional
 
@@ -162,6 +175,185 @@ def init_decode_cache(model: "TransformerLM", slots: int, cache_len: int,
             for i in range(model.n_layers)}
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentMoEArch:
+    """The architecture's keys under their published names (a model's
+    ``config.json``), plus the share of a layer this chip holds:
+    ``experts_held`` routed experts from ``first_held`` on and the first
+    ``vocab_held`` rows of the vocabulary.  ``initializer_range`` and
+    ``mtp_loss_weight`` are not in the published file; their defaults are
+    the family's.  ``embedding_range`` is the scale the token embedding
+    starts from: 1 and not ``initializer_range``, because RMSNorm puts
+    every block's input at scale 1 and its output near it, so rows of
+    scale 0.02 are drowned by the first attention's mean value vector,
+    every position looks alike to an untrained router, and all tokens
+    take the same ``num_experts_per_tok`` experts (measured at the
+    published widths: 41 of 2,048 choices held where 256 are expected,
+    233 at scale 1; PERF.md section 6, PR 37)."""
+    hidden_size: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    n_routed_experts: int
+    n_shared_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    rms_norm_eps: float
+    rope_theta: float
+    vocab_size: int
+    num_nextn_predict_layers: int
+    experts_held: int
+    first_held: int
+    vocab_held: int
+    initializer_range: float = 0.02
+    mtp_loss_weight: float = 0.3
+    embedding_range: float = 1.0
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "LatentMoEArch":
+        """From a configuration file's keys; a key this model has no use
+        for (``model_type``, ``max_position_embeddings``) is passed over, a
+        missing one is an error that names it."""
+        names = {f.name: f for f in dataclasses.fields(cls)}
+        missing = [n for n, f in names.items() if n not in d
+                   and f.default is dataclasses.MISSING]
+        if missing:
+            raise KeyError(f"model configuration lacks {missing}")
+        return cls(**{n: d[n] for n in names if n in d})
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(self.dtype or x.dtype)
+
+
+def rotary(x, positions, theta: float):
+    """Rotary position embedding over the last axis of ``x`` [B, T, H, r],
+    pairing element ``i`` with ``i + r/2`` (the rotate-half convention)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def causal_blocked_attention(q, k, v, block: Optional[int] = None):
+    """Causal softmax attention, ``q``/``k`` [B, T, H, dk] and ``v``
+    [B, T, H, dv] at positions 0..T-1, one block of ``block`` queries at a
+    time against the keys up to its last position: the scores held at
+    once are [B, H, block, <= T] and the blocks wholly above the diagonal
+    are never computed.  Each block is a `jax.checkpoint`, so the
+    backward pass computes its scores again and keeps none.  ``block``
+    None is one block."""
+    t = q.shape[1]
+    block = t if block is None else min(block, t)
+    scale = 1.0 / math.sqrt(q.shape[-1])
+
+    @jax.checkpoint
+    def one(qb, kb, vb, q_pos):
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        seen = jnp.arange(kb.shape[1])[None, :] <= q_pos[:, None]
+        p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb,
+                          preferred_element_type=jnp.float32)
+
+    out = [one(q[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block],
+               jnp.arange(lo, min(lo + block, t)))
+           for lo in range(0, t, block)]
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
+    2.1): ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` (heads x (nope +
+    rope)); ``[c_kv, k_r] = x W_kva``, ``c_kv = RMSNorm(c_kv)``,
+    ``[k_nope, v] = c_kv W_kvb``; rotary on ``q``'s rope part and on
+    ``k_r``, which every head shares; causal softmax of ``q k^T /
+    sqrt(nope + rope)``; heads x ``v_head_dim`` through ``W_o``.  Training
+    decompresses keys and values and runs `causal_blocked_attention`."""
+    arch: LatentMoEArch
+    dtype: object = None
+    block_size: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        a = self.arch
+        b, t, _ = x.shape
+        h, nope, rope = (a.num_attention_heads, a.qk_nope_head_dim,
+                         a.qk_rope_head_dim)
+
+        def dense(n, name):
+            return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name,
+                            kernel_init=nn.initializers.normal(
+                                a.initializer_range))
+        c_q = RMSNorm(a.rms_norm_eps, self.dtype, name="q_norm")(
+            dense(a.q_lora_rank, "q_a")(x))
+        q = dense(h * (nope + rope), "q_b")(c_q).reshape(b, t, h, nope + rope)
+        ckv = dense(a.kv_lora_rank + rope, "kv_a")(x)
+        c_kv = RMSNorm(a.rms_norm_eps, self.dtype, name="kv_norm")(
+            ckv[..., :a.kv_lora_rank])
+        k_r = rotary(ckv[..., None, a.kv_lora_rank:], positions, a.rope_theta)
+        kv = dense(h * (nope + a.v_head_dim), "kv_b")(c_kv).reshape(
+            b, t, h, nope + a.v_head_dim)
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], positions, a.rope_theta)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_r, (b, t, h, rope))], axis=-1)
+        out = causal_blocked_attention(q, k, kv[..., nope:], self.block_size)
+        return dense(a.hidden_size, "o")(
+            out.astype(x.dtype).reshape(b, t, h * a.v_head_dim))
+
+
+class LatentMoEBlock(nn.Module):
+    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the FFN a
+    gated MLP of the dense width, or the expert layer."""
+    arch: LatentMoEArch
+    experts: bool
+    dtype: object = None
+    block_size: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from fedml_tpu.models.moe import GatedMLP, SharedExpertMoE
+        a = self.arch
+        h = x + LatentAttention(a, self.dtype, self.block_size, name="attn")(
+            RMSNorm(a.rms_norm_eps, self.dtype, name="attn_norm")(x),
+            positions)
+        f = RMSNorm(a.rms_norm_eps, self.dtype, name="ffn_norm")(h)
+        if self.experts:
+            f = SharedExpertMoE(
+                a.n_routed_experts, a.experts_held, a.first_held,
+                a.num_experts_per_tok, a.moe_intermediate_size,
+                n_shared=a.n_shared_experts, scale=a.routed_scaling_factor,
+                normalize=a.norm_topk_prob, init_std=a.initializer_range,
+                dtype=self.dtype, name="moe")(f)
+        else:
+            f = GatedMLP(a.intermediate_size, a.initializer_range,
+                         self.dtype, name="mlp")(f)
+        return h + f
+
+
 class TransformerLM(nn.Module):
     """Per-position next-token logits, causal.
 
@@ -193,12 +385,22 @@ class TransformerLM(nn.Module):
     moe_aux_weight: float = 0.01      # Switch paper's alpha
     pad_id: int = 0       # pad token id; MoE routing excludes pad positions
     #                       (they would otherwise eat expert capacity)
+    arch: Optional[LatentMoEArch] = None  # the published keys of a
+    #                       latent-attention expert model: every width then
+    #                       comes from it and the fields above that state
+    #                       one are not read
 
     @nn.compact
     def __call__(self, input_seq, train: bool = False, positions=None,
                  ring_axis: Optional[str] = None,
                  cache: Optional[dict] = None):
         decode = cache is not None
+        if self.arch is not None:
+            if decode or ring_axis is not None:
+                raise NotImplementedError(
+                    "a latent-attention model trains only: its decode "
+                    "cache (the latent one) and the ring are not built")
+            return self._latent_moe(input_seq, positions)
         if decode:
             if positions is None:
                 raise ValueError(
@@ -260,3 +462,50 @@ class TransformerLM(nn.Module):
         logits = nn.Dense(self.vocab_size, dtype=self.dtype,
                           name="lm_head")(x)
         return (logits[:, 0, :], new_cache) if decode else logits
+
+    def _latent_moe(self, tokens, positions):
+        """The forward pass under ``arch`` (called inside `__call__`)."""
+        a = self.arch
+        t = tokens.shape[1]
+        if positions is None:
+            positions = jnp.arange(t)
+        init = nn.initializers.normal(a.initializer_range)
+        embed = nn.Embed(a.vocab_held, a.hidden_size, dtype=self.dtype,
+                         embedding_init=nn.initializers.normal(
+                             a.embedding_range), name="tok_embed")
+        final_norm = RMSNorm(a.rms_norm_eps, self.dtype, name="final_norm")
+        head = nn.Dense(a.vocab_held, use_bias=False, dtype=self.dtype,
+                        kernel_init=init, name="lm_head")
+        block = nn.remat(LatentMoEBlock)
+
+        x = embed(tokens)
+        for i in range(a.num_hidden_layers):
+            x = block(a, i >= a.first_k_dense_replace, self.dtype,
+                      self.block_size, name=f"layer_{i}")(x, positions)
+        logits = head(final_norm(x))
+        if a.num_nextn_predict_layers > 1:
+            raise NotImplementedError("one multi-token prediction module")
+        if a.num_nextn_predict_layers and t >= 3:
+            # DeepSeek-V3 (arXiv:2412.19437, section 2.2): position i's
+            # hidden state and the embedding of token i+1 go through one
+            # more block, the shared norm and head, to predict token i+2;
+            # the mean over real targets, weighted, is sown
+            merged = jnp.concatenate(
+                [RMSNorm(a.rms_norm_eps, self.dtype,
+                         name="mtp_hnorm")(x[:, :-1]),
+                 RMSNorm(a.rms_norm_eps, self.dtype,
+                         name="mtp_enorm")(embed(tokens[:, 1:]))], axis=-1)
+            y = block(a, True, self.dtype, self.block_size,
+                      name="mtp_block")(
+                nn.Dense(a.hidden_size, use_bias=False, dtype=self.dtype,
+                         kernel_init=init, name="mtp_proj")(merged),
+                positions[:-1])
+            mtp_logits = head(final_norm(y))[:, :-1].astype(jnp.float32)
+            target = tokens[:, 2:]
+            real = (target != self.pad_id).astype(jnp.float32)
+            nll = -jnp.take_along_axis(
+                jax.nn.log_softmax(mtp_logits, axis=-1), target[..., None],
+                axis=-1)[..., 0]
+            self.sow("losses", "mtp", a.mtp_loss_weight
+                     * jnp.sum(nll * real) / jnp.maximum(jnp.sum(real), 1.0))
+        return logits

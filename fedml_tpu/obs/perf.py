@@ -46,6 +46,7 @@ import os
 import re
 import threading
 import time
+from concurrent.futures import Future
 from typing import Callable, Dict, Optional
 
 from fedml_tpu.obs import critical_path as _cpath
@@ -367,6 +368,8 @@ class PerfRecorder:
         self._c_rounds = reg.counter("fedml_perf_rounds_total")
         self._h_phase: Dict[str, object] = {}
         self._closed = False
+        self._late: Optional[Future] = None  # the last line still waiting
+        #                                      for a value (`round_end`)
         self._ledger_disabled = False
         # round critical-path observatory (obs/critical_path.py): armed
         # per round in round_start, reduced into the line's
@@ -463,9 +466,16 @@ class PerfRecorder:
         """Close the round: sentry check, RSS watermark, wire deltas,
         one ledger line.  Returns the line dict (None when no round was
         open).  ``extra`` lands verbatim in the line (quorum size,
-        version tags, ...)."""
+        version tags, ...); a value that is a `Future` (the global's CRC,
+        which a worker takes beside the next round) lands when it is
+        there: the round is closed and timed now, and the line is
+        written, by the future's own thread, once every such value has
+        come (lines stay in round order where one worker makes them in
+        order; `close` waits for the last)."""
         if self._round is None:
             return None
+        late = {k: extra.pop(k) for k in list(extra)
+                if isinstance(extra[k], Future)}
         # the sentry runs FIRST so a strict-mode RecompileError fires
         # before a misleading clean line could be written
         recompile_events = self.sentry.check(round_idx)
@@ -510,7 +520,19 @@ class PerfRecorder:
             record = cp.finalize(duration=round_s, compile_s=compile_s)
             line["critical_path"] = record
             self._ingest.export(record, line["wire"]["bytes_in"])
-        self._write(line)
+        if late:
+            left = [len(late)]
+
+            def land(_done, line=line):
+                left[0] -= 1
+                if not left[0]:
+                    line.update({k: f.result() for k, f in late.items()})
+                    self._write(line)
+            for f in late.values():
+                f.add_done_callback(land)
+            self._late = f
+        else:
+            self._write(line)
         self._c_rounds.inc()
         if rss_peak is not None:
             self._g_rss.set(rss_peak)
@@ -547,6 +569,8 @@ class PerfRecorder:
         if self._closed:
             return
         self._closed = True
+        if self._late is not None:
+            self._late.exception()      # waits; the callback has written
         self.rss.stop()
         if self._trace_path is not None and self.tracer.spans:
             try:

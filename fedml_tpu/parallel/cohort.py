@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from fedml_tpu.core.pytree import tree_weighted_mean
@@ -38,29 +39,103 @@ CohortData = Dict[str, jax.Array]  # leaves [C, S, B, ...]; "num_samples" [C]
 CohortStep = Callable[..., Tuple[Pytree, Dict[str, jax.Array]]]
 
 
-def choose_client_axis(params: Pytree) -> str:
+def device_memory_bytes() -> Optional[int]:
+    """What one local device's memory holds (``bytes_limit`` of its
+    ``memory_stats``), or None on a backend that keeps no such count (the
+    CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats["bytes_limit"]) if stats.get("bytes_limit") else None
+
+
+def choose_client_axis(params: Pytree, wave_size: int = 1,
+                       device_bytes: Optional[int] = None) -> str:
     """How `train_cohort` runs the client axis when no engine is named: a
-    pure function of the trained tree's leaf shapes.
+    pure function of the trained tree's leaf shapes, the number of
+    clients trained together and the device's memory.
 
     ``"scan"`` when the tree holds a convolution kernel (a rank-4 leaf,
     flax's ``[kh, kw, in, out]``; dense, LSTM, attention and MoE leaves
-    are rank 1-3), ``"vmap"`` otherwise.  Under ``vmap`` kernels that
-    differ by client turn every convolution into a grouped convolution
-    and every activation carries the clients next to a 16-64 wide
-    channel axis; matmuls become batched matmuls, which the MXU takes as
-    they are, and a B=4 LSTM run client after client would be slower.
+    are rank 1-3), or when the clients' copies cannot be held side by
+    side; ``"vmap"`` otherwise.  Under ``vmap`` kernels that differ by
+    client turn every convolution into a grouped convolution and every
+    activation carries the clients next to a 16-64 wide channel axis;
+    matmuls become batched matmuls, which the MXU takes as they are, and
+    a B=4 LSTM run client after client would be slower.
 
-    The rule is the one read on a TPU v5e through the CLI and no wider
-    (PERF.md section 6, PR 26 and PR 28), seconds a round ``vmap`` /
-    ``scan``: ResNet-56, 10 silos x B=64, 2.42 / 1.50 at 10,000 rows
+    The size rule: ``vmap`` holds the global and, for each of
+    ``wave_size`` clients at once, a working copy, its gradient and its
+    result, ``(1 + 3 * wave_size)`` trees; where that is more than half
+    of ``device_bytes`` (the other half is the fold's accumulator, the
+    wave's sum and the activations) the clients train in sequence, one
+    tree of each kind at a time.  A 2.37 GB tree (591 M float32
+    parameters) in waves of two on a 16 GB chip reads 16.6 GB against
+    7.9 GB: ``scan`` (PERF.md section 6, PR 37).  ``device_bytes`` None
+    (a backend that keeps no count) leaves the shape rule alone.
+
+    The conv rule is the one read on a TPU v5e through the CLI and no
+    wider (PERF.md section 6, PR 26 and PR 28), seconds a round ``vmap``
+    / ``scan``: ResNet-56, 10 silos x B=64, 2.42 / 1.50 at 10,000 rows
     and 10.1 / 5.9 at 50,000; FEMNIST CNN, cohort 512 in waves of 256 x
     B=20, 2.32-2.37 / 1.56-1.66.  The sequential program is also the
     one that agrees with the plain reference (ResNet-56 ``change1_diff``
     2e-5 against 1e-4 - 5e-3; the vmapped CNN's conv-kernel update reads
     9-11 % small).  No convolutional shape has been read where ``vmap``
     wins; one that is goes here, with its reading."""
-    has_conv = any(jnp.ndim(x) == 4 for x in jax.tree.leaves(params))
-    return "scan" if has_conv else "vmap"
+    if any(jnp.ndim(x) == 4 for x in jax.tree.leaves(params)):
+        return "scan"
+    return "scan" if wave_outgrows_device(params, wave_size,
+                                          device_bytes) else "vmap"
+
+
+def wave_outgrows_device(params: Pytree, wave_size: int,
+                         device_bytes: Optional[int]) -> bool:
+    """`choose_client_axis`'s size rule: ``(1 + 3 * wave_size)`` trees are
+    more than half of ``device_bytes`` (False where that is None)."""
+    if device_bytes is None:
+        return False
+    tree_bytes = sum(int(np.prod(jnp.shape(x))) * jnp.dtype(x.dtype).itemsize
+                     for x in jax.tree.leaves(params))
+    return (1 + 3 * wave_size) * tree_bytes > device_bytes // 2
+
+
+def _client_keys(rng, n_clients: int, index_offset):
+    idx = jnp.arange(n_clients) + index_offset
+    return idx, jax.vmap(lambda i: jax.random.fold_in(rng, i))(idx)
+
+
+def train_cohort_sum(local_train, params: Pytree, data: CohortData,
+                     rng: jax.Array, index_offset=0):
+    """`train_cohort` with the clients in sequence, for a caller that reads
+    nothing of one client's result but its weighted sum: the `lax.scan`
+    over the clients carries ``sum_i w_i * result_i`` (``w`` the clients'
+    ``num_samples``, leaves in the fold's accumulator dtype) and the
+    running weight, and no ``[clients, ...]`` tree is ever made.  The
+    sum is the slot-order sequential one `StreamingAggregator.fold_wave`
+    makes of the stacked results, bit for bit.  Returns ``(sum, weight
+    total, metrics)``, ``metrics`` stacked by client as `train_cohort`'s."""
+    from fedml_tpu.core.stream_agg import zeros_acc_like
+    n_clients = data["num_samples"].shape[0]
+    _, rngs = _client_keys(rng, n_clients, index_offset)
+    w = data["num_samples"].astype(jnp.float32)
+    client_batches = {k: v for k, v in data.items() if k != "num_samples"}
+
+    def _one(carry, xs):
+        acc, wsum = carry
+        batches, r, weight = xs
+        new_params, metrics = local_train(params, batches, r)
+        # the client's result as the stacked path holds it, a value of
+        # its own: without the barrier XLA folds the optimizer's last
+        # update into the sum and contracts the two roundings otherwise
+        new_params = jax.lax.optimization_barrier(new_params)
+        acc = jax.tree.map(
+            lambda a, u: a + u.astype(a.dtype) * weight.astype(a.dtype),
+            acc, new_params)
+        return (acc, wsum + weight), metrics
+
+    (acc, wsum), metrics = jax.lax.scan(
+        _one, (zeros_acc_like(params), jnp.float32(0.0)),
+        (client_batches, rngs, w))
+    return acc, wsum, metrics
 
 
 def train_cohort(local_train, params: Pytree, data: CohortData,
@@ -94,8 +169,7 @@ def train_cohort(local_train, params: Pytree, data: CohortData,
         raise ValueError(f"client_axis must be 'vmap' or 'scan', "
                          f"got {client_axis!r}")
     n_clients = data["num_samples"].shape[0]
-    idx = jnp.arange(n_clients) + index_offset
-    rngs = jax.vmap(lambda i: jax.random.fold_in(rng, i))(idx)
+    idx, rngs = _client_keys(rng, n_clients, index_offset)
     client_batches = {k: v for k, v in data.items() if k != "num_samples"}
     if client_axis == "scan":
         def _one(_, xs):

@@ -75,8 +75,10 @@ def params_fingerprint(tree) -> object:
                 for k, v in tree.items()}
     if isinstance(tree, (list, tuple)):
         return [params_fingerprint(v) for v in tree]
-    arr = np.asarray(tree)
-    return (tuple(arr.shape), np.dtype(arr.dtype).str)
+    if not (hasattr(tree, "shape") and hasattr(tree, "dtype")):
+        tree = np.asarray(tree)
+    # a device array shows both without being brought to the host
+    return (tuple(tree.shape), np.dtype(tree.dtype).str)
 
 
 def _leaves(tree) -> List[np.ndarray]:

@@ -136,12 +136,17 @@ def make_local_trainer(workload: Workload,
     masked batch in the third: the masked mean's 0 plus any term beside
     it (a mixture's balance term).  The wave engine drops the metric.
 
+    A workload whose loss counts things (``workload.counter_shapes``, name
+    -> shape; the loss's aux carries them under ``"counters"``) has them
+    summed over the steps that ran into ``metrics["counters"]``.
+
     ``scan_unroll`` is forwarded to the step `lax.scan` — the default 1
     keeps the compiled program small; the full trip count lets XLA cost
     analysis (which counts a scan body once) see every step."""
     clip = (optax.clip_by_global_norm(workload.grad_clip_norm)
             if workload.grad_clip_norm is not None else None)
     stateful = workload.stateful
+    counter_shapes = dict(workload.counter_shapes or {})
 
     # Gradients are taken over the TRAINED collection only.  For stateful
     # workloads the non-trained collections (BatchNorm running stats) ride
@@ -194,7 +199,9 @@ def make_local_trainer(workload: Workload,
                                                           trained)
                 new_trained = optax.apply_updates(trained, updates)
                 new_state = aux["state"] if stateful else state
-                return new_trained, new_state, new_opt_state, loss
+                counted = {k: aux["counters"][k].astype(jnp.float32)
+                           for k in counter_shapes}
+                return new_trained, new_state, new_opt_state, (loss, counted)
 
             if grad_reduce is not None:
                 # a collective must be entered by every shard, whatever its
@@ -210,18 +217,24 @@ def make_local_trainer(workload: Workload,
             def keep_carry(trained, state, opt_state):
                 # the zero is made from the mask's sum, not from a literal:
                 # under shard_map both branches must vary over the same axes
-                return trained, state, opt_state, rows * 0
+                return trained, state, opt_state, (rows * 0, {
+                    k: jnp.zeros(shape, jnp.float32) + rows * 0
+                    for k, shape in counter_shapes.items()})
 
             trained, state, opt_state, loss = jax.lax.cond(
                 got_data, do_step, keep_carry, trained, state, opt_state)
             return (trained, state, opt_state, rng), loss
 
         total_steps = epochs * num_steps
-        (trained, state, _, _), losses = jax.lax.scan(
+        (trained, state, _, _), (losses, counted) = jax.lax.scan(
             step, (trained, state, opt_state, rng), jnp.arange(total_steps),
             unroll=scan_unroll)
         out = {"params": trained, **state} if stateful else trained
-        return out, {"train_loss_per_step": losses}
+        metrics = {"train_loss_per_step": losses}
+        if counter_shapes:
+            metrics["counters"] = jax.tree.map(lambda c: jnp.sum(c, axis=0),
+                                               counted)
+        return out, metrics
 
     return train
 

@@ -70,9 +70,18 @@ class Workload:
     metric_fn: Callable[[Pytree, Batch], Dict[str, jax.Array]]
     grad_clip_norm: Optional[float] = None
     stateful: bool = False  # params = full variables dict incl. batch_stats
+    # name -> shape of what the loss counts beside its value: float32
+    # arrays under ``aux["counters"]``, summed over a local run's steps by
+    # the local trainer (an expert layer's token counts)
+    counter_shapes: Optional[Dict[str, tuple]] = None
 
     def init(self, rng: jax.Array, sample_batch: Batch) -> Pytree:
-        variables = self.model.init(rng, sample_batch["x"])
+        init = self.model.init
+        if getattr(self.model, "arch", None) is not None:
+            # a published-size decoder's first forward pass, op by op,
+            # is hundreds of programs (4 min on the chip, PR 37): one
+            init = jax.jit(init)
+        variables = init(rng, sample_batch["x"])
         if self.stateful:
             return dict(variables)
         return variables["params"]
@@ -191,15 +200,18 @@ def make_nwp_loss_metrics(forward, pad_id: int = 0):
         return tok_valid * batch["mask"][:, None]
 
     def loss_fn(params, batch, rng, train):
-        logits, extra = forward(params, batch["x"], rng, train)
+        logits, extra, *counted = forward(params, batch["x"], rng, train)
         logits = logits.astype(jnp.float32)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, batch["y"])
         m = _position_mask(batch)
         loss = jnp.sum(ce * m) / jnp.maximum(jnp.sum(m), 1.0) + extra
-        return loss, {"loss": loss}
+        aux = {"loss": loss}
+        if counted:
+            aux["counters"] = counted[0]
+        return loss, aux
 
     def metric_fn(params, batch):
-        logits, _ = forward(params, batch["x"], None, False)
+        logits, *_ = forward(params, batch["x"], None, False)
         logits = logits.astype(jnp.float32)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, batch["y"])
         pred = jnp.argmax(logits, axis=-1)
@@ -225,9 +237,24 @@ def NWPWorkload(model, pad_id: int = 0,
     ``dtype=bfloat16`` (RNNOriginalFedAvg/RNNStackOverflow take it;
     create_workload wires both) or the recurrent matmuls stay f32."""
 
+    arch = getattr(model, "arch", None)
+    counts_experts = (arch is not None
+                      and arch.num_hidden_layers > arch.first_k_dense_replace)
+
     def forward(params, x, rng, train):
         if compute_dtype is not None:
             params = cast_floats(params, compute_dtype)
+        if arch is not None and train:
+            # a latent-attention expert model sows its loss terms (the
+            # multi-token prediction module's) already weighted, and each
+            # expert layer its token counts: summed over the layers
+            logits, sown = model.apply({"params": params}, x, train=train,
+                                       mutable=["losses", "moe_stats"])
+            extra = sum(jax.tree.leaves(sown.get("losses", {})), 0.0)
+            if not counts_experts:
+                return logits, extra
+            return logits, extra, {"moe": sum(
+                jax.tree.leaves(sown["moe_stats"]))}
         if getattr(model, "moe_experts", 0) and train:
             # capture the Switch load-balance terms sown per MoE layer
             # (models/moe.py); plain applies elsewhere no-op the sow.
@@ -242,7 +269,8 @@ def NWPWorkload(model, pad_id: int = 0,
 
     loss_fn, metric_fn = make_nwp_loss_metrics(forward, pad_id)
     return Workload(model=model, loss_fn=loss_fn, metric_fn=metric_fn,
-                    grad_clip_norm=grad_clip_norm)
+                    grad_clip_norm=grad_clip_norm,
+                    counter_shapes={"moe": (5,)} if counts_experts else None)
 
 
 def TagPredictionWorkload(model, grad_clip_norm: Optional[float] = None) -> Workload:

@@ -121,8 +121,9 @@ def tree_crc(tree) -> int:
     import jax
     crc = 0
     for leaf in jax.tree.leaves(tree):
-        crc = zlib.crc32(
-            np.ascontiguousarray(np.asarray(leaf)).tobytes(), crc)
+        # the leaf's own bytes, viewed and not copied
+        crc = zlib.crc32(np.ascontiguousarray(np.asarray(leaf))
+                         .reshape(-1).view(np.uint8), crc)
     return crc
 
 
