@@ -18,7 +18,9 @@ parts of the round the first invocation's config gates keep apart:
   kernels  each in-repo Pallas kernel reached through its CLI selector at
            ResNet-56 parameter size, COMPILED by Mosaic (never
            interpreted), then checked against its XLA compose to the
-           tolerance its own test uses
+           tolerance its own test uses; the fused attention core
+           (`models/fused_attention.py`), which has no selector, through
+           `causal_blocked_attention` at a shape it admits
   flash    --attn_flash at the CLI's default shapes is refused at config
            time with the reason; the kernel itself runs at a sequence it
            accepts and matches dense attention
@@ -360,6 +362,36 @@ def check_secagg_mask(tree):
         f"{diff:.2e}; {same:.4f} of a single upload left unmasked")
 
 
+def check_latent_attention(kernels):
+    """`causal_blocked_attention` at a shape the fused core admits: it
+    must hand over to the kernels, Mosaic must compile them, and result
+    and gradients must be the XLA blocks' to the default precision's
+    rounding (both round a product's operands to bfloat16)."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.models import transformer as tr
+    keys = jax.random.split(jax.random.key(0), 4)
+    q, k = (jax.random.normal(x, (1, 1024, 2, 256)) for x in keys[:2])
+    v, w = (jax.random.normal(x, (1, 1024, 2, 128)) for x in keys[2:])
+    assert tr.fused_core_fits(q, k, v)
+
+    def all_of(core):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(core(q, k, v) * w), (0, 1, 2)))(q, k, v)
+    (lf, gf), (lx, gx) = (all_of(tr.causal_blocked_attention),
+                          all_of(lambda q, k, v: tr._xla_blocked_attention(
+                              q, k, v, 512)))
+    kernels.assert_compiled("latent_attention")
+    gaps = [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+            for a, b in zip(gf, gx)]
+    assert all_finite([float(lf), *gaps]) and max(gaps) < 1e-2, gaps
+    assert abs(float(lf) - float(lx)) < 1e-2 * float(
+        jnp.linalg.norm(w)), (lf, lx)
+    say(f"   latent_attention: fused vs XLA blocks at T=1024, widths "
+        f"256/128: |dq|, |dk|, |dv| gaps {gaps[0]:.2e} {gaps[1]:.2e} "
+        f"{gaps[2]:.2e} of the norm")
+
+
 def phase_kernels(tmp, kernels):
     tree = r56_tree()
     # _agg_kernel: clip + in-kernel weak-DP noise + weighted mean
@@ -390,6 +422,8 @@ def phase_kernels(tmp, kernels):
     assert all_finite([s["train_loss"], s["test_loss"]]), s
     kernels.assert_compiled("secagg_mask")
     check_secagg_mask(tree)
+    # the attention core's forward and backward kernels
+    check_latent_attention(kernels)
 
 
 def phase_flash():

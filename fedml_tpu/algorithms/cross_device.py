@@ -611,21 +611,30 @@ class CrossDevice(FedAvg):
         return counts
 
     @staticmethod
-    def _expert_counts(aux_sums) -> dict:
-        """What an expert model's layers counted over the wave's steps
-        (`models.moe.SharedExpertMoE`, summed over layers, steps and
-        clients), for `wave.dispatch`: the ``tokens`` routed, their
+    def _model_counts(aux_sums) -> dict:
+        """What the model's layers counted over the wave's steps (summed
+        over layers, steps and clients), for `wave.dispatch`.  Always:
+        ``attn_calls``, the attention cores the wave program was handed
+        (`models.transformer.LatentAttention`: layers x client-steps that
+        ran), and ``attn_calls_fused``, those of them the fused kernels
+        took (`causal_blocked_attention` says which); both 0 for a model
+        without latent attention.  For an expert model
+        (`models.moe.SharedExpertMoE`) also the ``tokens`` routed, their
         ``expert_assignments`` (tokens x experts a token),
         ``expert_assignments_held`` (those whose expert this chip holds),
         and the sums over layer-steps of the fullest held expert's tokens
         and of the mean held expert's (``expert_load_max`` /
-        ``expert_load_mean``).  Nothing for a model that counts none."""
-        if "moe" not in aux_sums:
-            return {}
-        names = ("tokens", "expert_assignments", "expert_assignments_held",
-                 "expert_load_max", "expert_load_mean")
-        return dict(zip(names, (float(v) for v in
-                                jax.device_get(aux_sums["moe"]))))
+        ``expert_load_mean``)."""
+        read = jax.device_get({k: aux_sums[k] for k in ("attn", "moe")
+                               if k in aux_sums})
+        names = {"attn": ("attn_calls", "attn_calls_fused"),
+                 "moe": ("tokens", "expert_assignments",
+                         "expert_assignments_held", "expert_load_max",
+                         "expert_load_mean")}
+        counts = {"attn_calls": 0.0, "attn_calls_fused": 0.0}
+        for k, values in read.items():
+            counts.update(zip(names[k], (float(v) for v in values)))
+        return counts
 
     # -- staging one wave ahead ----------------------------------------------
     def _stage_next(self, waves, wi, round_idx) -> None:
@@ -704,12 +713,15 @@ class CrossDevice(FedAvg):
         self._crc_go.wait(10.0)
         # explicit parent, as `stage.prefetch`'s: the round it closes
         with self._span("round.crc", parent=round_ctx):
-            host = jax.device_get(params)
-            crc = tree_crc(host)
+            # one batched transfer, every leaf's started before any is
+            # awaited; the CRC reads each leaf as it lands
+            for leaf in jax.tree.leaves(params):
+                leaf.copy_to_host_async()
+            crc = tree_crc(params)
             if self.health is not None or self._wave_attacks:
                 # kept only for a reader: the pair holds the device
-                # tree too
-                self._mirror = (params, host)
+                # tree too (the copies are the arrays' own by now)
+                self._mirror = (params, jax.device_get(params))
         return crc
 
     def _stop_staging(self) -> None:
@@ -823,7 +835,7 @@ class CrossDevice(FedAvg):
                     if wi == len(waves) - 1:
                         wave_devices = placement_of(stacked)["devices"]
                     if self._real_steps is not None:
-                        dispatch_sp.set(**self._expert_counts(aux_sums))
+                        dispatch_sp.set(**self._model_counts(aux_sums))
             self._c_waves.inc()
             if self.degrade is not None:
                 # every live client completed with the wave: feed the

@@ -256,14 +256,42 @@ def rotary(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
+def fused_core_fits(q, k, v) -> bool:
+    """Whether `causal_blocked_attention` hands these to the fused kernels
+    (`models.fused_attention`): on a TPU, and float32 with a sequence that
+    is a whole number of the kernels' blocks, head widths that are
+    multiples of 128 and a head that fits in VMEM."""
+    from fedml_tpu.models import fused_attention
+    return jax.default_backend() == "tpu" and fused_attention.admits(q, k, v)
+
+
 def causal_blocked_attention(q, k, v, block: Optional[int] = None):
     """Causal softmax attention, ``q``/``k`` [B, T, H, dk] and ``v``
-    [B, T, H, dv] at positions 0..T-1, one block of ``block`` queries at a
-    time against the keys up to its last position: the scores held at
-    once are [B, H, block, <= T] and the blocks wholly above the diagonal
-    are never computed.  Each block is a `jax.checkpoint`, so the
-    backward pass computes its scores again and keeps none.  ``block``
-    None is one block."""
+    [B, T, H, dv] at positions 0..T-1.  One algorithm, and the inputs say
+    which implementation of it runs (`fused_core_fits`):
+
+    * on a TPU, for float32 inputs whose ``T`` is a whole number of 512,
+      whose ``dk`` and ``dv`` are multiples of 128 and whose head fits in
+      VMEM (``T * max(dk, dv) <= 8192 * 256``): the fused Pallas kernels
+      of `models.fused_attention`, one a pass, scores and probabilities in
+      VMEM only, the log-sum-exp saved for the backward pass; ``block``
+      plays no part there;
+    * anywhere else (the CPU, another dtype, a ragged or short ``T``, a
+      narrow head): XLA, one block of ``block`` queries at a time against
+      the keys up to its last position: the scores held at once are
+      [B, H, block, <= T] and the blocks wholly above the diagonal are
+      never computed.  Each block is a `jax.checkpoint`, so the backward
+      pass computes its scores again and keeps none.  ``block`` None is
+      one block."""
+    if fused_core_fits(q, k, v):
+        from fedml_tpu.core.pallas_agg import pallas_interpret
+        from fedml_tpu.models import fused_attention
+        return fused_attention.fused_causal_attention(
+            q, k, v, interpret=pallas_interpret(fused_attention.KERNEL))
+    return _xla_blocked_attention(q, k, v, block)
+
+
+def _xla_blocked_attention(q, k, v, block: Optional[int] = None):
     t = q.shape[1]
     block = t if block is None else min(block, t)
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -290,7 +318,8 @@ class LatentAttention(nn.Module):
     ``[k_nope, v] = c_kv W_kvb``; rotary on ``q``'s rope part and on
     ``k_r``, which every head shares; causal softmax of ``q k^T /
     sqrt(nope + rope)``; heads x ``v_head_dim`` through ``W_o``.  Training
-    decompresses keys and values and runs `causal_blocked_attention`."""
+    decompresses keys and values and runs `causal_blocked_attention`.
+    Sows ``attn_stats/calls``, float32 ``[1, fused]``."""
     arch: LatentMoEArch
     dtype: object = None
     block_size: Optional[int] = None
@@ -320,7 +349,12 @@ class LatentAttention(nn.Module):
             axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_r, (b, t, h, rope))], axis=-1)
-        out = causal_blocked_attention(q, k, kv[..., nope:], self.block_size)
+        v = kv[..., nope:]
+        # one attention core handed over, and whether the kernels took it
+        # (`wave.dispatch`'s ``attn_calls`` / ``attn_calls_fused``)
+        self.sow("attn_stats", "calls", jnp.array(
+            [1.0, float(fused_core_fits(q, k, v))], jnp.float32))
+        out = causal_blocked_attention(q, k, v, self.block_size)
         return dense(a.hidden_size, "o")(
             out.astype(x.dtype).reshape(b, t, h * a.v_head_dim))
 
@@ -476,7 +510,14 @@ class TransformerLM(nn.Module):
         final_norm = RMSNorm(a.rms_norm_eps, self.dtype, name="final_norm")
         head = nn.Dense(a.vocab_held, use_bias=False, dtype=self.dtype,
                         kernel_init=init, name="lm_head")
-        block = nn.remat(LatentMoEBlock)
+        # a block is computed again for its backward pass, but for what
+        # the fused attention core names: its result and log-sum-exp are
+        # kept, so its forward kernel runs once a step (the XLA core names
+        # nothing, and nothing of it is kept)
+        from fedml_tpu.models.fused_attention import SAVED
+        block = nn.remat(
+            LatentMoEBlock,
+            policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
 
         x = embed(tokens)
         for i in range(a.num_hidden_layers):

@@ -240,21 +240,26 @@ def NWPWorkload(model, pad_id: int = 0,
     arch = getattr(model, "arch", None)
     counts_experts = (arch is not None
                       and arch.num_hidden_layers > arch.first_k_dense_replace)
+    # what a latent-attention model's layers count, by the collection
+    # each sows into: every attention its core and whether the fused
+    # kernels took it, every expert layer its tokens
+    counted = {} if arch is None else {
+        "attn": ("attn_stats", (2,)),
+        **({"moe": ("moe_stats", (5,))} if counts_experts else {})}
 
     def forward(params, x, rng, train):
         if compute_dtype is not None:
             params = cast_floats(params, compute_dtype)
         if arch is not None and train:
             # a latent-attention expert model sows its loss terms (the
-            # multi-token prediction module's) already weighted, and each
-            # expert layer its token counts: summed over the layers
-            logits, sown = model.apply({"params": params}, x, train=train,
-                                       mutable=["losses", "moe_stats"])
+            # multi-token prediction module's) already weighted, and its
+            # layers their counts: summed over the layers
+            logits, sown = model.apply(
+                {"params": params}, x, train=train,
+                mutable=["losses"] + [c for c, _ in counted.values()])
             extra = sum(jax.tree.leaves(sown.get("losses", {})), 0.0)
-            if not counts_experts:
-                return logits, extra
-            return logits, extra, {"moe": sum(
-                jax.tree.leaves(sown["moe_stats"]))}
+            return logits, extra, {k: sum(jax.tree.leaves(sown[c]))
+                                   for k, (c, _) in counted.items()}
         if getattr(model, "moe_experts", 0) and train:
             # capture the Switch load-balance terms sown per MoE layer
             # (models/moe.py); plain applies elsewhere no-op the sow.
@@ -270,7 +275,8 @@ def NWPWorkload(model, pad_id: int = 0,
     loss_fn, metric_fn = make_nwp_loss_metrics(forward, pad_id)
     return Workload(model=model, loss_fn=loss_fn, metric_fn=metric_fn,
                     grad_clip_norm=grad_clip_norm,
-                    counter_shapes={"moe": (5,)} if counts_experts else None)
+                    counter_shapes={k: shape for k, (_, shape)
+                                    in counted.items()} or None)
 
 
 def TagPredictionWorkload(model, grad_clip_norm: Optional[float] = None) -> Workload:

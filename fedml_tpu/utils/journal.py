@@ -49,12 +49,14 @@ from a prefix only re-tasks more silos, never mis-aggregates).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import io
 import json
 import logging
 import os
 import time
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -112,18 +114,81 @@ def atomic_write(path: str, data: bytes, channel: str = "") -> None:
     os.replace(tmp, path)
 
 
+# crc32 over a GB-size tree, a piece a thread (`zlib.crc32` gives up the
+# GIL): the pieces and the least tree worth the threads
+_CRC_PIECE = 16 << 20
+_CRC_THREADS = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _crc_shift(power: int) -> tuple:
+    """The GF(2) matrix (its 32 columns as ints) that carries a crc32
+    over ``2 ** power`` zero bytes: zlib's ``crc32_combine``, every
+    squaring kept."""
+    if power:
+        matrix, squarings = _crc_shift(power - 1), 1
+    else:   # from one zero bit to a byte
+        matrix = (0xEDB88320,) + tuple(1 << n for n in range(31))
+        squarings = 3
+    for _ in range(squarings):
+        matrix = tuple(_crc_times(matrix, col) for col in matrix)
+    return matrix
+
+
+def _crc_times(matrix, vec: int) -> int:
+    out, i = 0, 0
+    while vec:
+        if vec & 1:
+            out ^= matrix[i]
+        vec >>= 1
+        i += 1
+    return out
+
+
+def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
+    """The crc32 of ``a + b`` from ``crc32(a)``, ``crc32(b)`` and
+    ``len(b)``."""
+    power = 0
+    while len2:
+        if len2 & 1:
+            crc1 = _crc_times(_crc_shift(power), crc1)
+        len2 >>= 1
+        power += 1
+    return crc1 ^ crc2
+
+
 def tree_crc(tree) -> int:
     """Content crc32 over a pytree's leaf bytes — the cheap identity the
     journal stamps on ``round_start`` so recovery can refuse to resume a
     fold whose clip reference is not the restored global (folding
     against the wrong reference would mis-aggregate silently; a crc
-    mismatch aborts to the round boundary instead)."""
+    mismatch aborts to the round boundary instead).  One value whatever
+    the size.  A tree of several pieces of `_CRC_PIECE` bytes is read by
+    `_CRC_THREADS` threads, each leaf's pieces handed over as soon as
+    the leaf is on the host (so the leaves of a device tree whose
+    transfers are under way are read while the later ones arrive), and
+    the pieces' values combined: a GB-size global's CRC runs beside the
+    next round's wave program and must not outlast it (PERF.md section
+    6, PR 38)."""
     import jax
+    leaves = jax.tree.leaves(tree)
+
+    def view(leaf):     # the leaf's own bytes, viewed and not copied
+        return np.ascontiguousarray(np.asarray(leaf)).reshape(-1).view(
+            np.uint8)
     crc = 0
-    for leaf in jax.tree.leaves(tree):
-        # the leaf's own bytes, viewed and not copied
-        crc = zlib.crc32(np.ascontiguousarray(np.asarray(leaf))
-                         .reshape(-1).view(np.uint8), crc)
+    if sum(getattr(x, "nbytes", 0) for x in leaves) < 4 * _CRC_PIECE:
+        for leaf in leaves:
+            crc = zlib.crc32(view(leaf), crc)
+        return crc
+    with ThreadPoolExecutor(_CRC_THREADS,
+                            thread_name_prefix="fedml-crc32") as pool:
+        parts = [(min(_CRC_PIECE, v.size - i),
+                  pool.submit(zlib.crc32, v[i:i + _CRC_PIECE]))
+                 for v in map(view, leaves)
+                 for i in range(0, v.size, _CRC_PIECE)]
+        for size, part in parts:
+            crc = crc32_combine(crc, part.result(), size)
     return crc
 
 
