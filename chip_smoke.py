@@ -21,6 +21,12 @@ parts of the round the first invocation's config gates keep apart:
            tolerance its own test uses; the fused attention core
            (`models/fused_attention.py`), which has no selector, through
            `causal_blocked_attention` at a shape it admits
+  selected the indexed grouped-query attention of
+           benchmark/models/keye_vl2_30b_a3b.json at its published widths
+           and 8,192 positions (`models/indexed_attention.py`: the XLA
+           blocks with the selection as a mask) against the plain
+           reference's attention on the same weights, and how many
+           (query, key) pairs the two select differently
   flash    --attn_flash at the CLI's default shapes is refused at config
            time with the reason; the kernel itself runs at a sequence it
            accepts and matches dense attention
@@ -426,6 +432,68 @@ def phase_kernels(tmp, kernels):
     check_latent_attention(kernels)
 
 
+def phase_selected(config="benchmark/models/keye_vl2_30b_a3b.json",
+                   t=8192, block=1024):
+    """One attention layer of the Keye-VL-2.0 configuration as the cell
+    runs it (published widths, 8,192 positions, blocks of 1,024) against
+    the plain reference's on the same weights, both at the default
+    precision: the result, and the selection itself (the same indexer
+    inputs through `index_selection` and through the reference's head by
+    head scores and `top_k`), which has to differ in a vanishing share of
+    its pairs for the cell's comparison to mean anything."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.configs import keye_vl2_30b_a3b as ref
+    from fedml_tpu.experiments.models import arch_of
+    from fedml_tpu.models.indexed_attention import (IndexedAttention,
+                                                    index_selection)
+    from fedml_tpu.models.transformer import rotary
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), config)
+    arch = arch_of(path)
+    with open(path) as f:
+        m = ref._Frozen({"initializer_range": 0.02, **json.load(f)})
+    x = jax.random.normal(jax.random.key(0), (1, t, arch.hidden_size))
+    pos = jnp.broadcast_to(jnp.arange(t), (3, t))
+    layer, plain = IndexedAttention(arch, block_size=block), ref._Attention(m)
+    params = jax.jit(layer.init)(jax.random.key(1), x, pos)["params"]
+    got = jax.jit(lambda p: layer.apply({"params": p}, x, pos))(params)
+    want = jax.jit(lambda p: plain.apply({"params": p}, x, pos))(params)
+    gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    assert all_finite([gap]) and gap < 1e-2, gap
+    say(f"   selected core at T={t}, {arch.num_attention_heads}/"
+        f"{arch.num_key_value_heads} heads of {arch.head_dim}, top-"
+        f"{arch.index_topk}: program vs plain reference {gap:.2e} of the "
+        f"norm")
+
+    ih, idim = arch.indexer_num_heads, arch.indexer_head_dim
+    keys = jax.random.split(jax.random.key(2), 3)
+    q_i = rotary(jax.random.normal(keys[0], (1, t, ih, idim)), pos[0],
+                 arch.rope_theta)
+    k_i = rotary(jax.random.normal(keys[1], (1, t, 1, idim)), pos[0],
+                 arch.rope_theta)[:, :, 0]
+    w_i = jax.random.normal(keys[2], (1, t, ih)) * (ih * idim) ** -0.5
+
+    @jax.jit
+    def plainly(q_i, k_i, w_i):
+        index = jnp.zeros((t, t), jnp.float32)
+        for j in range(ih):
+            index = index + w_i[0, :, j, None] * jax.nn.relu(
+                q_i[0, :, j] @ k_i[0].T)
+        return ref._select(index, arch.index_topk)
+    for precision in ("default", "highest"):
+        with jax.default_matmul_precision(precision):
+            ours = jax.jit(lambda *a: index_selection(
+                *a, arch.index_topk, block))(q_i, k_i, w_i)[0]
+            theirs = plainly(q_i, k_i, w_i)
+        chosen = int(jnp.sum(theirs))
+        assert int(jnp.sum(ours)) == chosen == ref.selected_pairs(
+            t, arch.index_topk)
+        apart = int(jnp.sum(ours != theirs)) // 2
+        assert apart < 1e-3 * chosen, (precision, apart, chosen)
+        say(f"   selection at {precision} precision: {apart} of {chosen} "
+            f"pairs chosen by one and not the other")
+
+
 def phase_flash():
     import jax
     import jax.numpy as jnp
@@ -592,6 +660,8 @@ def main() -> int:
             phase_round(tmp)
         with phases("kernels"):
             phase_kernels(tmp, kernels)
+        with phases("selected"):
+            phase_selected()
         with phases("flash"):
             phase_flash()
         with phases("decode"):

@@ -613,25 +613,31 @@ class CrossDevice(FedAvg):
     @staticmethod
     def _model_counts(aux_sums) -> dict:
         """What the model's layers counted over the wave's steps (summed
-        over layers, steps and clients), for `wave.dispatch`.  Always:
-        ``attn_calls``, the attention cores the wave program was handed
-        (`models.transformer.LatentAttention`: layers x client-steps that
-        ran), and ``attn_calls_fused``, those of them the fused kernels
-        took (`causal_blocked_attention` says which); both 0 for a model
-        without latent attention.  For an expert model
-        (`models.moe.SharedExpertMoE`) also the ``tokens`` routed, their
+        over layers, steps and clients), for `wave.dispatch`.  Always,
+        and 0 for a model without the mechanism: ``attn_calls``, the
+        attention cores the wave program was handed
+        (`models.transformer.LatentAttention`,
+        `models.indexed_attention.IndexedAttention`: layers x client-steps
+        that ran), ``attn_calls_fused``, those of them the fused kernels
+        took (`causal_blocked_attention` says which),
+        ``attn_pairs_causal``, the causal (query, key) pairs of the cores
+        whose keys an indexer selects, and ``attn_pairs_selected``, those
+        of them it selected.  For an expert model
+        (`models.moe.HeldExpertMoE`) also the ``tokens`` routed, their
         ``expert_assignments`` (tokens x experts a token),
         ``expert_assignments_held`` (those whose expert this chip holds),
         and the sums over layer-steps of the fullest held expert's tokens
         and of the mean held expert's (``expert_load_max`` /
-        ``expert_load_mean``)."""
-        read = jax.device_get({k: aux_sums[k] for k in ("attn", "moe")
-                               if k in aux_sums})
+        ``expert_load_mean``).  Float32 sums: exact up to 2**24 and to
+        seven digits beyond."""
         names = {"attn": ("attn_calls", "attn_calls_fused"),
+                 "select": ("attn_pairs_causal", "attn_pairs_selected"),
                  "moe": ("tokens", "expert_assignments",
                          "expert_assignments_held", "expert_load_max",
                          "expert_load_mean")}
-        counts = {"attn_calls": 0.0, "attn_calls_fused": 0.0}
+        read = jax.device_get({k: aux_sums[k] for k in names
+                               if k in aux_sums})
+        counts = dict.fromkeys(names["attn"] + names["select"], 0.0)
         for k, values in read.items():
             counts.update(zip(names[k], (float(v) for v in values)))
         return counts

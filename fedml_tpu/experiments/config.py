@@ -168,11 +168,14 @@ class ExperimentConfig:
     #                           many experts (models/moe.py); expert tables
     #                           are ep-shardable (parallel/expert.py)
     model_config: str = ""    # (transformer) a JSON file of a published
-    #                           architecture's keys under their own names
-    #                           (models/transformer.LatentMoEArch: latent
-    #                           attention, routed + shared experts), plus
-    #                           the share of a layer held here:
-    #                           experts_held, first_held, vocab_held
+    #                           architecture's keys under their own names,
+    #                           built as the arch its model_type names
+    #                           (experiments/models.arch_of: latent
+    #                           attention with routed + shared experts,
+    #                           or indexer-selected grouped-query
+    #                           attention), plus the share of a layer
+    #                           held here: experts_held, first_held,
+    #                           vocab_held
     silo_idle_timeout_s: float = 0.0  # grpc silos: exit after this long
     #                                   with no traffic (0 = wait forever)
     # ---- fault tolerance (comm/resilient.py + cross_silo health) -------

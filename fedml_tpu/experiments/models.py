@@ -27,6 +27,23 @@ _NWP_DATASETS = {"shakespeare", "fed_shakespeare", "stackoverflow_nwp",
                  "token_shards"}
 
 
+def arch_of(model_config: str):
+    """The arch a ``--model_config`` file describes, by its ``model_type``
+    (a file that states none is read as ``glm4_moe_lite``, the one arch
+    there was before the key was read)."""
+    import json
+    from fedml_tpu.models.indexed_attention import IndexedGQAArch
+    from fedml_tpu.models.transformer import LatentMoEArch
+    archs = {"glm4_moe_lite": LatentMoEArch, "KeyeVL2": IndexedGQAArch}
+    with open(model_config) as f:
+        keys = json.load(f)
+    kind = keys.get("model_type", "glm4_moe_lite")
+    if kind not in archs:
+        raise ValueError(f"--model_config {model_config}: no arch is built "
+                         f"for model_type {kind!r}; have {sorted(archs)}")
+    return archs[kind].from_dict(keys)
+
+
 def create_workload(model_name: str, dataset: str, class_num: int,
                     sample_shape: Sequence[int],
                     compute_dtype: str = "",
@@ -41,10 +58,10 @@ def create_workload(model_name: str, dataset: str, class_num: int,
     ``attn_block_size`` > 0 gives the transformer flash-style kv blocking
     (O(T*block) attention memory) for long-context train/eval;
     ``attn_flash`` swaps in the TPU pallas flash kernel instead.
-    ``model_config`` names a JSON file of a published architecture's keys
-    (`models.transformer.LatentMoEArch`): the transformer is then built
-    from them, every width the file's, over a next-token dataset whose
-    vocabulary is the file's ``vocab_held``."""
+    ``model_config`` names a JSON file of a published architecture's keys:
+    the transformer is then built from them as the arch the file's
+    ``model_type`` names (`arch_of`), every width the file's, over a
+    next-token dataset whose vocabulary is the file's ``vocab_held``."""
     import jax.numpy as jnp
     dtype = jnp.dtype(compute_dtype) if compute_dtype else None
     if (attn_block_size or attn_flash or moe_experts) \
@@ -56,8 +73,6 @@ def create_workload(model_name: str, dataset: str, class_num: int,
                          "exclusive attention backends; pick one")
     arch = None
     if model_config:
-        import json
-        from fedml_tpu.models.transformer import LatentMoEArch
         if model_name != "transformer" or dataset not in _NWP_DATASETS:
             raise ValueError("--model_config describes --model transformer "
                              "over a next-token dataset "
@@ -67,8 +82,7 @@ def create_workload(model_name: str, dataset: str, class_num: int,
                              "learned-position transformer's; a "
                              "--model_config states its own attention and "
                              "experts")
-        with open(model_config) as f:
-            arch = LatentMoEArch.from_dict(json.load(f))
+        arch = arch_of(model_config)
         if arch.vocab_held != class_num:
             raise ValueError(
                 f"--model_config holds {arch.vocab_held} rows of the "

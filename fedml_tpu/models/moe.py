@@ -131,8 +131,9 @@ class SwitchFFN(nn.Module):
 
 
 # ---------------------------------------------------------------------------
-# top-k sigmoid routing with a selection bias, no dropped token, a shared
-# expert, and a layer that holds a share of the experts
+# top-k routing (sigmoid with a selection bias, or softmax), no dropped
+# token, shared experts or none, and a layer that holds a share of the
+# experts
 
 def route_sigmoid_topk(logits, select_bias, k: int, scale: float,
                        normalize: bool = True):
@@ -149,6 +150,19 @@ def route_sigmoid_topk(logits, select_bias, k: int, scale: float,
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return chosen.astype(jnp.int32), w * scale
+
+
+def route_softmax_topk(logits, k: int, normalize: bool = True):
+    """``(chosen [..., k] int32, weights [..., k] f32)`` of the softmax
+    router (Qwen3-MoE's, Keye-VL-2.0's): probabilities over ALL experts,
+    the ``k`` largest chosen, their own probabilities as weights,
+    normalised over the ``k`` chosen where ``norm_topk_prob``.  No
+    selection bias, no scaling."""
+    w, chosen = jax.lax.top_k(
+        jax.nn.softmax(logits.astype(jnp.float32), axis=-1), k)
+    if normalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), w
 
 
 def plan_held_tiles(chosen, first_held: int, held: int, tile: int):
@@ -277,17 +291,20 @@ class GatedMLP(nn.Module):
         return dense(x.shape[-1], "down")(h)
 
 
-class SharedExpertMoE(nn.Module):
-    """The expert layer of the DeepSeek-V3 family as one chip of an
-    expert-parallel deployment holds it: ``experts_total`` routed experts
-    of which ``experts_held`` (ids ``first_held ..``) live here, ``top_k``
-    a token by `route_sigmoid_topk`, and ``n_shared`` shared experts every
-    token passes.  The router has its published width and the weights are
-    normalised over all ``top_k`` chosen, held or not; the layer returns
-    its own experts' part of the result plus the shared expert's, and
-    what the absent experts would add is left out (their chips', in a
-    deployment).  No capacity and no dropped token: the chosen (token,
-    expert) pairs are gathered by expert into `grouped_gated_mlp`.
+class HeldExpertMoE(nn.Module):
+    """An expert layer as one chip of an expert-parallel deployment holds
+    it: ``experts_total`` routed experts of which ``experts_held`` (ids
+    ``first_held ..``) live here, ``top_k`` a token, and ``n_shared``
+    shared experts every token passes (0: none).  ``router`` names the
+    rule: ``sigmoid`` is `route_sigmoid_topk` (DeepSeek-V3 / GLM-4.7: a
+    ``select_bias`` leaf, ``scale``), ``softmax`` is `route_softmax_topk`
+    (Keye-VL-2.0: no such leaf).  The router has its published width and
+    the weights are normalised over all ``top_k`` chosen, held or not; the
+    layer returns its own experts' part of the result plus the shared
+    experts', and what the absent experts would add is left out (their
+    chips', in a deployment).  No capacity and no dropped token: the
+    chosen (token, expert) pairs are gathered by expert into
+    `grouped_gated_mlp`.
 
     Sows ``moe_stats/counts``, float32 ``[tokens, assignments, held
     assignments, largest held expert's tokens, mean held expert's
@@ -304,11 +321,14 @@ class SharedExpertMoE(nn.Module):
     init_std: float = 0.02
     tile: int = 512
     dtype: object = None
+    router: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):
         b, t, d = x.shape
         n, held = b * t, self.experts_held
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"no router {self.router!r}")
         if not 0 <= self.first_held <= self.experts_total - held:
             raise ValueError(
                 f"experts {self.first_held}..{self.first_held + held - 1} "
@@ -317,12 +337,17 @@ class SharedExpertMoE(nn.Module):
         xt = x.reshape(n, d)
         router = self.param("router", init, (d, self.experts_total),
                             jnp.float32)
-        bias = self.param("select_bias", init, (self.experts_total,),
-                          jnp.float32)
+        # the choice is discrete: a logit a rounding away moves a token
         logits = jnp.dot(xt.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        chosen, w = route_sigmoid_topk(logits, bias, self.top_k, self.scale,
-                                       self.normalize)
+        if self.router == "softmax":
+            chosen, w = route_softmax_topk(logits, self.top_k,
+                                           self.normalize)
+        else:
+            bias = self.param("select_bias", init, (self.experts_total,),
+                              jnp.float32)
+            chosen, w = route_sigmoid_topk(logits, bias, self.top_k,
+                                           self.scale, self.normalize)
         wg = self.param("experts_gate", init, (held, d, self.d_ff),
                         jnp.float32)
         wu = self.param("experts_up", init, (held, d, self.d_ff),

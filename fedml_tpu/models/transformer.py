@@ -24,17 +24,26 @@ every slot may sit at a DIFFERENT sequence index, which is exactly what
 continuous batching needs (a finished slot restarts at position 0 and the
 ``kv_idx <= position`` mask hides the previous occupant's stale rows).
 
-With ``arch`` (a `LatentMoEArch`, the published configuration keys of the
-DeepSeek-V3 / GLM-4.7 family) the same model is built from other parts:
+With ``arch`` (the published configuration keys of an expert model, by the
+``model_type`` its file names) the same model is built from other parts:
 RMSNorm before each half of a block and before the head, rotary positions,
-gated SiLU MLPs without biases, latent attention (`LatentAttention`: queries
-and keys/values through low-rank latents, one rotary key shared by all
-heads), ``first_k_dense_replace`` dense blocks and then expert blocks
-(`models.moe.SharedExpertMoE`: the share of the routed experts this chip
-holds, and the shared expert), an untied head, one `jax.checkpoint` a block,
-and ``num_nextn_predict_layers`` multi-token prediction modules whose loss
-term is sown into ``losses``.  Training only: the decode cache and the ring
-are the learned-position model's.
+gated SiLU MLPs without biases, an untied head, one `jax.checkpoint` a
+block (`DecoderBlock`, `TransformerLM._decoder`), and the attention and the
+expert layer the arch states:
+
+* `LatentMoEArch` (DeepSeek-V3 / GLM-4.7 family): latent attention
+  (`LatentAttention`: queries and keys/values through low-rank latents, one
+  rotary key shared by all heads), ``first_k_dense_replace`` dense blocks
+  and then expert blocks (`models.moe.HeldExpertMoE`: the share of the
+  sigmoid-routed experts this chip holds, and the shared expert), and
+  ``num_nextn_predict_layers`` multi-token prediction modules whose loss
+  term is sown into ``losses``;
+* `models.indexed_attention.IndexedGQAArch` (Keye-VL-2.0's language model):
+  grouped-query attention with three-axis rotary positions whose keys a
+  learned indexer selects, every block an expert block routed by softmax.
+
+Both cores run through `causal_blocked_attention`.  Training only: the
+decode cache and the ring are the learned-position model's.
 """
 
 from __future__ import annotations
@@ -46,6 +55,7 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from fedml_tpu.parallel.ring_attention import (
     blockwise_attention, full_attention, ring_attention)
@@ -64,6 +74,11 @@ def _auto_block(t: int, threshold: int, max_block: int = 512,
             return b
     return None
 
+
+# the `checkpoint_name` of an indexer's selection: a `DecoderBlock`'s
+# checkpoint keeps it for the backward pass, beside what the fused core
+# names (`models.fused_attention.SAVED`)
+SELECTED = "attn_selected"
 
 # the flash kernel's q/kv block: the sequence must be a whole number of
 # these (its default BlockSizes; `create_workload` checks at config time)
@@ -175,10 +190,37 @@ def init_decode_cache(model: "TransformerLM", slots: int, cache_len: int,
             for i in range(model.n_layers)}
 
 
+class ArchKeys:
+    """What the dataclasses of published keys share: `from_dict`, and what
+    `TransformerLM._decoder`, `DecoderBlock` and the workload ask of an
+    arch beside its widths: ``position_rows`` (rows of positions the
+    rotary takes: 1, or 3 for temporal / height / width), `attention` and
+    `ffn` (the two halves of a block, as modules), and ``counters`` (name
+    -> (collection, shape) of what its layers sow a step, which
+    `trainer.workload.NWPWorkload` sums and `wave.dispatch` reports),
+    and the two counts the scaffolding reads, ``first_k_dense_replace``
+    and ``num_nextn_predict_layers`` (a field where the published file
+    has the key, 0 where the family has neither)."""
+    position_rows = 1
+
+    @classmethod
+    def from_dict(cls, d: dict):
+        """From a configuration file's keys; a key this model has no use
+        for (``model_type``, ``max_position_embeddings``) is passed over, a
+        missing one is an error that names it."""
+        names = {f.name: f for f in dataclasses.fields(cls)}
+        missing = [n for n, f in names.items() if n not in d
+                   and f.default is dataclasses.MISSING]
+        if missing:
+            raise KeyError(f"model configuration lacks {missing}")
+        return cls(**{n: d[n] for n in names if n in d})
+
+
 @dataclasses.dataclass(frozen=True)
-class LatentMoEArch:
+class LatentMoEArch(ArchKeys):
     """The architecture's keys under their published names (a model's
-    ``config.json``), plus the share of a layer this chip holds:
+    ``config.json``, ``model_type`` ``glm4_moe_lite``), plus the share of a
+    layer this chip holds:
     ``experts_held`` routed experts from ``first_held`` on and the first
     ``vocab_held`` rows of the vocabulary.  ``initializer_range`` and
     ``mtp_loss_weight`` are not in the published file; their defaults are
@@ -217,17 +259,28 @@ class LatentMoEArch:
     mtp_loss_weight: float = 0.3
     embedding_range: float = 1.0
 
-    @classmethod
-    def from_dict(cls, d: dict) -> "LatentMoEArch":
-        """From a configuration file's keys; a key this model has no use
-        for (``model_type``, ``max_position_embeddings``) is passed over, a
-        missing one is an error that names it."""
-        names = {f.name: f for f in dataclasses.fields(cls)}
-        missing = [n for n, f in names.items() if n not in d
-                   and f.default is dataclasses.MISSING]
-        if missing:
-            raise KeyError(f"model configuration lacks {missing}")
-        return cls(**{n: d[n] for n in names if n in d})
+    def attention(self, dtype, block_size):
+        return LatentAttention(self, dtype, block_size, name="attn")
+
+    def ffn(self, experts: bool, dtype):
+        from fedml_tpu.models.moe import GatedMLP, HeldExpertMoE
+        if not experts:
+            return GatedMLP(self.intermediate_size, self.initializer_range,
+                            dtype, name="mlp")
+        return HeldExpertMoE(
+            self.n_routed_experts, self.experts_held, self.first_held,
+            self.num_experts_per_tok, self.moe_intermediate_size,
+            n_shared=self.n_shared_experts,
+            scale=self.routed_scaling_factor, normalize=self.norm_topk_prob,
+            init_std=self.initializer_range, dtype=dtype, name="moe")
+
+    @property
+    def counters(self) -> dict:
+        # every attention its core and whether the fused kernels took
+        # it, every expert layer its tokens
+        experts = self.num_hidden_layers > self.first_k_dense_replace
+        return {"attn": ("attn_stats", (2,)),
+                **({"moe": ("moe_stats", (5,))} if experts else {})}
 
 
 class RMSNorm(nn.Module):
@@ -244,70 +297,113 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype or x.dtype)
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, sections=None):
     """Rotary position embedding over the last axis of ``x`` [B, T, H, r],
-    pairing element ``i`` with ``i + r/2`` (the rotate-half convention)."""
+    pairing element ``i`` with ``i + r/2`` (the rotate-half convention).
+    ``positions`` [T] turns every frequency.  With ``sections`` (numbers of
+    frequencies that add up to ``r/2``) ``positions`` is [len(sections),
+    T] and row ``a`` turns the ``a``-th run of frequencies (the chunked
+    multi-axis layout of Qwen2-VL: temporal, height, width)."""
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
+    if sections is None:
+        at = positions[:, None]
+    else:
+        if sum(sections) != half:
+            raise ValueError(f"rotary sections {tuple(sections)} do not add "
+                             f"up to the {half} frequencies of a head")
+        at = positions[np.repeat(np.arange(len(sections)), sections)].T
+    angle = at.astype(jnp.float32) * freq[None, :]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
 
 
-def fused_core_fits(q, k, v) -> bool:
+def fused_core_fits(q, k, v, selected=None) -> bool:
     """Whether `causal_blocked_attention` hands these to the fused kernels
-    (`models.fused_attention`): on a TPU, and float32 with a sequence that
-    is a whole number of the kernels' blocks, head widths that are
-    multiples of 128 and a head that fits in VMEM."""
+    (`models.fused_attention`): on a TPU, every causal key seen (no
+    selection), as many key heads as query heads, and float32 with a
+    sequence that is a whole number of the kernels' blocks, head widths
+    that are multiples of 128 and a head that fits in VMEM."""
     from fedml_tpu.models import fused_attention
-    return jax.default_backend() == "tpu" and fused_attention.admits(q, k, v)
+    return (jax.default_backend() == "tpu" and selected is None
+            and k.shape[2] == q.shape[2] and fused_attention.admits(q, k, v))
 
 
-def causal_blocked_attention(q, k, v, block: Optional[int] = None):
-    """Causal softmax attention, ``q``/``k`` [B, T, H, dk] and ``v``
-    [B, T, H, dv] at positions 0..T-1.  One algorithm, and the inputs say
-    which implementation of it runs (`fused_core_fits`):
+def causal_blocked_attention(q, k, v, block: Optional[int] = None,
+                             selected=None):
+    """Causal softmax attention, ``q`` [B, T, H, dk], ``k`` [B, T, Hkv, dk]
+    and ``v`` [B, T, Hkv, dv] at positions 0..T-1; query head ``h`` reads
+    key/value head ``h // (H / Hkv)``.  ``selected`` [B, T, T] bool, where
+    given, says which keys each query sees (a subset of its causal past:
+    `models.indexed_attention`); None is all of it.  One algorithm, and
+    the inputs say which implementation of it runs (`fused_core_fits`):
 
-    * on a TPU, for float32 inputs whose ``T`` is a whole number of 512,
-      whose ``dk`` and ``dv`` are multiples of 128 and whose head fits in
-      VMEM (``T * max(dk, dv) <= 8192 * 256``): the fused Pallas kernels
-      of `models.fused_attention`, one a pass, scores and probabilities in
-      VMEM only, the log-sum-exp saved for the backward pass; ``block``
-      plays no part there;
+    * on a TPU, with no selection and ``Hkv == H``, for float32 inputs
+      whose ``T`` is a whole number of 512, whose ``dk`` and ``dv`` are
+      multiples of 128 and whose head fits in VMEM (``T * max(dk, dv) <=
+      8192 * 256``): the fused Pallas kernels of `models.fused_attention`,
+      one a pass, scores and probabilities in VMEM only, the log-sum-exp
+      saved for the backward pass; ``block`` plays no part there;
     * anywhere else (the CPU, another dtype, a ragged or short ``T``, a
-      narrow head): XLA, one block of ``block`` queries at a time against
-      the keys up to its last position: the scores held at once are
-      [B, H, block, <= T] and the blocks wholly above the diagonal are
-      never computed.  Each block is a `jax.checkpoint`, so the backward
-      pass computes its scores again and keeps none.  ``block`` None is
-      one block."""
-    if fused_core_fits(q, k, v):
+      narrow head, a selection, grouped key heads): XLA, one block of
+      ``block`` queries at a time against the keys up to its last
+      position: the scores held at once are [B, H, block, <= T], the
+      selection is a mask on them, and the blocks wholly above the
+      diagonal are never computed.  Each block is a `jax.checkpoint`, so
+      the backward pass computes its scores again and keeps none.
+      ``block`` None is one block."""
+    if fused_core_fits(q, k, v, selected):
         from fedml_tpu.core.pallas_agg import pallas_interpret
         from fedml_tpu.models import fused_attention
         return fused_attention.fused_causal_attention(
             q, k, v, interpret=pallas_interpret(fused_attention.KERNEL))
-    return _xla_blocked_attention(q, k, v, block)
+    return _xla_blocked_attention(q, k, v, block, selected)
 
 
-def _xla_blocked_attention(q, k, v, block: Optional[int] = None):
-    t = q.shape[1]
+def _xla_blocked_attention(q, k, v, block: Optional[int] = None,
+                           selected=None):
+    b, t, h, _ = q.shape
+    kv = k.shape[2]
+    g = h // kv
     block = t if block is None else min(block, t)
     scale = 1.0 / math.sqrt(q.shape[-1])
 
+    # the g query heads that read one key/value head stand as g runs of
+    # rows under that head: one product a key/value head, no copy of it
+    # (nothing is moved where every query head has its own)
+    def heads_as_rows(x):           # [B, n, H, d] -> [B, g * n, Hkv, d]
+        n = x.shape[1]
+        return x if g == 1 else x.reshape(b, n, kv, g, -1).transpose(
+            0, 3, 1, 2, 4).reshape(b, g * n, kv, -1)
+
+    def rows_as_heads(x):           # and back
+        n = x.shape[1] // g
+        return x if g == 1 else x.reshape(b, g, n, kv, -1).transpose(
+            0, 2, 3, 1, 4).reshape(b, n, h, -1)
+
     @jax.checkpoint
-    def one(qb, kb, vb, q_pos):
+    def one(qb, kb, vb, q_pos, chosen):
         s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
                        preferred_element_type=jnp.float32) * scale
-        seen = jnp.arange(kb.shape[1])[None, :] <= q_pos[:, None]
+        seen = jnp.arange(kb.shape[1])[None, :] <= q_pos[:, None] \
+            if chosen is None else chosen
         p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb,
                           preferred_element_type=jnp.float32)
 
-    out = [one(q[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block],
-               jnp.arange(lo, min(lo + block, t)))
-           for lo in range(0, t, block)]
+    out = []
+    for lo in range(0, t, block):
+        hi = min(lo + block, t)
+        # a selection lies inside the causal past: it is the whole mask,
+        # each query's for every head of its group
+        chosen = None if selected is None else jnp.tile(
+            selected[:, None, lo:hi, :hi], (1, 1, g, 1))
+        q_pos = jnp.arange(lo, hi)
+        out.append(rows_as_heads(one(
+            heads_as_rows(q[:, lo:hi]), k[:, :hi], v[:, :hi],
+            q_pos if g == 1 else jnp.tile(q_pos, g), chosen)))
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
@@ -359,33 +455,23 @@ class LatentAttention(nn.Module):
             out.astype(x.dtype).reshape(b, t, h * a.v_head_dim))
 
 
-class LatentMoEBlock(nn.Module):
-    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the FFN a
-    gated MLP of the dense width, or the expert layer."""
-    arch: LatentMoEArch
+class DecoderBlock(nn.Module):
+    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
+    attention the arch's, the FFN its dense gated MLP or its expert
+    layer."""
+    arch: ArchKeys
     experts: bool
     dtype: object = None
     block_size: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, positions):
-        from fedml_tpu.models.moe import GatedMLP, SharedExpertMoE
         a = self.arch
-        h = x + LatentAttention(a, self.dtype, self.block_size, name="attn")(
+        h = x + a.attention(self.dtype, self.block_size)(
             RMSNorm(a.rms_norm_eps, self.dtype, name="attn_norm")(x),
             positions)
-        f = RMSNorm(a.rms_norm_eps, self.dtype, name="ffn_norm")(h)
-        if self.experts:
-            f = SharedExpertMoE(
-                a.n_routed_experts, a.experts_held, a.first_held,
-                a.num_experts_per_tok, a.moe_intermediate_size,
-                n_shared=a.n_shared_experts, scale=a.routed_scaling_factor,
-                normalize=a.norm_topk_prob, init_std=a.initializer_range,
-                dtype=self.dtype, name="moe")(f)
-        else:
-            f = GatedMLP(a.intermediate_size, a.initializer_range,
-                         self.dtype, name="mlp")(f)
-        return h + f
+        return h + a.ffn(self.experts, self.dtype)(
+            RMSNorm(a.rms_norm_eps, self.dtype, name="ffn_norm")(h))
 
 
 class TransformerLM(nn.Module):
@@ -419,10 +505,10 @@ class TransformerLM(nn.Module):
     moe_aux_weight: float = 0.01      # Switch paper's alpha
     pad_id: int = 0       # pad token id; MoE routing excludes pad positions
     #                       (they would otherwise eat expert capacity)
-    arch: Optional[LatentMoEArch] = None  # the published keys of a
-    #                       latent-attention expert model: every width then
-    #                       comes from it and the fields above that state
-    #                       one are not read
+    arch: Optional[ArchKeys] = None  # the published keys of an expert
+    #                       model (`LatentMoEArch`, `IndexedGQAArch`): every
+    #                       width then comes from it and the fields above
+    #                       that state one are not read
 
     @nn.compact
     def __call__(self, input_seq, train: bool = False, positions=None,
@@ -432,9 +518,10 @@ class TransformerLM(nn.Module):
         if self.arch is not None:
             if decode or ring_axis is not None:
                 raise NotImplementedError(
-                    "a latent-attention model trains only: its decode "
-                    "cache (the latent one) and the ring are not built")
-            return self._latent_moe(input_seq, positions)
+                    "a --model_config model trains only: neither its "
+                    "decode cache (latent keys, or an indexer's keys "
+                    "beside the selected ones) nor the ring is built")
+            return self._decoder(input_seq, positions)
         if decode:
             if positions is None:
                 raise ValueError(
@@ -497,12 +584,14 @@ class TransformerLM(nn.Module):
                           name="lm_head")(x)
         return (logits[:, 0, :], new_cache) if decode else logits
 
-    def _latent_moe(self, tokens, positions):
+    def _decoder(self, tokens, positions):
         """The forward pass under ``arch`` (called inside `__call__`)."""
         a = self.arch
         t = tokens.shape[1]
         if positions is None:
-            positions = jnp.arange(t)
+            # text: every axis of the rotary at the token's index
+            positions = jnp.arange(t) if a.position_rows == 1 else \
+                jnp.broadcast_to(jnp.arange(t), (a.position_rows, t))
         init = nn.initializers.normal(a.initializer_range)
         embed = nn.Embed(a.vocab_held, a.hidden_size, dtype=self.dtype,
                          embedding_init=nn.initializers.normal(
@@ -511,13 +600,15 @@ class TransformerLM(nn.Module):
         head = nn.Dense(a.vocab_held, use_bias=False, dtype=self.dtype,
                         kernel_init=init, name="lm_head")
         # a block is computed again for its backward pass, but for what
-        # the fused attention core names: its result and log-sum-exp are
-        # kept, so its forward kernel runs once a step (the XLA core names
+        # its attention names: the fused core's result and log-sum-exp,
+        # so its forward kernel runs once a step, and an indexer's
+        # selection, so it is made once a step (the XLA core names
         # nothing, and nothing of it is kept)
         from fedml_tpu.models.fused_attention import SAVED
         block = nn.remat(
-            LatentMoEBlock,
-            policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
+            DecoderBlock,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *SAVED, SELECTED))
 
         x = embed(tokens)
         for i in range(a.num_hidden_layers):

@@ -238,22 +238,19 @@ def NWPWorkload(model, pad_id: int = 0,
     create_workload wires both) or the recurrent matmuls stay f32."""
 
     arch = getattr(model, "arch", None)
-    counts_experts = (arch is not None
-                      and arch.num_hidden_layers > arch.first_k_dense_replace)
-    # what a latent-attention model's layers count, by the collection
-    # each sows into: every attention its core and whether the fused
-    # kernels took it, every expert layer its tokens
-    counted = {} if arch is None else {
-        "attn": ("attn_stats", (2,)),
-        **({"moe": ("moe_stats", (5,))} if counts_experts else {})}
+    # what a --model_config model's layers count a step, by the
+    # collection each sows into (the arch says: every attention its core
+    # and whether the fused kernels took it, an indexer its pairs, every
+    # expert layer its tokens)
+    counted = {} if arch is None else arch.counters
 
     def forward(params, x, rng, train):
         if compute_dtype is not None:
             params = cast_floats(params, compute_dtype)
         if arch is not None and train:
-            # a latent-attention expert model sows its loss terms (the
-            # multi-token prediction module's) already weighted, and its
-            # layers their counts: summed over the layers
+            # a --model_config model sows its loss terms (a multi-token
+            # prediction module's) already weighted, and its layers their
+            # counts: summed over the layers
             logits, sown = model.apply(
                 {"params": params}, x, train=train,
                 mutable=["losses"] + [c for c, _ in counted.values()])

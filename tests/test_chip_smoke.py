@@ -220,3 +220,15 @@ def test_compile_watch_counts_backend_compiles():
     assert after["compile_s"] > before["compile_s"]
     assert len(after["durations"]) == after["compiles"]
     assert json.dumps(after)  # plain numbers: printable as a fact
+
+
+def test_the_selected_phase_runs_at_a_tiny_size_on_the_cpu(capsys):
+    """`chip_smoke.phase_selected` is the chip's check of the indexed
+    attention at its published widths; here its control flow at the tiny
+    configuration's (no platform is asserted inside it)."""
+    import chip_smoke
+    chip_smoke.phase_selected(
+        "benchmark/tests/tiny/models/keye_vl2_30b_a3b.json", t=64, block=16)
+    said = capsys.readouterr().out
+    assert "program vs plain reference" in said
+    assert said.count("pairs chosen by one and not the other") == 2

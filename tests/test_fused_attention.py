@@ -271,6 +271,9 @@ def test_wave_dispatch_counts_none_without_attention(tmp_path):
             "--model", "resnet56", "--dataset", "cifar10",
             "--client_num_in_total", "2"]):
         assert args["attn_calls"] == 0 and args["attn_calls_fused"] == 0
+        # nor pairs for an indexer to select from (ISSUE 39)
+        assert args["attn_pairs_causal"] == 0
+        assert args["attn_pairs_selected"] == 0
 
 
 def test_attn_fused_share_reads_those_counts():

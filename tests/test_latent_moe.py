@@ -1,5 +1,5 @@
 """The latent-attention expert model on the normal path (ISSUE 37):
-`models/transformer.py` under ``arch`` and `models/moe.SharedExpertMoE`
+`models/transformer.py` under ``arch`` and `models/moe.HeldExpertMoE`
 against the plain reference `benchmark/configs/glm47_flash.py` at a tiny
 size on the CPU (seeded weights, products at ``highest``), the router and
 the attention on their own, the share test of the expert cut, and
@@ -17,7 +17,7 @@ import pytest
 
 from benchmark.configs import glm47_flash as ref
 from benchmark.token_shards import token_shard_arrays, write_token_shards
-from fedml_tpu.models.moe import (SharedExpertMoE, grouped_gated_mlp,
+from fedml_tpu.models.moe import (HeldExpertMoE, grouped_gated_mlp,
                                   plan_held_tiles, route_sigmoid_topk)
 from fedml_tpu.models.transformer import (LatentAttention, LatentMoEArch,
                                           TransformerLM,
@@ -135,7 +135,7 @@ def test_the_bias_changes_the_choice_and_not_the_weights():
 def test_no_token_is_dropped_when_every_token_takes_one_expert():
     """All 40 tokens alike: all choose the same two experts, one of them
     held, and every one gets that expert's answer."""
-    layer = SharedExpertMoE(experts_total=8, experts_held=2, first_held=2,
+    layer = HeldExpertMoE(experts_total=8, experts_held=2, first_held=2,
                             top_k=2, d_ff=12, n_shared=0, scale=1.8, tile=8)
     x = jnp.tile(jax.random.normal(jax.random.key(3), (1, 1, 16)),
                  (4, 10, 1))
@@ -245,7 +245,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
         total = shared
         for chip in range(4):
             lo = 2 * chip
-            layer = SharedExpertMoE(
+            layer = HeldExpertMoE(
                 experts_total=8, experts_held=2, first_held=lo,
                 top_k=TINY["num_experts_per_tok"],
                 d_ff=TINY["moe_intermediate_size"], n_shared=1,

@@ -365,7 +365,8 @@ def test_skipped_step_share_reads_the_dispatch_span_as_data():
         "name": "wave_skipped_step_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "staging and local training",
         "moves": "round_s", "workloads": ["resnet56_cifar10.silos10",
-                                           "glm47_flash.silos2"]} \
+                                           "glm47_flash.silos2",
+                                           "keye_vl2_30b_a3b.silos2"]} \
         in bench["per_layer"]
 
 
@@ -385,7 +386,8 @@ def test_prefetch_hit_share_reads_the_dispatch_span_as_data():
         "name": "stage_prefetch_hit_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "staging and local training",
         "moves": "round_s", "workloads": ["resnet56_cifar10.silos10",
-                                           "glm47_flash.silos2"]} \
+                                           "glm47_flash.silos2",
+                                           "keye_vl2_30b_a3b.silos2"]} \
         in bench["per_layer"]
 
 
