@@ -23,10 +23,12 @@ parts of the round the first invocation's config gates keep apart:
            `causal_blocked_attention` at a shape it admits
   selected the indexed grouped-query attention of
            benchmark/models/keye_vl2_30b_a3b.json at its published widths
-           and 8,192 positions (`models/indexed_attention.py`: the XLA
-           blocks with the selection as a mask) against the plain
-           reference's attention on the same weights, and how many
-           (query, key) pairs the two select differently
+           and 8,192 positions (`models/indexed_attention.py`: the fused
+           kernels with the selection and grouped key heads) against the
+           plain reference's attention on the same weights, how many
+           (query, key) pairs the two select differently, and the
+           selected kernels' result and gradients against the XLA blocks
+           on one selection
   flash    --attn_flash at the CLI's default shapes is refused at config
            time with the reason; the kernel itself runs at a sequence it
            accepts and matches dense attention
@@ -432,7 +434,44 @@ def phase_kernels(tmp, kernels):
     check_latent_attention(kernels)
 
 
-def phase_selected(config="benchmark/models/keye_vl2_30b_a3b.json",
+def check_selected_kernels(kernels, selected, heads, kv_heads, width,
+                           block):
+    """`causal_blocked_attention` with ``selected`` [1, T, T] and
+    ``kv_heads`` key heads under ``heads`` query heads: it must hand over
+    to the selected kernels, Mosaic must compile them, and result and
+    gradients must be the XLA blocks' on the same selection to the
+    default precision's rounding."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.models import transformer as tr
+    t = selected.shape[-1]
+    keys = jax.random.split(jax.random.key(3), 4)
+    q, w = (jax.random.normal(x, (1, t, heads, width)) for x in keys[:2])
+    k, v = (jax.random.normal(x, (1, t, kv_heads, width))
+            for x in keys[2:])
+    assert tr.fused_core_fits(q, k, v, selected)
+
+    def all_of(core):
+        def weighted(q, k, v):
+            out = core(q, k, v)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            weighted, (0, 1, 2), has_aux=True))(q, k, v)
+        return (out,) + grads
+    fused = all_of(lambda q, k, v: tr.causal_blocked_attention(
+        q, k, v, block, selected))
+    kernels.assert_compiled("selected_attention")
+    plain = all_of(lambda q, k, v: tr._xla_blocked_attention(
+        q, k, v, block, selected))
+    gaps = [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+            for a, b in zip(fused, plain)]
+    assert all_finite(gaps) and max(gaps) < 1e-2, gaps
+    say(f"   selected_attention: kernels vs XLA blocks at T={t}, "
+        f"{heads}/{kv_heads} heads of {width}, one selection: out, dq, dk, "
+        f"dv {' '.join(f'{x:.2e}' for x in gaps)} of the norm")
+
+
+def phase_selected(kernels, config="benchmark/models/keye_vl2_30b_a3b.json",
                    t=8192, block=1024):
     """One attention layer of the Keye-VL-2.0 configuration as the cell
     runs it (published widths, 8,192 positions, blocks of 1,024) against
@@ -440,7 +479,8 @@ def phase_selected(config="benchmark/models/keye_vl2_30b_a3b.json",
     precision: the result, and the selection itself (the same indexer
     inputs through `index_selection` and through the reference's head by
     head scores and `top_k`), which has to differ in a vanishing share of
-    its pairs for the cell's comparison to mean anything."""
+    its pairs for the cell's comparison to mean anything; then the
+    selected kernels against the XLA blocks on that selection."""
     import jax
     import jax.numpy as jnp
     from benchmark.configs import keye_vl2_30b_a3b as ref
@@ -492,6 +532,8 @@ def phase_selected(config="benchmark/models/keye_vl2_30b_a3b.json",
         assert apart < 1e-3 * chosen, (precision, apart, chosen)
         say(f"   selection at {precision} precision: {apart} of {chosen} "
             f"pairs chosen by one and not the other")
+    check_selected_kernels(kernels, ours[None], arch.num_attention_heads,
+                           arch.num_key_value_heads, arch.head_dim, block)
 
 
 def phase_flash():
@@ -661,7 +703,7 @@ def main() -> int:
         with phases("kernels"):
             phase_kernels(tmp, kernels)
         with phases("selected"):
-            phase_selected()
+            phase_selected(kernels)
         with phases("flash"):
             phase_flash()
         with phases("decode"):

@@ -1,4 +1,5 @@
-"""The causal attention core as fused Pallas kernels (ISSUE 38).
+"""The causal attention core as fused Pallas kernels (ISSUE 38; a
+selection and grouped key heads, ISSUE 40).
 
 `models.transformer.causal_blocked_attention` hands a sequence to these on
 a TPU where `admits` says they fit; everywhere else its XLA blocks run.
@@ -30,10 +31,33 @@ finds the attention group by it):
   products a pair in all, against the nine of a ``dkv`` + ``dq`` pair of
   kernels.
 
+The inputs choose the variant, one algorithm either way:
+
+* ``latent_attention_forward/backward`` (`KERNEL`): every causal key, as
+  many key heads as query heads (latent attention);
+* ``selected_attention_forward/backward`` (`SELECTED_KERNEL`): a
+  selection ``[B, T, T]`` bool (`models.indexed_attention`), handed over as
+  int8, and/or ``g = H / Hkv`` query heads a key/value head.  Query head
+  ``h`` reads key/value head ``h // g`` through the `BlockSpec` index maps
+  (no copy of k or v a query head).  The forward grid is (sequence, key
+  head, query block, query head of the group): the ``g`` heads of a key
+  head run innermost, so a query block's row of the selection, ``[block,
+  T]``, is fetched once a key head, not once a query head.  The backward
+  pass takes the selection transposed, ``[keys, queries]`` (XLA makes that
+  int8 copy once a step), a key block's row of it fetched each grid step,
+  and writes ``dk``/``dv`` a query head, which XLA sums over the group.
+  The selection is the whole mask: every score tile is masked by it (the
+  diagonal one too), and a key a row did not select adds nothing to it
+  (``p`` is 0 there, whatever the running maximum).  PRECONDITION: the
+  selection lies inside the causal past and every row selects at least
+  one key (the indexer's rows select ``min(t + 1, topk)``); a row that
+  selects nothing divides by a zero sum.  Tiles no query selects are
+  computed all the same (no skip: at random weights none occurs).
+
 The result and the log-sum-exp carry `checkpoint_name`s (`SAVED`): a
 `jax.checkpoint` around the caller that saves those two names (the
-latent-attention block's does) runs the forward kernel once a step, not
-again for the backward pass.
+block's does) runs the forward kernel once a step, not again for the
+backward pass.
 """
 
 from __future__ import annotations
@@ -48,12 +72,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 KERNEL = "latent_attention"         # its name to `pallas_interpret`
+SELECTED_KERNEL = "selected_attention"  # the variant's, likewise
 BLOCK = 512                         # queries and keys a block
 # a head's whole k and v (forward) or q, do and dq (backward) stay in
 # VMEM: the float32 blocks the pipeline double-buffers, their bfloat16
 # copies, dq.  At 8,192 x 256 that is 48 MB forward and 72 MB backward of
 # the v5e's 128 MiB, so the kernels ask for 100 MB and `admits` stops at
-# that size
+# that size (a selection's row of `BLOCK` x T int8, double-buffered, adds
+# 8 MB at 8,192, 16 MB at the longest admitted T, 16,384 x 128)
 MAX_HEAD_ELEMS = 8192 * 256
 VMEM_LIMIT = 100 * 1024 * 1024
 SAVED = ("attn_core_out", "attn_core_lse")
@@ -62,15 +88,25 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
-def admits(q, k, v) -> bool:
-    """Whether the kernels fit ``q``/``k`` [B, T, H, dk], ``v``
-    [B, T, H, dv]: float32, a whole number of `BLOCK`s, head widths that
-    are multiples of 128 lanes, a head that fits in VMEM."""
-    t, dk, dv = q.shape[1], q.shape[-1], v.shape[-1]
+def admits(q, k, v, selected=None) -> bool:
+    """Whether the kernels fit ``q`` [B, T, H, dk], ``k`` [B, T, Hkv, dk],
+    ``v`` [B, T, Hkv, dv] and ``selected`` (None, or [B, T, T]): float32, a
+    whole number of `BLOCK`s, head widths that are multiples of 128 lanes,
+    ``Hkv`` dividing ``H``, a head that fits in VMEM."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
     return (all(x.dtype == jnp.float32 for x in (q, k, v))
             and k.shape[1] == t and t % BLOCK == 0
+            and h % k.shape[2] == 0
+            and (selected is None or selected.shape == (b, t, t))
             and dk % 128 == 0 and dv % 128 == 0
             and t * max(dk, dv) <= MAX_HEAD_ELEMS)
+
+
+def kernel_name(g, selected=None) -> str:
+    """`KERNEL` for every causal key under as many key heads as query
+    heads (``g`` query heads a key head: 1), else `SELECTED_KERNEL`."""
+    return KERNEL if selected is None and g == 1 else SELECTED_KERNEL
 
 
 def _cast_rows(src_ref, dst_ref, block):
@@ -100,11 +136,16 @@ def _below_diagonal(block, transposed):
     return a <= b if transposed else b <= a
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref, *,
-                    scale, block):
+def _forward_kernel(*refs, scale, block, selected, heads_inner):
+    q_ref, k_ref, v_ref = refs[:3]
+    sel_ref = refs[3] if selected else None
+    o_ref, lse_ref, kb_ref, vb_ref = refs[3 + selected:]
     i = pl.program_id(2)
+    # the key head's first grid step casts what the rest read
+    first = (jnp.logical_and(i == 0, pl.program_id(3) == 0) if heads_inner
+             else i == 0)
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         _cast_rows(k_ref, kb_ref, block)
         _cast_rows(v_ref, vb_ref, block)
@@ -116,11 +157,16 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref, *,
         at = pl.ds(pl.multiple_of(j * block, block), block)
         s = jax.lax.dot_general(qb, kb_ref[at, :], _NT,
                                 preferred_element_type=jnp.float32) * scale
-        if diagonal:
+        if sel_ref is not None:         # the whole mask, every tile
+            keep = sel_ref[:, at] != 0
+            s = jnp.where(keep, s, _MASK)
+        elif diagonal:
             s = jnp.where(_below_diagonal(block, False), s, _MASK)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
+        if sel_ref is not None:         # a row that selected nothing yet
+            p = jnp.where(keep, p, 0.0)
         l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
         acc = alpha * acc + jnp.dot(p.astype(jnp.bfloat16), vb_ref[at, :],
                                     preferred_element_type=jnp.float32)
@@ -135,9 +181,10 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref, *,
     lse_ref[...] = _column_to_row(m + jnp.log(l))
 
 
-def _backward_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
-                     dq_ref, dk_ref, dv_ref, qb_ref, dob_ref, *,
-                     scale, block):
+def _backward_kernel(*refs, scale, block, selected):
+    q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref = refs[:6]
+    sel_ref = refs[6] if selected else None
+    dq_ref, dk_ref, dv_ref, qb_ref, dob_ref = refs[6 + selected:]
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -156,9 +203,13 @@ def _backward_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
         # [keys, queries]: the rows' log-sum-exp and delta lie along it
         s = jax.lax.dot_general(kb, qi, _NT,
                                 preferred_element_type=jnp.float32) * scale
-        if diagonal:
-            s = jnp.where(_below_diagonal(block, True), s, _MASK)
-        p = jnp.exp(s - lse_ref[:, at])
+        if sel_ref is not None:         # the selection, transposed
+            p = jnp.where(sel_ref[:, at] != 0, jnp.exp(s - lse_ref[:, at]),
+                          0.0)
+        else:
+            if diagonal:
+                s = jnp.where(_below_diagonal(block, True), s, _MASK)
+            p = jnp.exp(s - lse_ref[:, at])
         dv = dv + jnp.dot(p.astype(jnp.bfloat16), doi,
                           preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(vb, doi, _NT,
@@ -181,6 +232,10 @@ def _backward_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=VMEM_LIMIT)
+# the same with the query heads of a key head innermost
+_PARAMS_GROUPED = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _head(t, width):
@@ -194,73 +249,123 @@ def _rows(block, width):
                         lambda b, h, i: (b, h, i, 0))
 
 
-def _forward(q, k, v, block, interpret):
-    """``q``/``k`` [B, H, T, dk], ``v`` [B, H, T, dv] ->
-    (out [B, H, T, dv], lse [B, H, 1, T])."""
+def _forward(q, k, v, selected, block, interpret):
+    """``q`` [B, H, T, dk], ``k`` [B, Hkv, T, dk], ``v`` [B, Hkv, T, dv],
+    ``selected`` None or [B, T, T] bool -> (out [B, H, T, dv], lse [B, H,
+    1, T])."""
     b, h, t, dk = q.shape
-    dv = v.shape[-1]
+    kv, dv = k.shape[1], v.shape[-1]
+    g = h // kv
+    args = [q, k, v]
+    if selected is None and g == 1:
+        grid, params = (b, h, t // block), _PARAMS
+        rows = functools.partial(_rows, block)
+        head = functools.partial(_head, t)
+        lse = pl.BlockSpec((None, None, 1, block),
+                           lambda b, h, i: (b, h, 0, i))
+    else:
+        # grid (sequence, key head c, query block i, head r of c's
+        # group): query head c * g + r
+        grid, params = (b, kv, t // block, g), _PARAMS_GROUPED
+
+        def rows(width):
+            return pl.BlockSpec((None, None, block, width),
+                                lambda b, c, i, r: (b, c * g + r, i, 0))
+
+        def head(width):
+            return pl.BlockSpec((None, None, t, width),
+                                lambda b, c, i, r: (b, c, 0, 0))
+        lse = pl.BlockSpec((None, None, 1, block),
+                           lambda b, c, i, r: (b, c * g + r, 0, i))
+    in_specs = [rows(dk), head(dk), head(dv)]
+    if selected is not None:
+        # a query block's row of the selection, fetched as i moves
+        in_specs.append(pl.BlockSpec((None, block, t),
+                                     lambda b, c, i, r: (b, i, 0)))
+        args.append(selected.astype(jnp.int8))
     return pl.pallas_call(
         functools.partial(_forward_kernel, scale=1.0 / math.sqrt(dk),
-                          block=block),
-        grid=(b, h, t // block),
-        in_specs=[_rows(block, dk), _head(t, dk), _head(t, dv)],
-        out_specs=[_rows(block, dv),
-                   pl.BlockSpec((None, None, 1, block),
-                                lambda b, h, i: (b, h, 0, i))],
+                          block=block, selected=selected is not None,
+                          heads_inner=len(grid) == 4),
+        grid=grid, in_specs=in_specs, out_specs=[rows(dv), lse],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((t, dk), jnp.bfloat16),
                         pltpu.VMEM((t, dv), jnp.bfloat16)],
-        compiler_params=_PARAMS, interpret=interpret,
-        name="latent_attention_forward")(q, k, v)
+        compiler_params=params, interpret=interpret,
+        name=f"{kernel_name(g, selected)}_forward")(*args)
 
 
-def _backward(q, k, v, lse, delta, do, block, interpret):
+def _backward(q, k, v, lse, delta, do, selected, block, interpret):
     """-> (dq, dk, dv), each as its primal."""
     b, h, t, dk = q.shape
-    dv = v.shape[-1]
+    kv, dv = k.shape[1], v.shape[-1]
+    g = h // kv
     row = _head(1, t)       # the head's log-sum-exp, its delta
-    return pl.pallas_call(
+    if g == 1:
+        kv_rows = functools.partial(_rows, block)
+    else:
+        def kv_rows(width):     # query head h's key block of head h // g
+            return pl.BlockSpec((None, None, block, width),
+                                lambda b, h, j: (b, h // g, j, 0))
+    in_specs = [_head(t, dk), _head(t, dv), kv_rows(dk), kv_rows(dv), row,
+                row]
+    args = [q, do, k, v, lse, delta]
+    if selected is not None:
+        # a key block's row of the selection transposed, [keys, queries]
+        in_specs.append(pl.BlockSpec((None, block, t),
+                                     lambda b, h, j: (b, j, 0)))
+        args.append(jnp.swapaxes(selected, 1, 2).astype(jnp.int8))
+    dq, dk_h, dv_h = pl.pallas_call(
         functools.partial(_backward_kernel, scale=1.0 / math.sqrt(dk),
-                          block=block),
+                          block=block, selected=selected is not None),
         grid=(b, h, t // block),
-        in_specs=[_head(t, dk), _head(t, dv), _rows(block, dk),
-                  _rows(block, dv), row, row],
+        in_specs=in_specs,
         out_specs=[_head(t, dk), _rows(block, dk), _rows(block, dv)],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32)
-                   for x in (q, k, v)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), jnp.float32)
+                   for d in (dk, dk, dv)],
         scratch_shapes=[pltpu.VMEM((t, dk), jnp.bfloat16),
                         pltpu.VMEM((t, dv), jnp.bfloat16)],
         compiler_params=_PARAMS, interpret=interpret,
-        name="latent_attention_backward")(q, do, k, v, lse, delta)
+        name=f"{kernel_name(g, selected)}_backward")(*args)
+    if g == 1:
+        return dq, dk_h, dv_h
+    # each query head's share of its key head's gradients, summed
+    return (dq,) + tuple(x.reshape(b, kv, g, t, -1).sum(axis=2)
+                         for x in (dk_h, dv_h))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _core(q, k, v, block, interpret):
-    return _forward(q, k, v, block, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _core(q, k, v, selected, block, interpret):
+    return _forward(q, k, v, selected, block, interpret)[0]
 
 
-def _core_fwd(q, k, v, block, interpret):
-    out, lse = _forward(q, k, v, block, interpret)
+def _core_fwd(q, k, v, selected, block, interpret):
+    out, lse = _forward(q, k, v, selected, block, interpret)
     out, lse = (checkpoint_name(x, n) for x, n in zip((out, lse), SAVED))
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, selected, out, lse)
 
 
 def _core_bwd(block, interpret, saved, do):
-    q, k, v, out, lse = saved
+    q, k, v, selected, out, lse = saved
     delta = jnp.sum(do * out, axis=-1)[:, :, None, :]
-    return _backward(q, k, v, lse, delta, do, block, interpret)
+    # the selection is integers: no cotangent
+    return _backward(q, k, v, lse, delta, do, selected, block,
+                     interpret) + (None,)
 
 
 _core.defvjp(_core_fwd, _core_bwd)
 
 
-def fused_causal_attention(q, k, v, *, block: int = BLOCK,
+def fused_causal_attention(q, k, v, selected=None, *, block: int = BLOCK,
                            interpret: bool = False):
-    """Causal softmax attention of ``q``/``k`` [B, T, H, dk] and ``v``
-    [B, T, H, dv] at positions 0..T-1 -> [B, T, H, dv], through the
-    kernels.  ``T`` a whole number of ``block``s and both widths multiples
-    of 128 (`admits` checks it for `BLOCK`)."""
+    """Causal softmax attention of ``q`` [B, T, H, dk], ``k`` [B, T, Hkv,
+    dk] and ``v`` [B, T, Hkv, dv] at positions 0..T-1 -> [B, T, H, dv],
+    query head ``h`` reading key/value head ``h // (H / Hkv)``, through the
+    kernels; ``selected`` [B, T, T] bool, where given, the keys each query
+    sees (the module docstring's precondition holds).  ``T`` a whole
+    number of ``block``s and both widths multiples of 128 (`admits` checks
+    it for `BLOCK`)."""
     heads_first = lambda x: x.transpose(0, 2, 1, 3)
     return heads_first(_core(heads_first(q), heads_first(k), heads_first(v),
-                             block, interpret))
+                             selected, block, interpret))

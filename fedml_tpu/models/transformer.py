@@ -322,13 +322,13 @@ def rotary(x, positions, theta: float, sections=None):
 
 def fused_core_fits(q, k, v, selected=None) -> bool:
     """Whether `causal_blocked_attention` hands these to the fused kernels
-    (`models.fused_attention`): on a TPU, every causal key seen (no
-    selection), as many key heads as query heads, and float32 with a
-    sequence that is a whole number of the kernels' blocks, head widths
-    that are multiples of 128 and a head that fits in VMEM."""
+    (`models.fused_attention`): on a TPU, with or without a selection,
+    key heads that divide the query heads, and float32 with a sequence
+    that is a whole number of the kernels' blocks, head widths that are
+    multiples of 128 and a head that fits in VMEM."""
     from fedml_tpu.models import fused_attention
-    return (jax.default_backend() == "tpu" and selected is None
-            and k.shape[2] == q.shape[2] and fused_attention.admits(q, k, v))
+    return (jax.default_backend() == "tpu"
+            and fused_attention.admits(q, k, v, selected))
 
 
 def causal_blocked_attention(q, k, v, block: Optional[int] = None,
@@ -336,18 +336,23 @@ def causal_blocked_attention(q, k, v, block: Optional[int] = None,
     """Causal softmax attention, ``q`` [B, T, H, dk], ``k`` [B, T, Hkv, dk]
     and ``v`` [B, T, Hkv, dv] at positions 0..T-1; query head ``h`` reads
     key/value head ``h // (H / Hkv)``.  ``selected`` [B, T, T] bool, where
-    given, says which keys each query sees (a subset of its causal past:
-    `models.indexed_attention`); None is all of it.  One algorithm, and
-    the inputs say which implementation of it runs (`fused_core_fits`):
+    given, says which keys each query sees (a subset of its causal past
+    in which every row selects a key: `models.indexed_attention`); None is
+    all of it.  One algorithm, and the inputs say which implementation of
+    it runs (`fused_core_fits`):
 
-    * on a TPU, with no selection and ``Hkv == H``, for float32 inputs
-      whose ``T`` is a whole number of 512, whose ``dk`` and ``dv`` are
-      multiples of 128 and whose head fits in VMEM (``T * max(dk, dv) <=
-      8192 * 256``): the fused Pallas kernels of `models.fused_attention`,
-      one a pass, scores and probabilities in VMEM only, the log-sum-exp
-      saved for the backward pass; ``block`` plays no part there;
+    * on a TPU, for float32 inputs whose ``T`` is a whole number of 512,
+      whose ``dk`` and ``dv`` are multiples of 128, whose ``Hkv`` divides
+      ``H`` and whose head fits in VMEM (``T * max(dk, dv) <= 8192 *
+      256``), with a selection or without: the fused Pallas kernels of
+      `models.fused_attention`, one a pass, scores and probabilities in
+      VMEM only, the log-sum-exp saved for the backward pass; without a
+      selection and with ``Hkv == H`` its ``latent_attention`` kernels,
+      else its ``selected_attention`` ones (the selection a mask on every
+      score tile, a key head read by its group of query heads through the
+      index maps); ``block`` plays no part there;
     * anywhere else (the CPU, another dtype, a ragged or short ``T``, a
-      narrow head, a selection, grouped key heads): XLA, one block of
+      narrow head, a head too long for VMEM): XLA, one block of
       ``block`` queries at a time against the keys up to its last
       position: the scores held at once are [B, H, block, <= T], the
       selection is a mask on them, and the blocks wholly above the
@@ -358,7 +363,9 @@ def causal_blocked_attention(q, k, v, block: Optional[int] = None,
         from fedml_tpu.core.pallas_agg import pallas_interpret
         from fedml_tpu.models import fused_attention
         return fused_attention.fused_causal_attention(
-            q, k, v, interpret=pallas_interpret(fused_attention.KERNEL))
+            q, k, v, selected, interpret=pallas_interpret(
+                fused_attention.kernel_name(q.shape[2] // k.shape[2],
+                                            selected)))
     return _xla_blocked_attention(q, k, v, block, selected)
 
 

@@ -222,13 +222,30 @@ def test_compile_watch_counts_backend_compiles():
     assert json.dumps(after)  # plain numbers: printable as a fact
 
 
-def test_the_selected_phase_runs_at_a_tiny_size_on_the_cpu(capsys):
+def test_the_selected_phase_runs_at_a_tiny_size_on_the_cpu(capsys,
+                                                            monkeypatch):
     """`chip_smoke.phase_selected` is the chip's check of the indexed
     attention at its published widths; here its control flow at the tiny
-    configuration's (no platform is asserted inside it)."""
+    configuration's (no platform is asserted inside it).  The kernels'
+    check it ends with needs the chip (the tiny heads are too narrow for
+    them): here it is handed the selection the phase made, and
+    `tests/test_fused_attention.py` runs the same comparison through the
+    interpreter."""
     import chip_smoke
+    from unittest import mock
+    check = mock.Mock()
+    monkeypatch.setattr(chip_smoke, "check_selected_kernels", check)
+    kernels = object()
     chip_smoke.phase_selected(
-        "benchmark/tests/tiny/models/keye_vl2_30b_a3b.json", t=64, block=16)
+        kernels, "benchmark/tests/tiny/models/keye_vl2_30b_a3b.json", t=64,
+        block=16)
     said = capsys.readouterr().out
     assert "program vs plain reference" in said
     assert said.count("pairs chosen by one and not the other") == 2
+    tiny = json.load(open(REPO / "benchmark" / "tests" / "tiny" / "models"
+                          / "keye_vl2_30b_a3b.json"))
+    (got, selected, *widths, block), _ = check.call_args
+    assert got is kernels and selected.shape == (1, 64, 64)
+    assert widths == [tiny["num_attention_heads"],
+                      tiny["num_key_value_heads"], tiny["head_dim"]]
+    assert block == 16 and selected.dtype == bool
