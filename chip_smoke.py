@@ -44,11 +44,11 @@ platform is set, no ``--platform cpu`` is passed, nothing is interpreted,
 and nothing is skipped except ``mesh`` on a host with fewer than 4 chips.
 
 What it prints are set-up facts, not speeds: the device as JAX reports it,
-each phase's wall seconds, seconds spent compiling and number of
-compilations (every backend compile of the process, from JAX's monitoring
-events; for the round phase also the named compile ledger of
-`fedml_tpu.obs.device`), and the persistent compile cache's hits and
-misses.  The last line of stdout is one JSON object, ``{"ok": true,
+each phase's wall seconds, seconds spent tracing, lowering and compiling
+and number of compilations (every compile of the process, from the
+program's own account, `fedml_tpu.obs.trace.compile_totals`; for the
+round phase also the named compile ledger of `fedml_tpu.obs.device`), and
+the persistent compile cache's hits and misses.  The last line of stdout is one JSON object, ``{"ok": true,
 "device": {...}}``; the exit code is 0 only then.
 """
 
@@ -58,7 +58,6 @@ import logging
 import os
 import sys
 import tempfile
-import threading
 import time
 
 # the one platform this script accepts; every placement assertion below
@@ -98,63 +97,33 @@ class KernelPaths(logging.Handler):
             f"the compiled path")
 
 
-class CompileWatch:
-    """Every backend compile of the process, from JAX's own monitoring
-    events: count, seconds, and the persistent cache's hits and misses.
-    A cache hit still counts as a (near-zero-second) compile; JAX records
-    a miss only when it WRITES the entry.  ``snapshot()`` returns running
-    totals — diff two around a phase.  Listeners cannot be unregistered:
-    one per process."""
+class Phases:
+    """Per-phase wall / compile accounting over the program's own
+    compile totals (`fedml_tpu.obs.trace.compile_totals`: a persistent
+    cache hit still counts as a near-zero-second compile, and JAX
+    records a miss only when it WRITES the entry)."""
+
+    KEYS = ("compiles", "trace_s", "lower_s", "compile_s", "cache_hits",
+            "cache_misses")
 
     def __init__(self):
-        import jax.monitoring as monitoring
-        self._lock = threading.Lock()
-        self._durations = []
-        self._hits = self._misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self._durations.append(float(secs))
-
-    def _on_event(self, event, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self._hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            with self._lock:
-                self._misses += 1
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return {"compiles": len(self._durations),
-                    "compile_s": sum(self._durations),
-                    "cache_hits": self._hits,
-                    "cache_misses": self._misses,
-                    "durations": list(self._durations)}
-
-
-class Phases:
-    """Per-phase wall / compile accounting over one `CompileWatch`."""
-
-    def __init__(self, watch):
-        self.watch = watch
+        from fedml_tpu.obs.trace import compile_totals
+        self.totals = compile_totals
         self.rows = []
 
     @contextlib.contextmanager
     def __call__(self, name: str):
         say(f"== phase {name}")
-        before, t0 = self.watch.snapshot(), time.perf_counter()
+        before, t0 = self.totals(), time.perf_counter()
         yield
-        wall, after = time.perf_counter() - t0, self.watch.snapshot()
+        wall, after = time.perf_counter() - t0, self.totals()
         row = {"phase": name, "wall_s": round(wall, 1),
-               **{k: after[k] - before[k] for k in
-                  ("compiles", "compile_s", "cache_hits", "cache_misses")}}
-        row["compile_s"] = round(row["compile_s"], 1)
+               **{k: after[k] - before[k] for k in self.KEYS}}
+        for k in ("trace_s", "lower_s", "compile_s"):
+            row[k] = round(row[k], 1)
         self.rows.append(row)
-        say(f"== phase {name}: wall {row['wall_s']} s, compiling "
+        say(f"== phase {name}: wall {row['wall_s']} s, tracing "
+            f"{row['trace_s']} s, lowering {row['lower_s']} s, compiling "
             f"{row['compile_s']} s in {row['compiles']} compilations, "
             f"persistent cache {row['cache_hits']} hits / "
             f"{row['cache_misses']} misses (written)")
@@ -695,7 +664,7 @@ def main() -> int:
         f"{compile_cache_dir() or os.environ['JAX_COMPILATION_CACHE_DIR']}"
         f" (min compile time "
         f"{jax.config.jax_persistent_cache_min_compile_time_secs} s)")
-    kernels, phases = KernelPaths(), Phases(CompileWatch())
+    kernels, phases = KernelPaths(), Phases()
 
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
         with phases("round"):
@@ -714,19 +683,12 @@ def main() -> int:
         else:
             say(f"mesh: not run ({len(devices)} device)")
 
-    total = phases.watch.snapshot()
-    say("phase       wall_s  compile_s  compiles  cache_hits  cache_misses")
+    say("phase       wall_s  trace_s  lower_s  compile_s  compiles  "
+        "cache_hits  cache_misses")
     for r in phases.rows:
-        say(f"{r['phase']:<10} {r['wall_s']:>7} {r['compile_s']:>10} "
-            f"{r['compiles']:>9} {r['cache_hits']:>11} "
-            f"{r['cache_misses']:>13}")
-    # which compiles a cache floor would keep out: count and seconds by
-    # how long each took (a hit is a near-zero "compile")
-    d = total["durations"]
-    for lo, hi in ((0, 1), (1, 5), (5, float("inf"))):
-        took = [x for x in d if lo <= x < hi]
-        say(f"compiles taking [{lo}, {hi}) s: {len(took)} totalling "
-            f"{sum(took):.1f} s")
+        say(f"{r['phase']:<10} {r['wall_s']:>7} {r['trace_s']:>8} "
+            f"{r['lower_s']:>8} {r['compile_s']:>10} {r['compiles']:>9} "
+            f"{r['cache_hits']:>11} {r['cache_misses']:>13}")
     say(f"kernel paths: { {k: sorted(v) for k, v in kernels.paths.items()} }")
     say(json.dumps({"ok": True,
                     "device": {"platform": dev.platform,

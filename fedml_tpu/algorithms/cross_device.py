@@ -704,7 +704,7 @@ class CrossDevice(FedAvg):
         the run's end): forty transfers started in front of a wave
         program's launch held it back 0.1-0.2 s (my chip run, PR 37)."""
         if self._crc_job is not None:
-            self._crc_job.result()
+            self._join_crc()
         self._crc_go.clear()
         if self._crc_pool is None:
             self._crc_pool = ThreadPoolExecutor(
@@ -713,16 +713,28 @@ class CrossDevice(FedAvg):
                                               self._round_ctx)
         return self._crc_job
 
+    def _join_crc(self) -> int:
+        """Wait for the CRC job in flight under `round.crc_join`: the
+        main thread's wait on the worker (``waited`` 0 where the job had
+        ended already).  Re-raises what the job raised."""
+        job = self._crc_job
+        with self._span("round.crc_join", wait="worker", joins=1,
+                        waited=int(not job.done())):
+            return job.result()
+
     def _crc_of(self, params, round_ctx) -> int:
         """On the worker.  Reads ``params``, which no round writes."""
         from fedml_tpu.utils.journal import tree_crc
         self._crc_go.wait(10.0)
         # explicit parent, as `stage.prefetch`'s: the round it closes
-        with self._span("round.crc", parent=round_ctx):
+        with self._span("round.crc", parent=round_ctx) as crc_sp:
             # one batched transfer, every leaf's started before any is
             # awaited; the CRC reads each leaf as it lands
-            for leaf in jax.tree.leaves(params):
+            leaves = jax.tree.leaves(params)
+            for leaf in leaves:
                 leaf.copy_to_host_async()
+            if self._tracer is not None:
+                crc_sp.set(bytes=sum(leaf.nbytes for leaf in leaves))
             crc = tree_crc(params)
             if self.health is not None or self._wave_attacks:
                 # kept only for a reader: the pair holds the device
@@ -761,7 +773,7 @@ class CrossDevice(FedAvg):
         needs_host = self.health is not None or bool(self._wave_attacks)
         if needs_host and self._crc_job is not None:
             self._crc_go.set()          # no launch to wait behind: now
-            self._crc_job.result()      # the worker may still be at it
+            self._join_crc()            # the worker may still be at it
         mirror, self._mirror = self._mirror, None
         host_params = (mirror[1] if mirror is not None
                        and mirror[0] is params else None)
@@ -948,7 +960,7 @@ class CrossDevice(FedAvg):
                                               round_sp, checkpointer)
             self._crc_go.set()
             if self._crc_job is not None:
-                self._crc_job.result()      # the last round's; re-raises
+                self._join_crc()            # the last round's; re-raises
         finally:
             # the last round stages nothing; a round that raised may have
             self._stop_staging()

@@ -390,6 +390,9 @@ class PerfRecorder:
             self._trace_path = os.path.join(d, "trace.json")
             if os.path.exists(self._trace_path):
                 os.replace(self._trace_path, self._trace_path + ".prev")
+        # what the compiler takes while this recorder is open: `jit.*`
+        # spans under the site that paid them, or roots on this tracer
+        _trace.watch_compiles(self.tracer)
 
     # -- registration --------------------------------------------------------
     def register_jit(self, name: str, fn) -> bool:
@@ -569,6 +572,7 @@ class PerfRecorder:
         if self._closed:
             return
         self._closed = True
+        _trace.watch_compiles(None, since=self.tracer)
         if self._late is not None:
             self._late.exception()      # waits; the callback has written
         self.rss.stop()

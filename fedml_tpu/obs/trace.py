@@ -27,6 +27,12 @@ enters a ``jax.profiler.TraceAnnotation`` of the same name, so whenever
 a profiler session is open the program's spans lie on the trace's host
 plane beside the device's ops (a flag check when none is).
 
+Compiles: the process's one JAX-monitoring listener (`watch_compiles`,
+`compile_totals`) records each stage of a compile as a span (``jit.trace``,
+``jit.lower``, ``jit.compile``) under the `TimedSpan` open on the thread
+that paid it, and keeps the process's compile totals.  A run that compiles
+nothing pays nothing.
+
 Duplicate tolerance: a chaotic wire can deliver one frame twice.  Spans
 created with ``deterministic=True`` derive their span id from
 ``(trace_id, parent_id, name, node)``, and the tracer records the FIRST
@@ -235,19 +241,19 @@ class SpanTracer:
     def record_span(self, name: str, dur_s: float,
                     t0_ns: Optional[int] = None, parent=None,
                     trace_id: Optional[str] = None, node=None,
-                    **args) -> None:
+                    span_id: Optional[str] = None, **args) -> None:
         """Record an already-finished span retroactively: the hot-path
         form for schedulers that know a phase's duration only after it
         ran (serve queue wait, batch execution, decode steps) — one call
         per event, no context-manager entry on the critical path.
         ``t0_ns`` defaults to ``now - dur_s`` on this tracer's clock;
         pass a Span/SpanContext as ``parent`` to hang it under a
-        request."""
+        request, and ``span_id`` where children already name it."""
         if isinstance(parent, Span):
             parent = parent.context
         dur_ns = int(dur_s * 1e9)
         sp = self.start_span(name, parent=parent, trace_id=trace_id,
-                             node=node, **args)
+                             node=node, span_id=span_id, **args)
         sp.t0 = self._clock() - dur_ns if t0_ns is None else t0_ns
         sp._ended = True
         self._record(sp, dur_ns)
@@ -397,6 +403,158 @@ def child(name: str, phase: Optional[str] = None, hist=None):
     if hist is not None:
         return TimedSpan(None, name, hist=hist)
     return NULL_CONTEXT
+
+
+# -- the compiler's time, as spans (ISSUE 41) ----------------------------------
+
+# JAX's monitoring events of the three stages of a compile -> span name
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+# the persistent cache's verdict, seen inside a backend compile -> its arg
+_CACHE_VERDICTS = {"/jax/compilation_cache/cache_hits": "cache_hit",
+                   "/jax/compilation_cache/cache_misses": "cache_miss"}
+_TOTAL_OF = {"jit.trace": "trace_s", "jit.lower": "lower_s",
+             "jit.compile": "compile_s"}
+
+
+class _CompileWatch:
+    """The process's one JAX-monitoring listener.  Each stage of a
+    compile becomes a span (`COMPILE_SPANS`) under the `TimedSpan` site
+    open on the compiling thread, else a root span of the open
+    recorder's tracer (`watch_compiles`), else none; the process totals
+    count either way.  A stage starts at JAX's start marker (a scalar
+    event at entry) and ends at its duration event, both read on
+    ``perf_counter_ns`` here; a stage that runs inside another (an inner
+    jit traced inside the outer's trace) is that one's child, so a
+    thread's leaf spans never overlap.  `jit.compile` says whether the
+    persistent cache answered (``cache_hit``) or was written
+    (``cache_miss``): both 0 where no cache is on.  Listeners cannot be
+    unregistered: one per process, made by `compile_totals` or a
+    recorder, whichever comes first."""
+
+    def __init__(self):
+        import jax.monitoring as monitoring
+        from jax.profiler import TraceAnnotation
+        self.annotate = TraceAnnotation
+        self.lock = threading.Lock()
+        self.totals = {"compiles": 0, "compile_s": 0.0, "trace_s": 0.0,
+                       "lower_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+        self.tracer: Optional[SpanTracer] = None   # the open recorder's
+        self.local = threading.local()   # .open: stages begun, innermost
+        #                                  last; .verdict: the cache's
+        self.ids = itertools.count()
+        self.nonce = f"{os.getpid():x}.jit"
+        monitoring.register_scalar_listener(self._on_start)
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_end)
+
+    def _open(self) -> list:
+        stack = getattr(self.local, "open", None)
+        if stack is None:
+            stack = self.local.open = []
+        return stack
+
+    def _on_start(self, event, value, **_kw) -> None:
+        name = COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        # on the profiler's host plane too, as a `TimedSpan` is
+        note = self.annotate(name)
+        note.__enter__()
+        self._open().append({"event": event, "note": note,
+                             "t0": time.perf_counter_ns(),
+                             "id": f"{self.nonce}.{next(self.ids)}"})
+        if name == "jit.compile":
+            self.local.verdict = {}
+
+    def _on_event(self, event, **_kw) -> None:
+        arg = _CACHE_VERDICTS.get(event)
+        if arg is not None:
+            with self.lock:     # the event's last part: `cache_hits`
+                self.totals[event.rsplit("/", 1)[1]] += 1
+            verdict = getattr(self.local, "verdict", None)
+            if verdict is None:
+                verdict = self.local.verdict = {}
+            verdict[arg] = 1
+
+    def _on_end(self, event, secs, fun_name="", **_kw) -> None:
+        name = COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        t1 = time.perf_counter_ns()
+        stack = self._open()
+        if stack and stack[-1]["event"] == event:
+            own = stack.pop()
+            own["note"].__exit__(None, None, None)
+        else:   # no start seen (the listener came mid-compile)
+            own = {"t0": t1 - int(secs * 1e9),
+                   "id": f"{self.nonce}.{next(self.ids)}"}
+        with self.lock:
+            self.totals[_TOTAL_OF[name]] += float(secs)
+            if name == "jit.compile":
+                self.totals["compiles"] += 1
+        args = {"fun": fun_name}
+        if name == "jit.compile":
+            verdict = getattr(self.local, "verdict", None) or {}
+            self.local.verdict = {}
+            args.update(cache_hit=verdict.get("cache_hit", 0),
+                        cache_miss=verdict.get("cache_miss", 0))
+        site = getattr(_ambient, "site", None)
+        tracer = site._tracer if site is not None else self.tracer
+        if tracer is None:
+            return
+        # one trace id for a nest of stages: the site's, else one the
+        # outermost stage keeps for all of them
+        root = stack[0] if stack else own
+        if site is not None:
+            trace_id = site.span.trace_id
+        else:
+            if "trace" not in root:
+                root["trace"] = tracer.new_trace_id()
+            trace_id = root["trace"]
+        parent = (SpanContext(trace_id, stack[-1]["id"]) if stack
+                  else site.span if site is not None else None)
+        tracer.record_span(name, (t1 - own["t0"]) / 1e9, t0_ns=own["t0"],
+                           parent=parent, trace_id=trace_id,
+                           span_id=own["id"], **args)
+
+
+_compile_watch: Optional[_CompileWatch] = None
+_compile_watch_lock = threading.Lock()
+
+
+def _watch() -> _CompileWatch:
+    global _compile_watch
+    with _compile_watch_lock:
+        if _compile_watch is None:
+            _compile_watch = _CompileWatch()
+        return _compile_watch
+
+
+def watch_compiles(tracer: Optional[SpanTracer],
+                   since: Optional[SpanTracer] = None) -> None:
+    """Make ``tracer`` the one a compile outside any site records its
+    spans on (None: no spans), making the listener if there is none yet.
+    With ``since``, only while that tracer holds it: a recorder that
+    closes hands back its own, never another recorder's."""
+    watch = _watch()
+    with watch.lock:
+        if since is None or watch.tracer is since:
+            watch.tracer = tracer
+
+
+def compile_totals() -> dict:
+    """The process's compile account since its listener was made:
+    ``compiles`` (backend compiles, a persistent-cache hit among them),
+    ``compile_s``, ``trace_s``, ``lower_s``, ``cache_hits`` and
+    ``cache_misses`` (entries written).  Running totals: diff two
+    around a phase."""
+    watch = _watch()
+    with watch.lock:
+        return dict(watch.totals)
 
 
 def _node_pid(node) -> int:

@@ -209,16 +209,24 @@ def test_cifar_twin_honours_client_num_in_total():
 
 # -- the process-wide compile account ----------------------------------------
 
-def test_compile_watch_counts_backend_compiles():
+def test_compile_totals_count_backend_compiles():
+    """`chip_smoke.Phases` reads the program's own compile account
+    (chip_smoke keeps no listener of its own)."""
     import jax.numpy as jnp
-    from chip_smoke import CompileWatch
-    watch = CompileWatch()
-    before = watch.snapshot()
-    jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)).block_until_ready()
-    after = watch.snapshot()
+    import chip_smoke
+    from fedml_tpu.obs.trace import compile_totals
+    phases = chip_smoke.Phases()
+    before = compile_totals()
+    with phases("tiny"):
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)).block_until_ready()
+    after = compile_totals()
     assert after["compiles"] > before["compiles"]
     assert after["compile_s"] > before["compile_s"]
-    assert len(after["durations"]) == after["compiles"]
+    assert after["trace_s"] > before["trace_s"]
+    assert after["lower_s"] > before["lower_s"]
+    [row] = phases.rows
+    assert row["compiles"] == after["compiles"] - before["compiles"]
+    assert not hasattr(chip_smoke, "CompileWatch")
     assert json.dumps(after)  # plain numbers: printable as a fact
 
 

@@ -126,7 +126,11 @@ def test_span_names_of_a_round_are_the_contract(cli_run):
                 ("round", "round.ledger")}
         if cli_run["pipelined"]:
             want.add(("round", "fold.drain"))
+        if round_idx > 0:
+            # the main thread's wait on the last round's CRC job
+            want.add(("round", "round.crc_join"))
         assert want <= paths, want - paths
+        assert (("round", "round.crc_join") in paths) == (round_idx > 0)
         for e in members:
             assert (e["args"].get("wait") == "device") \
                 == (e["name"] in WAIT_SPANS), e["name"]
@@ -228,6 +232,15 @@ def test_each_ledger_phase_is_the_sum_of_its_spans(cli_run):
         assert ("barrier_wait" in tagged) == cli_run["pipelined"]
         for phase, ns in tagged.items():
             assert abs(line["phases"][phase] - ns / 1e9) <= 0.51e-6, phase
+
+
+def test_crc_span_counts_the_globals_bytes(cli_run):
+    """`round.crc` carries the global's bytes: the span table's bytes a
+    round beside its seconds give the copy's rate (lr on MNIST: 784 x 10
+    weights and 10 biases in f32)."""
+    crcs = [e for e in cli_run["events"] if e["name"] == "round.crc"]
+    assert len(crcs) == 2
+    assert {e["args"]["bytes"] for e in crcs} == {(784 * 10 + 10) * 4}
 
 
 def test_counts_ride_the_staging_spans(cli_run):
@@ -445,6 +458,47 @@ def test_spans_are_profiler_annotations_on_the_host_plane(workload, data,
     assert {"round", "wave", "stage.prefetch", "stage.gather", "stage.put",
             "wave.dispatch", "wave.wait", "fold_wave", "finalize.dispatch",
             "round.sync"} <= names
+
+
+@pytest.mark.parametrize("held", [True, False])
+def test_crc_join_says_whether_the_main_thread_waited(workload, data,
+                                                      tmp_path, held):
+    """`round.crc_join`: ``waited`` 1 where the worker still held the job
+    (a stub `_crc_of` on a gate), 0 where it had ended; no ledger phase,
+    and not a wait on the device."""
+    import threading
+
+    class OpenedByTheJoin:
+        """The job, whose gate opens only once the join waits on it."""
+        def __init__(self, job):
+            self.job = job
+
+        def done(self):
+            return self.job.done()
+
+        def result(self):
+            gate.set()
+            return self.job.result()
+
+    perf = PerfRecorder(str(tmp_path / "perf.jsonl"))
+    gate = threading.Event()
+    try:
+        eng = CrossDevice(workload, data, _cfg(), perf=perf)
+        eng._crc_of = lambda params, ctx: gate.wait(10.0) and 7
+        job = eng._start_crc({})
+        if held:
+            eng._crc_job = OpenedByTheJoin(job)
+        else:
+            gate.set()
+            job.exception()                     # ended before the join
+        assert eng._join_crc() == 7
+        eng._stop_staging()
+    finally:
+        gate.set()
+        perf.close()
+    [join] = [s for s in perf.tracer.spans if s["name"] == "round.crc_join"]
+    assert join["args"] == {"wait": "worker", "joins": 1,
+                            "waited": int(held)}
 
 
 def test_sites_without_a_recorder_keep_nothing(workload, data):
