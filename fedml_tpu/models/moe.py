@@ -173,10 +173,12 @@ def plan_held_tiles(chosen, first_held: int, held: int, tile: int):
     run of rows an expert, each padded to a whole number of ``tile`` rows,
     so a tile belongs to one expert; its length ``L`` is the worst case
     (every choice of every token held) and only the first ``n_active``
-    tiles hold a row.  Returns ``dest`` [N, k] (row of each choice, ``L``
-    for a choice whose expert is not held), ``row_token`` [L] (the token
-    in each row, ``N`` for a padding row), ``tile_expert`` [L / tile],
-    ``n_active`` and ``counts`` [held] (tokens each held expert takes)."""
+    tiles hold a row.  The layout is never built: it is a list of indices.
+    Returns ``row_choice`` [L] (the flat index ``token * k + choice`` of
+    each row's choice, ``N * k`` for a padding row, so ``row_choice // k``
+    is the row's token and ``N`` a padding row's), ``tile_expert``
+    [L / tile], ``n_active`` and ``counts`` [held] (tokens each held expert
+    takes)."""
     n, k = chosen.shape
     cap = n * min(k, held)
     length = -(-cap // tile) * tile + held * tile
@@ -192,14 +194,13 @@ def plan_held_tiles(chosen, first_held: int, held: int, tile: int):
     starts = ends - padded
     dest = jnp.where(is_held,
                      starts[jnp.clip(local, 0, held - 1)] + rank, length)
-    token = jnp.arange(n * k, dtype=jnp.int32) // k
-    row_token = jnp.full((length + 1,), n, jnp.int32).at[dest].set(
-        token)[:length]
+    row_choice = jnp.full((length + 1,), n * k, jnp.int32).at[dest].set(
+        jnp.arange(n * k, dtype=jnp.int32))[:length]
     tile_expert = jnp.clip(jnp.searchsorted(
         ends, jnp.arange(length // tile) * tile, side="right"),
         0, held - 1).astype(jnp.int32)
-    return (dest.reshape(n, k), row_token, tile_expert,
-            (ends[-1] // tile).astype(jnp.int32), counts)
+    return (row_choice, tile_expert, (ends[-1] // tile).astype(jnp.int32),
+            counts)
 
 
 def _silu_gate(xt, wg, wu):
@@ -209,70 +210,97 @@ def _silu_gate(xt, wg, wu):
 
 
 @jax.custom_vjp
-def grouped_gated_mlp(xp, wg, wu, wd, row_token, tile_expert, n_active):
-    """The grouped product over the experts held: ``rows[j] =
-    W_down[e](silu(W_gate[e] x) * W_up[e] x)`` for every tile ``j`` of
-    ``plan_held_tiles``' layout, ``e`` the tile's expert, ``x`` the rows
-    of ``xp`` [N + 1, d] (its last row zeros, what a padding row reads)
-    the tile names.  Only the ``n_active`` tiles that hold a row are
-    computed: a loop with a data-dependent trip count, which is why the
-    gradient is written out below and not traced.  Returns [L + 1, d],
-    the last row zeros (what a choice that is not held reads)."""
-    return _grouped_fwd_rows(xp, wg, wu, wd, row_token, tile_expert,
-                             n_active)
+def grouped_gated_mlp(xp, wg, wu, wd, row_weight, row_token, tile_expert,
+                      n_active):
+    """The grouped product over the experts held, summed into the tokens:
+    ``y[t] = sum_j row_weight[j] W_down[e](silu(W_gate[e] x_t) * W_up[e]
+    x_t)`` over the rows ``j`` of `plan_held_tiles`' layout whose token is
+    ``t``, ``e`` the expert of ``j``'s tile, ``x`` the rows of ``xp``
+    [N + 1, d] (its last row zeros, what a padding row reads).  A tile's
+    results are added into its tokens as it is computed, so no array of
+    the layout's length and the model's width exists.  Only the
+    ``n_active`` tiles that hold a row are computed: a loop with a
+    data-dependent trip count, which is why the gradient is written out
+    below and not traced.  Returns [N + 1, d]; a padding row's weight is
+    0, so the last row stays zeros."""
+    return _grouped_fwd_sum(xp, wg, wu, wd, row_weight, row_token,
+                            tile_expert, n_active)
 
 
-def _grouped_fwd_rows(xp, wg, wu, wd, row_token, tile_expert, n_active):
+def _tile(row_weight, row_token, tile_expert, j):
+    """The tokens, row weights and expert of tile ``j``."""
     tile = row_token.shape[0] // tile_expert.shape[0]
-    d = wd.shape[-1]
+    return (jax.lax.dynamic_slice(row_token, (j * tile,), (tile,)),
+            jax.lax.dynamic_slice(row_weight, (j * tile,), (tile,)),
+            tile_expert[j])
 
-    def body(j, rows):
-        tok = jax.lax.dynamic_slice(row_token, (j * tile,), (tile,))
-        e = tile_expert[j]
+
+def _grouped_fwd_sum(xp, wg, wu, wd, row_weight, row_token, tile_expert,
+                     n_active):
+    def body(j, y):
+        tok, rw, e = _tile(row_weight, row_token, tile_expert, j)
         _, _, h = _silu_gate(xp[tok], wg[e], wu[e])
-        return jax.lax.dynamic_update_slice(rows, (h @ wd[e]).astype(
-            rows.dtype), (j * tile, 0))
+        return y.at[tok].add((rw[:, None] * (h @ wd[e])).astype(y.dtype))
 
-    rows = jnp.zeros((row_token.shape[0] + 1, d), xp.dtype)
-    return jax.lax.fori_loop(0, n_active, body, rows)
+    return jax.lax.fori_loop(0, n_active, body, jnp.zeros_like(xp))
 
 
-def _grouped_fwd(xp, wg, wu, wd, row_token, tile_expert, n_active):
-    rows = _grouped_fwd_rows(xp, wg, wu, wd, row_token, tile_expert,
-                             n_active)
-    return rows, (xp, wg, wu, wd, row_token, tile_expert, n_active)
+def _grouped_fwd(xp, wg, wu, wd, row_weight, row_token, tile_expert,
+                 n_active):
+    y = _grouped_fwd_sum(xp, wg, wu, wd, row_weight, row_token,
+                         tile_expert, n_active)
+    return y, (xp, wg, wu, wd, row_weight, row_token, tile_expert, n_active)
 
 
-def _grouped_bwd(res, d_rows):
-    xp, wg, wu, wd, row_token, tile_expert, n_active = res
+def _grouped_bwd(res, dy):
+    xp, wg, wu, wd, row_weight, row_token, tile_expert, n_active = res
     tile = row_token.shape[0] // tile_expert.shape[0]
 
     def body(j, carry):
-        dxp, dwg, dwu, dwd = carry
-        tok = jax.lax.dynamic_slice(row_token, (j * tile,), (tile,))
-        e = tile_expert[j]
-        xt = xp[tok]
-        dy = jax.lax.dynamic_slice(d_rows, (j * tile, 0),
-                                   (tile, d_rows.shape[1]))
+        dxp, dwg, dwu, dwd, drw = carry
+        tok, rw, e = _tile(row_weight, row_token, tile_expert, j)
+        xt, dyt = xp[tok], dy[tok]
         g, u, h = _silu_gate(xt, wg[e], wu[e])
-        dh = dy @ wd[e].T
+        gt = dyt @ wd[e].T
+        # the row weight's gradient is <W_down h, dy> = <h, W_down^T dy>
+        drw = jax.lax.dynamic_update_slice(
+            drw, jnp.sum(h * gt, axis=-1).astype(drw.dtype), (j * tile,))
+        dh = rw[:, None] * gt
         sig = jax.nn.sigmoid(g)
         dg = dh * u * (sig * (1.0 + g * (1.0 - sig)))
         du = dh * g * sig
-        dwd = dwd.at[e].add((h.T @ dy).astype(dwd.dtype))
+        dwd = dwd.at[e].add((h.T @ (rw[:, None] * dyt)).astype(dwd.dtype))
         dwg = dwg.at[e].add((xt.T @ dg).astype(dwg.dtype))
         dwu = dwu.at[e].add((xt.T @ du).astype(dwu.dtype))
         dxt = dg @ wg[e].T + du @ wu[e].T
-        return dxp.at[tok].add(dxt.astype(dxp.dtype)), dwg, dwu, dwd
+        return dxp.at[tok].add(dxt.astype(dxp.dtype)), dwg, dwu, dwd, drw
 
-    dxp, dwg, dwu, dwd = jax.lax.fori_loop(
+    dxp, dwg, dwu, dwd, drw = jax.lax.fori_loop(
         0, n_active, body, tuple(jnp.zeros_like(a)
-                                 for a in (xp, wg, wu, wd)))
+                                 for a in (xp, wg, wu, wd, row_weight)))
     # the padding row of xp is a constant zero: nothing flows into it
-    return dxp.at[-1].set(0), dwg, dwu, dwd, None, None, None
+    return dxp.at[-1].set(0), dwg, dwu, dwd, drw, None, None, None
 
 
 grouped_gated_mlp.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def held_expert_sum(xt, chosen, w, wg, wu, wd, first_held: int, tile: int):
+    """``(y [N, d], counts [held])``: what the held experts add to each of
+    the ``N`` tokens of ``xt`` [N, d] under the router's ``chosen`` [N, k]
+    and weights ``w`` [N, k], a choice whose expert is not held adding
+    nothing.  The weights reach the rows of the layout through a gather
+    of ``L`` scalars."""
+    n, d = xt.shape
+    row_choice, tile_expert, n_active, counts = plan_held_tiles(
+        chosen, first_held, wg.shape[0], tile)
+    xp = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])
+    row_weight = jnp.concatenate([w.reshape(-1).astype(xt.dtype),
+                                  jnp.zeros((1,), xt.dtype)])[row_choice]
+    y = grouped_gated_mlp(xp, wg, wu, wd, row_weight,
+                          row_choice // chosen.shape[1], tile_expert,
+                          n_active)
+    return y[:n], counts
 
 
 class GatedMLP(nn.Module):
@@ -304,7 +332,8 @@ class HeldExpertMoE(nn.Module):
     experts', and what the absent experts would add is left out (their
     chips', in a deployment).  No capacity and no dropped token: the
     chosen (token, expert) pairs are gathered by expert into
-    `grouped_gated_mlp`.
+    `grouped_gated_mlp`, which adds each tile's results into its tokens
+    (`held_expert_sum`).
 
     Sows ``moe_stats/counts``, float32 ``[tokens, assignments, held
     assignments, largest held expert's tokens, mean held expert's
@@ -356,13 +385,9 @@ class HeldExpertMoE(nn.Module):
                         jnp.float32)
         dt = self.dtype or x.dtype
         tile = min(self.tile, max(8, -(-n // 8) * 8))
-        dest, row_token, tile_expert, n_active, counts = plan_held_tiles(
-            chosen, self.first_held, held, tile)
-        xp = jnp.concatenate([xt.astype(dt), jnp.zeros((1, d), dt)])
-        rows = grouped_gated_mlp(xp, wg.astype(dt), wu.astype(dt),
-                                 wd.astype(dt), row_token, tile_expert,
-                                 n_active)
-        y = jnp.sum(rows[dest] * w[..., None].astype(dt), axis=1)
+        y, counts = held_expert_sum(xt.astype(dt), chosen, w, wg.astype(dt),
+                                    wu.astype(dt), wd.astype(dt),
+                                    self.first_held, tile)
         if self.n_shared:
             y = y + GatedMLP(self.n_shared * self.d_ff, self.init_std,
                              self.dtype, name="shared")(xt)

@@ -8,7 +8,9 @@ the attention on their own, the share test of the expert cut, and
 
 import dataclasses
 import json
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +19,7 @@ import pytest
 
 from benchmark.configs import glm47_flash as ref
 from benchmark.token_shards import token_shard_arrays, write_token_shards
-from fedml_tpu.models.moe import (HeldExpertMoE, grouped_gated_mlp,
+from fedml_tpu.models.moe import (HeldExpertMoE, held_expert_sum,
                                   plan_held_tiles, route_sigmoid_topk)
 from fedml_tpu.models.transformer import (LatentAttention, LatentMoEArch,
                                           TransformerLM,
@@ -158,13 +160,132 @@ def test_no_token_is_dropped_when_every_token_takes_one_expert():
 
 def test_grouped_product_skips_nothing_and_computes_only_active_tiles():
     chosen = jnp.asarray([[0, 5], [1, 0], [7, 6], [1, 2]], jnp.int32)
-    dest, row_token, tile_expert, n_active, counts = plan_held_tiles(
+    row_choice, tile_expert, n_active, counts = plan_held_tiles(
         chosen, first_held=0, held=2, tile=2)
     np.testing.assert_array_equal(counts, [2, 2])
     assert int(n_active) == 2
-    assert sorted(np.asarray(row_token[:4])) == [0, 1, 1, 3]
-    held = np.asarray(dest) < row_token.shape[0]
-    np.testing.assert_array_equal(held, np.asarray(chosen) < 2)
+    rows = np.asarray(row_choice)
+    assert sorted(rows[:4] // 2) == [0, 1, 1, 3]       # the rows' tokens
+    np.testing.assert_array_equal(tile_expert[:2], [0, 1])
+    # every held choice has one row and no other choice has any; a
+    # padding row names the choice past the last, so the token past the last
+    np.testing.assert_array_equal(
+        np.sort(rows[rows < chosen.size]),
+        np.flatnonzero(np.asarray(chosen).reshape(-1) < 2))
+    assert set(rows[rows >= chosen.size]) == {chosen.size}
+
+
+# the layer's sum against every held expert over every token, masked by
+# the choice: 24 tokens, top-3 of 12 experts, 4..6 held, tiles of 4 rows
+N_TOK, TOP, EXPERTS, FIRST, HELD, TILE = 24, 3, 12, 4, 3, 4
+NOT_HELD = [e for e in range(EXPERTS) if not FIRST <= e < FIRST + HELD]
+SUM_CASES = {
+    # token 0 takes all three held experts, tokens 1 and 2 two each
+    "several_held_choices": (
+        {0: [4, 5, 6], 1: [4, 6], 2: [5, 6], 3: [5]},
+        lambda counts: int(counts[2]) == 3),
+    "held_expert_without_token": (
+        {t: [4] for t in range(5)} | {t: [6] for t in range(5, 8)},
+        lambda counts: int(counts[1]) == 0),
+    "run_over_several_tiles": (
+        {t: [4] for t in range(11)} | {11: [5]},
+        lambda counts: int(counts[0]) > 2 * TILE),
+    # the last active tile holds one row and three of padding
+    "last_tile_mostly_padding": (
+        {t: [6] for t in range(TILE + 1)} | {7: [4, 5]},
+        lambda counts: int(counts[2]) % TILE == 1),
+    "nothing_held": ({}, lambda counts: int(counts.sum()) == 0),
+}
+
+
+def _route(forced):
+    rng = np.random.default_rng(0)
+    chosen = np.stack([rng.choice(NOT_HELD, TOP, replace=False)
+                       for _ in range(N_TOK)])
+    for t, experts in forced.items():
+        chosen[t, :len(experts)] = experts
+    return jnp.asarray(chosen, jnp.int32)
+
+
+def _dense_held_sum(xt, chosen, w, wg, wu, wd):
+    """Every held expert over every token, weighted by the router's weight
+    where the token chose it and by 0 elsewhere."""
+    y = jnp.zeros_like(xt)
+    for e in range(HELD):
+        m = jnp.sum(jnp.where(chosen == FIRST + e, w, 0.0), axis=1)
+        h = jax.nn.silu(xt @ wg[e]) * (xt @ wu[e])
+        y = y + m[:, None] * (h @ wd[e])
+    return y
+
+
+@pytest.mark.parametrize("case", sorted(SUM_CASES))
+def test_held_sum_and_its_gradients_are_the_dense_masked_sum(case):
+    forced, holds = SUM_CASES[case]
+    chosen = _route(forced)
+    keys = jax.random.split(jax.random.key(11), 6)
+    d, f = 16, 8
+    xt = jax.random.normal(keys[0], (N_TOK, d))
+    w = jax.random.uniform(keys[1], (N_TOK, TOP), minval=0.1)
+    wg, wu = (0.3 * jax.random.normal(k, (HELD, d, f)) for k in keys[2:4])
+    wd = 0.3 * jax.random.normal(keys[4], (HELD, f, d))
+    cot = jax.random.normal(keys[5], (N_TOK, d))
+    _, tile_expert, n_active, counts = plan_held_tiles(
+        chosen, FIRST, HELD, TILE)
+    assert holds(np.asarray(counts)), case
+    assert int(n_active) < tile_expert.shape[0]
+    assert int(n_active) == sum(-(-int(c) // TILE) for c in counts)
+
+    def program(*a):
+        y, _ = held_expert_sum(a[0], chosen, a[1], *a[2:], FIRST, TILE)
+        return y
+
+    def dense(*a):
+        return _dense_held_sum(a[0], chosen, *a[1:])
+
+    args = (xt, w, wg, wu, wd)
+    with jax.default_matmul_precision("highest"):
+        got, want = program(*args), dense(*args)
+        grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * cot),
+                          argnums=tuple(range(5)))(*args)
+                 for fn in (program, dense)]
+    scale = float(jnp.max(jnp.abs(want))) + 1e-12
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * scale)
+    if case == "nothing_held":
+        assert not np.any(np.asarray(got))
+    else:
+        assert scale > 1e-2
+    for name, a, b in zip(("x", "w", "gate", "up", "down"), *grads):
+        scale = float(jnp.max(jnp.abs(b))) + 1e-12
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6 * scale,
+                                   err_msg=name)
+
+
+def test_no_array_of_the_layouts_length_and_the_models_width_is_compiled():
+    """The gradient of a Keye-shaped layer (1,024 tokens, top-8 of 128, 8
+    held, tiles of 128 rows) holds no array of the worst-case layout's
+    rows (``L = 1,024 x 8 + 8 x 128``, or ``L + 1``) or of every choice's
+    (``N x k``) at the model's width: each tile is summed into the
+    ``[N + 1, d]`` tokens."""
+    n, k, held, tile, d = 1024, 8, 8, 128, 256
+    layer = HeldExpertMoE(experts_total=128, experts_held=held, first_held=0,
+                          top_k=k, d_ff=64, n_shared=0, tile=tile,
+                          router="softmax")
+    x = jax.ShapeDtypeStruct((1, n, d), jnp.float32)
+    params = jax.eval_shape(layer.init, jax.random.key(0), x)["params"]
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    rows = set()
+    for dims in re.findall(r"\[([0-9]+(?:,[0-9]+)+)\]", hlo):
+        dims = [int(v) for v in dims.split(",")]
+        if dims[-1] == d:
+            rows.add(math.prod(dims[:-1]))
+    length = n * k + held * tile
+    assert n + 1 in rows            # the tokens the tiles are summed into
+    assert not rows & {length, length + 1, n * k}, sorted(rows)
 
 
 # ---------------------------------------------------------------------------
