@@ -1,17 +1,20 @@
-"""Per-layer metrics of an expert model with latent attention: what its
-two distinctive kernels take of a traced round, what they are required to
-do, and what the router made of the tokens.
+"""Per-layer metrics of an expert model (with latent attention, or with
+another attention whose group is read elsewhere): what its two distinctive
+kernels take of a traced round, what they are required to do, and what the
+router made of the tokens.
 
 Two groups of device operations are read from the traced rounds (the
 profiler names an op by its whole HLO line; named scopes do not reach the
 v5e trace, PERF.md section 3, so a group is found by the shape the op
 produces):
 
-* ``attention``: the attention core of every block, forward, recomputed
-  and backward: the ops whose result carries the heads beside a query
-  block, ``[B, heads, block, keys]`` (scores, their softmax, their
+* ``attention``: the latent attention core of every block, forward,
+  recomputed and backward: the ops whose result carries the heads beside a
+  query block, ``[B, heads, block, keys]`` (scores, their softmax, their
   gradients) or ``[B, block, heads, head_dim]`` (the mix and the gradients
-  by q, k and v) at the configuration's ``attn_block_size``;
+  by q, k and v) at the configuration's ``attn_block_size``.  Only for a
+  model with latent attention (``LATENT_KEYS``); another attention has a
+  reader of its own (``sparse_attention.py``);
 * ``experts``: the grouped products over the experts held, a tile of rows
   against one expert's matrices inside the loop over the active tiles
   (``models/moe.grouped_gated_mlp``): results ``[tile, moe_width]``,
@@ -47,6 +50,9 @@ TILE = 512      # rows a tile of the grouped product holds (models/moe.py)
 COUNTS = ("tokens", "expert_assignments", "expert_assignments_held",
           "expert_load_max", "expert_load_mean")
 _SHAPE = re.compile(r"= \(?[a-z0-9]+\[([0-9,]*)\]")
+# the head widths of latent attention: the attention group is looked for
+# only in a model file that has them
+LATENT_KEYS = ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
 
 
 def _model(ctx) -> Optional[dict]:
@@ -85,10 +91,11 @@ def group_of(hlo: str, m: dict) -> Optional[str]:
         return None
     dims = _produced(hlo)
     heads, block = m["num_attention_heads"], m["block"]
-    head_dims = {m["qk_nope_head_dim"] + m["qk_rope_head_dim"],
-                 m["v_head_dim"]}
     d, f = m["hidden_size"], m["moe_intermediate_size"]
-    if len(dims) == 4 and heads in dims[1:3]:
+    if len(dims) == 4 and heads in dims[1:3] and all(
+            k in m for k in LATENT_KEYS):
+        head_dims = {m["qk_nope_head_dim"] + m["qk_rope_head_dim"],
+                     m["v_head_dim"]}
         # [B, heads, block, keys] or [B, block, heads, head_dim] (or a
         # key range of k / v: a multiple of the block, heads, head_dim)
         rest = [x for i, x in enumerate(dims[1:], 1) if x != heads or i > 2]

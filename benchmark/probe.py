@@ -9,10 +9,11 @@ everything the process does between two entries (training, fold, server
 step, CRC, ledger line, sampling of the next cohort) lies between them.
 
 The same wrap hands the comparison what the timed path produced: the global
-model going into the first round and coming out of the first ``keep``
-rounds, and the cohort ids of every round (to count the samples the window
-consumed).  The globals are copied to host memory as they are taken, which
-waits for that round's last program; ``keep`` rounds are set-up and lie
+model going into the first round (g0), coming out of it (g1) and coming out
+of round ``keep`` (gK), the globals ``reference/fedavg.compare`` reads, and
+the cohort ids of every round (to count the samples the window consumed).
+The globals are copied to host memory as they are taken, which waits for
+that round's last program; ``keep`` rounds are set-up and lie
 before the window opens (``keep <= first``), so nothing of the harness's
 stays on the chip while ``MemoryWatch`` reads it and the window's rounds are
 entered as if no global had been kept.  While the window
@@ -180,7 +181,7 @@ class RoundProbe:
         self.stamps_mono: List[float] = []  # perf_counter at each entry
         self.cohorts: List[Any] = []
         self.state_in = None
-        self.states_out: List[Any] = []
+        self.states_out: Dict[int, Any] = {}   # g1 and gK, by round index
         self.compiles_at: Dict[str, dict] = {}
         self.tracing = False
         self._span = None
@@ -219,8 +220,9 @@ class RoundProbe:
             if k == 0 and spec.keep and state_arg is not None:
                 spec.state_in = _host_copy(args[state_arg])
             out = original(*args, **kwargs)
-            if k < spec.keep and state_out is not None:
-                spec.states_out.append(_host_copy(out[state_out]))
+            if spec.keep and k in (0, spec.keep - 1) \
+                    and state_out is not None:
+                spec.states_out[k + 1] = _host_copy(out[state_out])
             return out
 
         return hooked

@@ -33,17 +33,21 @@ the file does not follow it refuses before any round (``FOLLOWS``).
 ``fault`` plants one of the faults the benchmark's comparison has to catch,
 so that their readings can be taken from the reference put in the
 program's place.
+
+The comparison (``compare``) walks the leaves of the kept globals of both
+sides together and holds one leaf's float64 differences at a time, so the
+host memory it adds is a leaf's, not a tree's.
 """
 
 from __future__ import annotations
 
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-FAULTS = ("half_batch", "state_unchanged")
+FAULTS = ("half_batch", "half_tokens", "state_unchanged")
 # the program's options this file follows, by the CLI's own key: any other
 # value is another algorithm, and following this one instead would compare
 # the program with something it was not asked to do
@@ -148,11 +152,15 @@ def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
         precision: str = "highest",
         log: Callable[[str], None] = lambda s: None) -> dict:
     """Follow ``rounds`` rounds from the seed under ``task`` (a
-    ``tasks.Task``).  Returns the globals ``states[0..rounds]`` as host
-    trees and ``loss_r0``, the task's mean loss of ``states[1]`` over
-    every client's training rows."""
+    ``tasks.Task``).  Returns ``states``, the globals the comparison reads
+    as host trees by round index: {0: g0, 1: g1, rounds: the last}, and
+    ``loss_r0``, the task's mean loss of g1 over every client's training
+    rows."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
+    if fault == "half_tokens" and task.pad_id is None:
+        raise ValueError("half_tokens needs a task whose loss leaves out a "
+                         "pad id")
     B = batch_size
     with jax.default_matmul_precision(precision):
         train_step, eval_batch, accumulate = make_steps(model, lr, task,
@@ -161,7 +169,7 @@ def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
         key, init_key = jax.random.split(key)
         x0 = jnp.asarray(_pad(clients[0][0][:B], B))
         params = model.init(init_key, x0)["params"]
-        states: List = [jax.tree.map(np.asarray, params)]
+        states: Dict[int, object] = {0: jax.tree.map(np.asarray, params)}
         loss_r0 = None
         ones = np.ones(B, np.float32)
         for r in range(rounds):
@@ -184,13 +192,19 @@ def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
                         # half of each batch left out, the mean taken
                         # over the rest
                         m = m * (np.arange(B) % 2 == 0)
+                    if fault == "half_tokens":
+                        # every other target made the pad, which the loss
+                        # leaves out: half of a batch of one row
+                        yb = yb.copy()
+                        yb[..., 1::2] = task.pad_id
                     p, ck = train_step(p, _pad(xb, B), _pad(yb, B), m, ck)
                 acc = accumulate(acc, p, float(n))
                 total += float(n)
             new = jax.tree.map(lambda a: a / total, acc)
             if fault != "state_unchanged":
                 params = new
-            states.append(jax.tree.map(np.asarray, params))
+            if r in (0, rounds - 1):
+                states[r + 1] = jax.tree.map(np.asarray, params)
             log(f"reference round {r}: {len(ids)} clients, {int(total)} rows")
             if r == 0:
                 parts = [eval_batch(params, *b) for b in eval_batches(
@@ -203,15 +217,38 @@ def run(model, clients: Sequence[Tuple[np.ndarray, np.ndarray]], *,
 # ---------------------------------------------------------------------------
 # the numbers compared
 
-def _change(states, k):
-    """Leaves of (states[k] - states[0]), float64, flattened-tree order."""
-    return [np.asarray(x, np.float64) - np.asarray(y, np.float64)
-            for x, y in zip(jax.tree.leaves(states[k]),
-                            jax.tree.leaves(states[0]))]
+def _change_norms(now, then):
+    """(now - then) in float64 and its norm: the float32 leaves are
+    converted as the subtraction reads them, so no float64 copy of either
+    is made."""
+    change = np.subtract(now, then, dtype=np.float64)
+    return change, np.linalg.norm(change.ravel())
 
 
-def _norms(leaves):
-    return np.asarray([np.linalg.norm(v.ravel()) for v in leaves])
+def leaf_norms(prog: Dict[int, object], ref: Dict[int, object],
+               rounds: Sequence[int]) -> Dict[str, Dict[int, np.ndarray]]:
+    """For each round j of ``rounds``, three vectors of leaf count, in the
+    flattened-tree order: the norm of the reference's change of a leaf
+    since g0 (``ref``), of the program's (``prog``), and of the difference
+    of the two changes (``diff``).  One leaf is held in float64 at a
+    time: a leaf's two changes."""
+    out = {kind: {j: [] for j in rounds} for kind in ("ref", "prog", "diff")}
+    leaves = {(side, j): jax.tree.leaves(states[j])
+              for side, states in (("prog", prog), ("ref", ref))
+              for j in {0, *rounds}}
+    for i in range(len(leaves["ref", 0])):
+        for j in rounds:
+            c_ref, n_ref = _change_norms(leaves["ref", j][i],
+                                         leaves["ref", 0][i])
+            c_prog, n_prog = _change_norms(leaves["prog", j][i],
+                                           leaves["prog", 0][i])
+            np.subtract(c_prog, c_ref, out=c_prog)
+            out["ref"][j].append(n_ref)
+            out["prog"][j].append(n_prog)
+            out["diff"][j].append(np.linalg.norm(c_prog.ravel()))
+            del c_ref, c_prog     # before the next two are made
+    return {kind: {j: np.asarray(v) for j, v in by.items()}
+            for kind, by in out.items()}
 
 
 def _worst_leaf_gap(prog, ref, keep=None) -> float:
@@ -222,30 +259,37 @@ def _worst_leaf_gap(prog, ref, keep=None) -> float:
     return float((gap if keep is None else gap[keep]).max())
 
 
-def compare(prog_states, prog_loss_r0: float, ref: dict) -> dict:
+def compare(prog_states: Dict[int, object], prog_loss_r0: float,
+            ref: dict) -> dict:
     """The numbers `correct` is decided on, program against reference.
 
-    ``prog_states``: the program's globals [g0, g1, ... gK] (K >= 1) as
-    host trees with the reference's layout.  Returns name -> reading."""
+    ``prog_states``: the program's globals by round index, {0: g0, 1: g1,
+    K: gK} (K >= 1), as host trees with the reference's layout; the
+    reference's ``states`` are kept alike, for the same rounds.  Returns
+    name -> reading."""
     rs = ref["states"]
+    if set(prog_states) != set(rs) or not {0, 1} <= set(rs):
+        raise ValueError(f"the program kept the globals of rounds "
+                         f"{sorted(prog_states)}, the reference of "
+                         f"{sorted(rs)}; both need 0, 1 and the same last")
     if jax.tree.structure(prog_states[0]) != jax.tree.structure(rs[0]):
         raise ValueError(
             "the program's parameter tree is not laid out as the "
             "reference's: "
             f"{jax.tree.structure(prog_states[0])} vs "
             f"{jax.tree.structure(rs[0])}")
-    k = min(len(prog_states), len(rs)) - 1
+    k = max(rs)
+    rounds = sorted({1, k})
+    norms = leaf_norms(prog_states, rs, rounds)
     out = {"loss_r0": abs(prog_loss_r0 - ref["loss_r0"])
            / abs(ref["loss_r0"])}
-    g_ref = _norms(_change(rs, 1))
-    out["grad1_worst_leaf"] = _worst_leaf_gap(
-        _norms(_change(prog_states, 1)), g_ref)
+    g_ref = norms["ref"][1]
+    out["grad1_worst_leaf"] = _worst_leaf_gap(norms["prog"][1], g_ref)
     # leaves whose first pseudo-gradient is nought to rounding in the
     # reference are left out of the change (contract, step 4)
     keep = g_ref >= 1e-3 * np.median(g_ref)
-    c_ref, c_prog = _change(rs, k), _change(prog_states, k)
     out[f"change{k}_worst_leaf"] = _worst_leaf_gap(
-        _norms(c_prog), _norms(c_ref), keep)
+        norms["prog"][k], norms["ref"][k], keep)
     # not a norm gap but the norm of the difference of the two changes: it
     # also sees a change of the right size in the wrong direction (a
     # clipped gradient keeps its norm whatever the batch holds).  Over the
@@ -254,10 +298,8 @@ def compare(prog_states, prog_loss_r0: float, ref: dict) -> dict:
     # leaves whose gradient is a sum that all but cancels (GroupNorm's 64
     # scales) carry the whole tree's on some seeds, the median leaf is
     # steady from seed to seed (PERF.md section 6)
-    for j in sorted({1, k}):
-        ref_j, prog_j = _change(rs, j), _change(prog_states, j)
-        diff = _norms([p - r for p, r in zip(prog_j, ref_j)])
-        n_ref = _norms(ref_j)
+    for j in rounds:
+        diff, n_ref = norms["diff"][j], norms["ref"][j]
         out[f"change{j}_diff"] = float(
             np.sqrt(np.sum(diff ** 2)) / np.sqrt(np.sum(n_ref ** 2)))
         out[f"change{j}_median_leaf"] = float(np.median(

@@ -67,6 +67,7 @@ class Task:
     clip_norm: Optional[float]
     eval_rows: int
     eval_by: str
+    pad_id: Optional[int] = None   # a target the loss leaves out, if any
 
 
 def from_config(config: dict) -> Task:
@@ -91,6 +92,8 @@ def from_config(config: dict) -> Task:
         raise ValueError(f"configuration {name!r}: 'task.eval_by' = "
                          f"{t['eval_by']!r}; have {EVAL_BY}")
     clip = t["clip_norm"]
-    return Task(loss=functools.partial(loss, **t.get("loss_args", {})),
+    loss_args = t.get("loss_args", {})
+    return Task(loss=functools.partial(loss, **loss_args),
                 clip_norm=None if clip is None else float(clip),
-                eval_rows=int(t["eval_rows"]), eval_by=t["eval_by"])
+                eval_rows=int(t["eval_rows"]), eval_by=t["eval_by"],
+                pad_id=loss_args.get("pad_id"))

@@ -284,8 +284,9 @@ def timed_call(cell: Cell, seed: int, data_dir: str, n: int, watch,
     set-up rounds, ``n`` window rounds, ``n_traced`` rounds under the
     profiler where ``trace_dir`` is given, one more.  Returns the window's
     stamps and cohorts, the program's ledger lines of the window, what the
-    call produced in its first ``KEEP`` rounds (globals g0..gKEEP, taken
-    to the host in set-up) and the device's record."""
+    call produced in its first ``KEEP`` rounds (the globals g0, g1 and
+    gKEEP by round index, taken to the host in set-up) and the device's
+    record."""
     from benchmark.compile_watch import diff
     from benchmark.probe import MemoryWatch, RoundProbe
     run_dir = os.path.join(CACHE, "runs", cell.name)
@@ -299,8 +300,8 @@ def timed_call(cell: Cell, seed: int, data_dir: str, n: int, watch,
                           rounds=probe.rounds_needed, extra=extra), probe)
     win = probe.window()
     device = device_record(cell.chips, memory)
-    states = [probe.state_in] + probe.states_out   # host copies already
-    probe.state_in, probe.states_out = None, []
+    states = {0: probe.state_in, **probe.states_out}  # host copies already
+    probe.state_in, probe.states_out = None, {}
     lines = read_jsonl(os.path.join(run_dir, "perf.jsonl"))[KEEP:KEEP + n]
     evals = [r for r in read_jsonl(os.path.join(run_dir, "metrics.jsonl"))
              if r.get("round") == 0 and "train_loss" in r]
@@ -326,6 +327,12 @@ def follow_reference(cell: Cell, clients, pseed: int, **kw) -> dict:
                       rounds=KEEP, cohort=int(a["client_num_per_round"]),
                       batch_size=int(a["batch_size"]), lr=float(a["lr"]),
                       log=say, **kw)
+
+
+def peak_rss_bytes() -> int:
+    """The process's peak resident set so far (Linux counts it in KiB)."""
+    import resource
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
 def run_cell(bench: dict, workload: str, seed: int, seconds: float,
@@ -417,10 +424,16 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     # and the program's state is gone
     t0 = time.time()
     ref = follow_reference(cell, clients, pseed)
+    # the room the host has left: what the next, larger tree would add to
+    # (a reading for PERF.md, no metric and no limit)
+    peak_rss = {"after_reference": peak_rss_bytes()}
     numbers = fedavg.compare(prog_states, prog_loss_r0, ref)
+    peak_rss["after_compare"] = peak_rss_bytes()
     ok, rows = fedavg.verdict(numbers, cell.limits)
     reference_s = time.time() - t0
     correct = bool(ok and not failed)
+    say(f"peak resident set: {peak_rss['after_reference']} bytes after the "
+        f"reference, {peak_rss['after_compare']} after the comparison")
 
     detail = {"workload": workload, "seed": seed, "program_seed": pseed,
               "rounds": n, "t_warm": t_warm, "round_gaps_s":
@@ -431,7 +444,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
               "train_flops_per_sample": ctx["train_flops_per_sample"],
               "program_loss_r0": prog_loss_r0,
               "reference_loss_r0": ref["loss_r0"],
-              "reference_s": reference_s,
+              "reference_s": reference_s, "peak_rss_bytes": peak_rss,
               "total_s": time.time() - T_PROCESS_START, "trace": ctx["trace"],
               "phases": [ln.get("phases") for ln in lines],
               "global_crc": [ln.get("global_crc") for ln in lines]}
@@ -441,7 +454,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     print(json.dumps({k: detail[k] for k in (
         "rounds", "t_warm", "end_to_end", "compiles_in_window", "recompiles",
         "compiles_total", "numbers", "train_flops_per_sample", "reference_s",
-        "total_s")}))
+        "peak_rss_bytes", "total_s")}))
     result = {"correct": correct, **result,
               "compared": {r["name"]: {"value": r["value"],
                                        "limit": r["limit"]} for r in rows}}

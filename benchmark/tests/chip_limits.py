@@ -11,14 +11,18 @@ control seed it drives the program again with its own lower-precision path
 switched on (``--compute_dtype bfloat16``: the control of a float32
 configuration) against the same reference.  For a fault seed it puts the
 reference with a planted fault in the program's place (a state left
-unchanged reads 1 on every norm and needs no run).  A further argument
+unchanged reads 1 on every norm and needs no run; ``half_tokens`` only
+where the task leaves out a pad id).  A further argument
 drives the program once more on its seeds with the given CLI flags (another
 path of the program, as a witness).  Every reading goes through
 ``fedavg.verdict`` with the cell's committed limits, as a run's does: a
 sound seed has to come out correct, a control or a fault not.  One JSON
 line per reading goes to stdout and to
 ``chiprun_out/limits.<workload>.jsonl``, and a summary by kind to stderr.
-The benchmark's own runs never run this.
+The process holds one program set of globals and one reference set at a
+time: each set compared is let go before the next is made, so a language
+model's readings fit the host as a run's do.  The benchmark's own runs
+never run this.
 """
 
 import json
@@ -31,18 +35,22 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
 CONTROL = ("--compute_dtype", "bfloat16")
-FAULTS = ("half_batch",)
+FAULTS = ("half_batch", "half_tokens")
 
 
 def seeds_of(arg: str):
     return [] if arg == "-" else [int(s) for s in arg.split(",")]
 
 
-def main(workload: str, seeds, control_seeds, fault_seeds, variants=()) -> None:
+def main(workload: str, seeds, control_seeds, fault_seeds, variants=(),
+         bench=None) -> None:
+    """``bench``: BENCHMARK.json's contents, or another's (a test's tiny
+    cell, whose caller has put the look for a chip aside)."""
     from benchmark import run
     from benchmark.compile_watch import CompileWatch
-    from benchmark.reference import fedavg
-    bench = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    from benchmark.reference import fedavg, tasks
+    if bench is None:
+        bench = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = run.Cell(bench, workload)
     run.check_device(cell.chips)
     watch = CompileWatch()
@@ -64,6 +72,10 @@ def main(workload: str, seeds, control_seeds, fault_seeds, variants=()) -> None:
         print(json.dumps(row), flush=True)
         out.flush()
 
+    faults = [f for f in FAULTS
+              if f != "half_tokens" or tasks.from_config(cell.config).pad_id
+              is not None]
+
     for seed in seeds:
         t0 = time.time()
         data_dir, arrays = run.ensure_data(cell, seed)
@@ -71,6 +83,7 @@ def main(workload: str, seeds, control_seeds, fault_seeds, variants=()) -> None:
             arrays = run.make_arrays(cell, seed)
         pseed = run.program_seed(seed, cell.config)
         clients = cell.reference.train_clients(arrays, cell.config, pseed)
+        del arrays
         t1 = time.time()
         prog = run.timed_call(cell, seed, data_dir, 1, watch)
         t2 = time.time()
@@ -80,29 +93,30 @@ def main(workload: str, seeds, control_seeds, fault_seeds, variants=()) -> None:
               "numbers": fedavg.compare(prog["states"], prog["loss_r0"], ref),
               "loss_r0": [prog["loss_r0"], ref["loss_r0"]],
               "data_s": t1 - t0, "program_s": t2 - t1,
-              "reference_s": t3 - t2, "device": prog["device"]})
-        if seed in control_seeds:
-            ctl = run.timed_call(cell, seed, data_dir, 1, watch,
-                                 extra=CONTROL)
-            emit({"workload": workload, "seed": seed, "kind": "control",
-                  "numbers": fedavg.compare(ctl["states"], ctl["loss_r0"],
-                                            ref)})
-        for name, v_seeds, flags in variants:
-            if seed in v_seeds:
-                alt = run.timed_call(cell, seed, data_dir, 1, watch,
-                                     extra=flags)
-                emit({"workload": workload, "seed": seed, "kind": name,
-                      "flags": flags,
-                      "numbers": fedavg.compare(alt["states"],
-                                                alt["loss_r0"], ref)})
-        if seed in fault_seeds:
-            for fault in FAULTS:
-                bad = run.follow_reference(cell, clients, pseed, fault=fault)
-                emit({"workload": workload, "seed": seed,
-                      "kind": "fault:" + fault,
-                      "numbers": fedavg.compare(bad["states"],
-                                                bad["loss_r0"], ref)})
-        del arrays, clients, prog, ref
+              "reference_s": t3 - t2, "device": prog["device"],
+              "peak_rss_bytes": run.peak_rss_bytes()})
+        del prog
+        others = [("control", CONTROL)] if seed in control_seeds else []
+        others += [(name, flags) for name, v_seeds, flags in variants
+                   if seed in v_seeds]
+        for name, flags in others:
+            alt = run.timed_call(cell, seed, data_dir, 1, watch,
+                                 extra=flags)
+            emit({"workload": workload, "seed": seed, "kind": name,
+                  "flags": list(flags),
+                  "numbers": fedavg.compare(alt["states"], alt["loss_r0"],
+                                            ref),
+                  "peak_rss_bytes": run.peak_rss_bytes()})
+            del alt
+        for fault in faults if seed in fault_seeds else ():
+            bad = run.follow_reference(cell, clients, pseed, fault=fault)
+            emit({"workload": workload, "seed": seed,
+                  "kind": "fault:" + fault,
+                  "numbers": fedavg.compare(bad["states"], bad["loss_r0"],
+                                            ref),
+                  "peak_rss_bytes": run.peak_rss_bytes()})
+            del bad
+        del clients, ref
     out.close()
     for kind in sorted({r["kind"] for r in readings}):
         rows = [r for r in readings if r["kind"] == kind]

@@ -257,6 +257,17 @@ def _half_batch(original):
     return gather
 
 
+PAD_ID = 0     # the task's pad id in the next-token configurations
+
+
+def _half_tokens(original):
+    def gather(stacked, client_ids, pad_to=None):
+        out = original(stacked, client_ids, pad_to=pad_to)
+        out["y"] = out["y"].at[..., 1::2].set(PAD_ID)
+        return out
+    return gather
+
+
 FAULTS = {
     "sound": None,
     # a step that returns its state unchanged
@@ -266,18 +277,24 @@ FAULTS = {
     # half of each batch left out, the mean taken over the rest
     "half_batch": ("fedml_tpu.algorithms.cross_device:gather_cohort",
                    _half_batch),
+    # every other target the pad, which the loss leaves out: the half of a
+    # batch of one row (a next-token task's only)
+    "half_tokens": ("fedml_tpu.algorithms.cross_device:gather_cohort",
+                    _half_tokens),
     # the control: the program's own lower-precision path switched on
     "control_bfloat16": ("--compute_dtype", "bfloat16"),
 }
 # the number that has to refuse each fault, whatever else does: the norm of
 # the difference of the two changes after three rounds, over the whole tree
 REFUSED_BY = {"state_unchanged": "change3_diff", "half_batch": "change3_diff",
-              "control_bfloat16": "change3_diff"}
+              "half_tokens": "change3_diff", "control_bfloat16": "change3_diff"}
 SOUND_AT_MOST = 1e-4    # every compared number of a sound run, on the CPU
+NEXT_TOKEN = ("shakespeare",)
 
 
-@pytest.mark.parametrize("which", list(rehearse.CELLS))
-@pytest.mark.parametrize("fault", list(FAULTS))
+@pytest.mark.parametrize("fault,which", [
+    (fault, which) for fault in FAULTS for which in rehearse.CELLS
+    if fault != "half_tokens" or which in NEXT_TOKEN])
 def test_rehearsal_is_correct_only_when_sound(fault, which, capfd,
                                               monkeypatch):
     from benchmark.probe import patched
@@ -303,3 +320,46 @@ def test_rehearsal_is_correct_only_when_sound(fault, which, capfd,
         assert row["value"] > row["limit"], (fault, result["compared"])
     assert result["failed"] == 0 and result["attempted"] >= run.MIN_ROUNDS
     assert set(result["metrics"]) == {"round_s", "samples_per_s", "setup_s"}
+
+
+def test_chip_limits_holds_one_program_set_and_one_reference_set(
+        tmp_path, monkeypatch, capfd):
+    """``chip_limits.main`` on the tiny indexed expert cell, one seed with
+    the control and both faults: each set of globals it compares is let go
+    before the next is made, so no more than the reference's and one
+    other set are alive whenever a set is made; the program is correct,
+    the control and ``half_tokens`` are not."""
+    import weakref
+    import jax
+    import chip_limits
+    import rehearse_keye   # noqa: F401  the tiny Keye cell in rehearse.CELLS
+    monkeypatch.setattr(chip_limits, "ROOT", str(tmp_path))
+    monkeypatch.setattr(run, "check_device", lambda chips: None)
+    made = []     # a weak reference to one leaf of each set made, in order
+    alive = []    # how many sets were alive as each was made
+
+    def tracked(f):
+        def call(*a, **kw):
+            out = f(*a, **kw)
+            made.append(weakref.ref(jax.tree.leaves(out["states"][0])[0]))
+            alive.append(sum(r() is not None for r in made))
+            return out
+        return call
+
+    monkeypatch.setattr(run, "timed_call", tracked(run.timed_call))
+    monkeypatch.setattr(run, "follow_reference", tracked(run.follow_reference))
+    cell = rehearse.CELLS["keye"][2]
+    seed = 2147483659
+    chip_limits.main(cell, [seed], [seed], [seed],
+                     bench=rehearse.tiny_bench("keye"))
+    capfd.readouterr()
+    with open(tmp_path / "chiprun_out" / f"limits.{cell}.jsonl") as f:
+        rows = {r["kind"]: r for r in map(json.loads, f)}
+    assert list(rows) == ["program", "control", "fault:half_batch",
+                          "fault:half_tokens"]
+    # program, reference, control, two faults: the reference and one more
+    assert alive == [1, 2, 2, 2, 2], alive
+    assert rows["program"]["correct"], rows["program"]
+    assert not rows["control"]["correct"], rows["control"]
+    assert "change3_diff" in rows["fault:half_tokens"]["failed_on"]
+    assert all(r["peak_rss_bytes"] > 0 for r in rows.values())

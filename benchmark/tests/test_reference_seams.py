@@ -193,20 +193,22 @@ def test_the_probe_keeps_host_copies_the_program_cannot_reach():
         return donated(params), {"n": len(ids)}
 
     spec = {"state_arg": 1, "cohort_arg": 2, "state_out": 0}
-    probe = RoundProbe(spec, first=2, n_window=1, keep=2)
+    probe = RoundProbe(spec, first=3, n_window=1, keep=3)
     hooked = probe.wrap(one_round)
     params = {"w": jnp.zeros((3, 2)), "b": {"c": jnp.ones(4)}}
     for r in range(probe.rounds_needed):
         params, _ = hooked(None, params, [r])
-        kept = [probe.state_in] + probe.states_out
-        assert len(kept) == 1 + min(r + 1, 2)
+        # g1 and g3, the globals the comparison reads; g2 is not kept
+        assert sorted(probe.states_out) == [1, 3][:1 + (r >= 2)]
+        kept = [probe.state_in] + list(probe.states_out.values())
         for leaf in jax.tree.leaves(kept):
             assert type(leaf) is np.ndarray and leaf.flags.owndata
             assert not isinstance(leaf, jax.Array)
     # the values are those of their rounds, whatever the program did to
     # its own buffers since
     assert probe.state_in["w"].tolist() == np.zeros((3, 2)).tolist()
-    assert [s["b"]["c"][0] for s in probe.states_out] == [2.0, 3.0]
+    assert {j: s["b"]["c"][0] for j, s in probe.states_out.items()} == {
+        1: 2.0, 3: 4.0}
     assert float(params["w"][0, 0]) == probe.rounds_needed
     assert len(probe.window()["edges_mono"]) == 2
     with pytest.raises(ValueError, match="keep"):
@@ -214,7 +216,7 @@ def test_the_probe_keeps_host_copies_the_program_cannot_reach():
     # nothing is kept where nothing is asked for
     idle = RoundProbe(spec, first=1, n_window=1, keep=0)
     idle.wrap(one_round)(None, params, [0])
-    assert idle.state_in is None and idle.states_out == []
+    assert idle.state_in is None and idle.states_out == {}
 
 
 # -- the tiny next-token configuration's data ----------------------------------------
