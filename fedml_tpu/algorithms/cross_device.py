@@ -617,12 +617,18 @@ class CrossDevice(FedAvg):
         and 0 for a model without the mechanism: ``attn_calls``, the
         attention cores the wave program was handed
         (`models.transformer.LatentAttention`,
-        `models.indexed_attention.IndexedAttention`: layers x client-steps
+        `models.indexed_attention.IndexedAttention`,
+        `models.window_attention.WindowAttention`: layers x client-steps
         that ran), ``attn_calls_fused``, those of them the fused kernels
         took (`causal_blocked_attention` says which),
         ``attn_pairs_causal``, the causal (query, key) pairs of the cores
         whose keys an indexer selects, and ``attn_pairs_selected``, those
-        of them it selected.  For an expert model
+        of them it selected, ``attn_tiles_causal``, the key tiles on or
+        below the diagonal of the cores under a window (sequences x query
+        heads, at the blocks of the path that took them: the precondition
+        there is a window of at least one key, so every row sees its
+        own), and ``attn_tiles_visited``, those of them the core computed
+        (`models.transformer.window_tiles`).  For an expert model
         (`models.moe.HeldExpertMoE`) also the ``tokens`` routed, their
         ``expert_assignments`` (tokens x experts a token),
         ``expert_assignments_held`` (those whose expert this chip holds),
@@ -632,12 +638,14 @@ class CrossDevice(FedAvg):
         seven digits beyond."""
         names = {"attn": ("attn_calls", "attn_calls_fused"),
                  "select": ("attn_pairs_causal", "attn_pairs_selected"),
+                 "window": ("attn_tiles_causal", "attn_tiles_visited"),
                  "moe": ("tokens", "expert_assignments",
                          "expert_assignments_held", "expert_load_max",
                          "expert_load_mean")}
         read = jax.device_get({k: aux_sums[k] for k in names
                                if k in aux_sums})
-        counts = dict.fromkeys(names["attn"] + names["select"], 0.0)
+        counts = dict.fromkeys(names["attn"] + names["select"]
+                               + names["window"], 0.0)
         for k, values in read.items():
             counts.update(zip(names[k], (float(v) for v in values)))
         return counts

@@ -172,8 +172,9 @@ class ExperimentConfig:
     #                           built as the arch its model_type names
     #                           (experiments/models.arch_of: latent
     #                           attention with routed + shared experts,
-    #                           or indexer-selected grouped-query
-    #                           attention), plus the share of a layer
+    #                           indexer-selected grouped-query attention,
+    #                           or window and full grouped-query layers),
+    #                           plus the share of a layer
     #                           held here: experts_held, first_held,
     #                           vocab_held
     silo_idle_timeout_s: float = 0.0  # grpc silos: exit after this long

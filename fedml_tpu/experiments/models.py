@@ -34,7 +34,9 @@ def arch_of(model_config: str):
     import json
     from fedml_tpu.models.indexed_attention import IndexedGQAArch
     from fedml_tpu.models.transformer import LatentMoEArch
-    archs = {"glm4_moe_lite": LatentMoEArch, "KeyeVL2": IndexedGQAArch}
+    from fedml_tpu.models.window_attention import WindowGQAArch
+    archs = {"glm4_moe_lite": LatentMoEArch, "KeyeVL2": IndexedGQAArch,
+             "laguna": WindowGQAArch}
     with open(model_config) as f:
         keys = json.load(f)
     kind = keys.get("model_type", "glm4_moe_lite")

@@ -97,7 +97,7 @@ class IndexedGQAArch(ArchKeys):
             flat["mrope_section"] = tuple(sections)
         return super().from_dict(flat)
 
-    def attention(self, dtype, block_size):
+    def attention(self, dtype, block_size, layer):
         return IndexedAttention(self, dtype, block_size, name="attn")
 
     def ffn(self, experts: bool, dtype):

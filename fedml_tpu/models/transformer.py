@@ -40,9 +40,14 @@ expert layer the arch states:
   term is sown into ``losses``;
 * `models.indexed_attention.IndexedGQAArch` (Keye-VL-2.0's language model):
   grouped-query attention with three-axis rotary positions whose keys a
-  learned indexer selects, every block an expert block routed by softmax.
+  learned indexer selects, every block an expert block routed by softmax;
+* `models.window_attention.WindowGQAArch` (Laguna-XS.2): grouped-query
+  attention whose layers differ by kind (a 512-key window beside full
+  causal attention), head count and rotary (YaRN over half of each head on
+  the full layers), a dense block and then sigmoid-routed expert blocks
+  with a shared expert.
 
-Both cores run through `causal_blocked_attention`.  Training only: the
+Every core runs through `causal_blocked_attention`.  Training only: the
 decode cache and the ring are the learned-position model's.
 """
 
@@ -195,7 +200,9 @@ class ArchKeys:
     `TransformerLM._decoder`, `DecoderBlock` and the workload ask of an
     arch beside its widths: ``position_rows`` (rows of positions the
     rotary takes: 1, or 3 for temporal / height / width), `attention` and
-    `ffn` (the two halves of a block, as modules), and ``counters`` (name
+    `ffn` (the two halves of a block, as modules; `attention` is given the
+    layer's index, which an arch whose layers differ by kind reads and
+    the others pass over), and ``counters`` (name
     -> (collection, shape) of what its layers sow a step, which
     `trainer.workload.NWPWorkload` sums and `wave.dispatch` reports),
     and the two counts the scaffolding reads, ``first_k_dense_replace``
@@ -259,7 +266,7 @@ class LatentMoEArch(ArchKeys):
     mtp_loss_weight: float = 0.3
     embedding_range: float = 1.0
 
-    def attention(self, dtype, block_size):
+    def attention(self, dtype, block_size, layer):
         return LatentAttention(self, dtype, block_size, name="attn")
 
     def ffn(self, experts: bool, dtype):
@@ -297,15 +304,20 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype or x.dtype)
 
 
-def rotary(x, positions, theta: float, sections=None):
+def rotary(x, positions, theta: float, sections=None, freq=None,
+           scale: float = 1.0):
     """Rotary position embedding over the last axis of ``x`` [B, T, H, r],
     pairing element ``i`` with ``i + r/2`` (the rotate-half convention).
     ``positions`` [T] turns every frequency.  With ``sections`` (numbers of
     frequencies that add up to ``r/2``) ``positions`` is [len(sections),
     T] and row ``a`` turns the ``a``-th run of frequencies (the chunked
-    multi-axis layout of Qwen2-VL: temporal, height, width)."""
+    multi-axis layout of Qwen2-VL: temporal, height, width).  ``freq``
+    [r/2], where given, replaces the frequencies ``theta^(-j / (r/2))``
+    (`yarn_inv_freq`'s), and ``scale`` multiplies cos and sin (YaRN's
+    attention factor)."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freq is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if sections is None:
         at = positions[:, None]
     else:
@@ -315,62 +327,119 @@ def rotary(x, positions, theta: float, sections=None):
         at = positions[np.repeat(np.arange(len(sections)), sections)].T
     angle = at.astype(jnp.float32) * freq[None, :]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
 
 
-def fused_core_fits(q, k, v, selected=None) -> bool:
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies (Peng et al., arXiv:2309.00071) of a
+    rotary over ``dim`` elements, float32 [dim / 2], as transformers'
+    ``_compute_yarn_parameters`` makes them with ``truncate`` on: each
+    frequency ``theta^(-2j / dim)`` is kept (extrapolated) where it turns
+    more than ``beta_fast`` times over ``original`` positions, divided by
+    ``factor`` (interpolated) where it turns fewer than ``beta_slow``
+    times, and ramped linearly between over the whole dimensions the two
+    bounds fall in.  The attention factor is the caller's (`rotary`'s
+    ``scale``)."""
+    def correction_dim(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = np.float32(theta) ** (
+        np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    extrapolated = np.float32(1.0) / pos_freqs
+    interpolated = np.float32(1.0) / (np.float32(factor) * pos_freqs)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - np.float32(low))
+                   / np.float32(high - low), 0, 1)
+    kept = np.float32(1.0) - ramp
+    return (interpolated * (1 - kept) + extrapolated * kept).astype(
+        np.float32)
+
+
+def fused_core_fits(q, k, v, selected=None, window=None) -> bool:
     """Whether `causal_blocked_attention` hands these to the fused kernels
-    (`models.fused_attention`): on a TPU, with or without a selection,
-    key heads that divide the query heads, and float32 with a sequence
-    that is a whole number of the kernels' blocks, head widths that are
-    multiples of 128 and a head that fits in VMEM."""
+    (`models.fused_attention`): on a TPU, with a selection, a window or
+    neither, key heads that divide the query heads, and float32 with a
+    sequence that is a whole number of the kernels' blocks, head widths
+    that are multiples of 128 and a head that fits in VMEM."""
     from fedml_tpu.models import fused_attention
     return (jax.default_backend() == "tpu"
-            and fused_attention.admits(q, k, v, selected))
+            and fused_attention.admits(q, k, v, selected, window))
 
 
 def causal_blocked_attention(q, k, v, block: Optional[int] = None,
-                             selected=None):
+                             selected=None, window: Optional[int] = None):
     """Causal softmax attention, ``q`` [B, T, H, dk], ``k`` [B, T, Hkv, dk]
     and ``v`` [B, T, Hkv, dv] at positions 0..T-1; query head ``h`` reads
     key/value head ``h // (H / Hkv)``.  ``selected`` [B, T, T] bool, where
     given, says which keys each query sees (a subset of its causal past
     in which every row selects a key: `models.indexed_attention`); None is
-    all of it.  One algorithm, and the inputs say which implementation of
-    it runs (`fused_core_fits`):
+    all of it.  ``window``, where given (a number of keys, at least 1;
+    never with a selection), limits query ``t`` to the keys ``t - window <
+    s <= t`` (`models.window_attention`): every row sees at least its own
+    key.  One algorithm, and the inputs say which implementation of it
+    runs (`fused_core_fits`):
 
     * on a TPU, for float32 inputs whose ``T`` is a whole number of 512,
       whose ``dk`` and ``dv`` are multiples of 128, whose ``Hkv`` divides
       ``H`` and whose head fits in VMEM (``T * max(dk, dv) <= 8192 *
-      256``), with a selection or without: the fused Pallas kernels of
-      `models.fused_attention`, one a pass, scores and probabilities in
-      VMEM only, the log-sum-exp saved for the backward pass; without a
-      selection and with ``Hkv == H`` its ``latent_attention`` kernels,
-      else its ``selected_attention`` ones (the selection a mask on every
-      score tile, a key head read by its group of query heads through the
+      256``), with a selection, a window or neither: the fused Pallas
+      kernels of `models.fused_attention`, one a pass, scores and
+      probabilities in VMEM only, the log-sum-exp saved for the backward
+      pass; without either and with ``Hkv == H`` its ``latent_attention``
+      kernels, with a window its ``window_attention`` ones (the key tiles
+      outside every row's window neither fetched nor computed), else its
+      ``selected_attention`` ones (the selection a mask on every score
+      tile, a key head read by its group of query heads through the
       index maps); ``block`` plays no part there;
     * anywhere else (the CPU, another dtype, a ragged or short ``T``, a
       narrow head, a head too long for VMEM): XLA, one block of
       ``block`` queries at a time against the keys up to its last
-      position: the scores held at once are [B, H, block, <= T], the
-      selection is a mask on them, and the blocks wholly above the
-      diagonal are never computed.  Each block is a `jax.checkpoint`, so
-      the backward pass computes its scores again and keeps none.
-      ``block`` None is one block."""
-    if fused_core_fits(q, k, v, selected):
+      position, from the first block of ``block`` keys that holds a key
+      of its first row's window on: the scores held at once are [B, H,
+      block, <= T], the selection or the window is a mask on them, and
+      the blocks wholly above the diagonal or before the window are never
+      computed (`window_tiles` counts them).  Each block is a
+      `jax.checkpoint`, so the backward pass computes its scores again
+      and keeps none.  ``block`` None is one block."""
+    if window is not None and (selected is not None or window < 1):
+        raise ValueError(f"a window of {window} keys: at least one key, "
+                         f"and never beside a selection")
+    if fused_core_fits(q, k, v, selected, window):
         from fedml_tpu.core.pallas_agg import pallas_interpret
         from fedml_tpu.models import fused_attention
         return fused_attention.fused_causal_attention(
-            q, k, v, selected, interpret=pallas_interpret(
+            q, k, v, selected, window=window, interpret=pallas_interpret(
                 fused_attention.kernel_name(q.shape[2] // k.shape[2],
-                                            selected)))
-    return _xla_blocked_attention(q, k, v, block, selected)
+                                            selected, window)))
+    return _xla_blocked_attention(q, k, v, block, selected, window)
+
+
+def window_tiles(t: int, block: int, window: int):
+    """``(causal, visited)``: of the key tiles (``block`` queries against
+    ``block`` keys) on or below one head's diagonal, the number whose keys
+    some row of the query block may see under a ``window`` of keys, the
+    tiles `causal_blocked_attention` computes with a window, whichever
+    path takes it.  Counted from the bounds both paths' loops read
+    (`fused_attention.window_key_blocks`): what those bounds give, not a
+    count the kernels keep."""
+    from fedml_tpu.models.fused_attention import window_key_blocks
+    n = -(-t // block)
+    return (n * (n + 1) // 2,
+            sum(i + 1 - window_key_blocks(i, block, window)[0]
+                for i in range(n)))
 
 
 def _xla_blocked_attention(q, k, v, block: Optional[int] = None,
-                           selected=None):
+                           selected=None, window: Optional[int] = None):
+    from fedml_tpu.models.fused_attention import window_key_blocks
     b, t, h, _ = q.shape
     kv = k.shape[2]
     g = h // kv
@@ -392,10 +461,16 @@ def _xla_blocked_attention(q, k, v, block: Optional[int] = None,
 
     @jax.checkpoint
     def one(qb, kb, vb, q_pos, chosen):
+        # ``q_pos``: the rows' positions counted from the first key given
         s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
                        preferred_element_type=jnp.float32) * scale
-        seen = jnp.arange(kb.shape[1])[None, :] <= q_pos[:, None] \
-            if chosen is None else chosen
+        if chosen is not None:
+            seen = chosen
+        else:
+            k_pos = jnp.arange(kb.shape[1])[None, :]
+            seen = k_pos <= q_pos[:, None]
+            if window is not None:
+                seen = seen & (k_pos > q_pos[:, None] - window)
         p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb,
                           preferred_element_type=jnp.float32)
@@ -403,13 +478,16 @@ def _xla_blocked_attention(q, k, v, block: Optional[int] = None,
     out = []
     for lo in range(0, t, block):
         hi = min(lo + block, t)
+        # the first block of keys that holds a key of row lo's window
+        first = 0 if window is None else \
+            window_key_blocks(lo // block, block, window)[0] * block
         # a selection lies inside the causal past: it is the whole mask,
         # each query's for every head of its group
         chosen = None if selected is None else jnp.tile(
             selected[:, None, lo:hi, :hi], (1, 1, g, 1))
-        q_pos = jnp.arange(lo, hi)
+        q_pos = jnp.arange(lo - first, hi - first)
         out.append(rows_as_heads(one(
-            heads_as_rows(q[:, lo:hi]), k[:, :hi], v[:, :hi],
+            heads_as_rows(q[:, lo:hi]), k[:, first:hi], v[:, first:hi],
             q_pos if g == 1 else jnp.tile(q_pos, g), chosen)))
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
@@ -464,17 +542,19 @@ class LatentAttention(nn.Module):
 
 class DecoderBlock(nn.Module):
     """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
-    attention the arch's, the FFN its dense gated MLP or its expert
-    layer."""
+    attention the arch's for layer ``layer`` (a field: the block's
+    checkpoint sees no traced argument for it), the FFN its dense gated
+    MLP or its expert layer."""
     arch: ArchKeys
     experts: bool
     dtype: object = None
     block_size: Optional[int] = None
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions):
         a = self.arch
-        h = x + a.attention(self.dtype, self.block_size)(
+        h = x + a.attention(self.dtype, self.block_size, self.layer)(
             RMSNorm(a.rms_norm_eps, self.dtype, name="attn_norm")(x),
             positions)
         return h + a.ffn(self.experts, self.dtype)(
@@ -513,7 +593,8 @@ class TransformerLM(nn.Module):
     pad_id: int = 0       # pad token id; MoE routing excludes pad positions
     #                       (they would otherwise eat expert capacity)
     arch: Optional[ArchKeys] = None  # the published keys of an expert
-    #                       model (`LatentMoEArch`, `IndexedGQAArch`): every
+    #                       model (`LatentMoEArch`, `IndexedGQAArch`,
+    #                       `WindowGQAArch`): every
     #                       width then comes from it and the fields above
     #                       that state one are not read
 
@@ -526,8 +607,9 @@ class TransformerLM(nn.Module):
             if decode or ring_axis is not None:
                 raise NotImplementedError(
                     "a --model_config model trains only: neither its "
-                    "decode cache (latent keys, or an indexer's keys "
-                    "beside the selected ones) nor the ring is built")
+                    "decode cache (latent keys, an indexer's keys "
+                    "beside the selected ones, or a window layer's "
+                    "cache beside a full one's) nor the ring is built")
             return self._decoder(input_seq, positions)
         if decode:
             if positions is None:
@@ -620,7 +702,7 @@ class TransformerLM(nn.Module):
         x = embed(tokens)
         for i in range(a.num_hidden_layers):
             x = block(a, i >= a.first_k_dense_replace, self.dtype,
-                      self.block_size, name=f"layer_{i}")(x, positions)
+                      self.block_size, i, name=f"layer_{i}")(x, positions)
         logits = head(final_norm(x))
         if a.num_nextn_predict_layers > 1:
             raise NotImplementedError("one multi-token prediction module")
@@ -635,7 +717,7 @@ class TransformerLM(nn.Module):
                  RMSNorm(a.rms_norm_eps, self.dtype,
                          name="mtp_enorm")(embed(tokens[:, 1:]))], axis=-1)
             y = block(a, True, self.dtype, self.block_size,
-                      name="mtp_block")(
+                      a.num_hidden_layers, name="mtp_block")(
                 nn.Dense(a.hidden_size, use_bias=False, dtype=self.dtype,
                          kernel_init=init, name="mtp_proj")(merged),
                 positions[:-1])

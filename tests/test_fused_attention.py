@@ -3,8 +3,8 @@ ISSUE 40): `models/fused_attention.py`'s Pallas kernels through the
 interpreter against the XLA blocks of `causal_blocked_attention`, the rule
 that chooses between them, the shape the benchmark's readers find the
 kernels by, the counts `wave.dispatch` carries, and the kernels compiled
-at the GLM and Keye cells' sizes for a described TPU v5e (no chip is
-attached: nothing of that runs).
+at the GLM, Keye and Laguna cells' sizes for a described TPU v5e (no chip
+is attached: nothing of that runs).
 """
 
 import functools
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import expert_attention, sparse_attention
+from benchmark import expert_attention, sparse_attention, window_attention
 from fedml_tpu.core.pallas_agg import pallas_interpret
 from fedml_tpu.models import fused_attention as fa
 from fedml_tpu.models import transformer as tr
@@ -410,19 +410,23 @@ def test_an_indexed_block_runs_the_forward_kernel_once_a_step():
 
 def _kernel_call(kernel, sharding=None, cell="glm"):
     """(the kernel's call, its arguments' shapes at the GLM cell's size,
-    or the Keye cell's with its selection)."""
+    the Keye cell's with its selection, or the window layers' of the
+    Laguna cell with their window of 512 keys)."""
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
-    h, kv, d = (20, 20, 256) if cell == "glm" else (32, 4, 128)
+    h, kv, d = {"glm": (20, 20, 256), "keye": (32, 4, 128),
+                "laguna": (64, 8, 128)}[cell]
+    window = 512 if cell == "laguna" else None
     q, k, row = shape(1, h, 8192, d), shape(1, kv, 8192, d), shape(
         1, h, 1, 8192)
-    selected = () if cell == "glm" else (shape(1, 8192, 8192,
-                                               dtype=jnp.bool_),)
+    selected = () if cell != "keye" else (shape(1, 8192, 8192,
+                                                dtype=jnp.bool_),)
     if kernel == "forward":
         return (lambda q, k, v, *s: fa._forward(
-            q, k, v, *(s or (None,)), fa.BLOCK, False), (q, k, k) + selected)
+            q, k, v, *(s or (None,)), fa.BLOCK, False, window),
+            (q, k, k) + selected)
     return (lambda q, k, v, lse, delta, do, *s: fa._backward(
-        q, k, v, lse, delta, do, *(s or (None,)), fa.BLOCK, False),
+        q, k, v, lse, delta, do, *(s or (None,)), fa.BLOCK, False, window),
         (q, k, k, row, row, q) + selected)
 
 
@@ -542,13 +546,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("cell", ["glm", "keye"])
+@pytest.mark.parametrize("cell", ["glm", "keye", "laguna"])
 @pytest.mark.parametrize("kernel", ["forward", "backward"])
 def test_kernels_compile_for_a_v5e_at_the_cells_size(kernel, cell, one_chip):
-    """Mosaic takes the kernels at 20 heads x 8,192 x 256 (GLM) and at 32
-    query / 4 key heads x 8,192 x 128 with the selection (Keye) within the
-    VMEM they ask for, and the custom call's first result is the
-    ``[B, heads, T, width]`` array (what the interpreter cannot show)."""
+    """Mosaic takes the kernels at 20 heads x 8,192 x 256 (GLM), at 32
+    query / 4 key heads x 8,192 x 128 with the selection (Keye) and at 64
+    query / 8 key heads x 8,192 x 128 under a window of 512 keys (Laguna's
+    window layers) within the VMEM they ask for, and the custom call's
+    first result is the ``[B, heads, T, width]`` array (what the
+    interpreter cannot show)."""
     fn, args = _kernel_call(kernel, one_chip, cell)
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
@@ -566,7 +572,14 @@ def test_kernels_compile_for_a_v5e_at_the_cells_size(kernel, cell, one_chip):
         assert "%latent_attention_" in calls[0]
         m = dict(GLM["model"], block=GLM["cli"]["attn_block_size"])
         assert expert_attention.group_of(calls[0], m) == "attention"
-    else:
+    elif cell == "keye":
         assert "%selected_attention_" in calls[0]
         assert sparse_attention.group_of(calls[0], _keye_model()) == \
             "attention"
+    else:
+        assert "%window_attention_" in calls[0]
+        laguna = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                             "laguna_xs2.json")))
+        m = window_attention.with_layers(laguna["model"], 1)
+        assert window_attention.group_of(calls[0], m) == "window"
+        assert sparse_attention.group_of(calls[0], _keye_model()) is None

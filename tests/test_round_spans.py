@@ -379,7 +379,8 @@ def test_skipped_step_share_reads_the_dispatch_span_as_data():
         "source": "program_counter", "layer": "staging and local training",
         "moves": "round_s", "workloads": ["resnet56_cifar10.silos10",
                                            "glm47_flash.silos2",
-                                           "keye_vl2_30b_a3b.silos2"]} \
+                                           "keye_vl2_30b_a3b.silos2",
+                                           "laguna_xs2.silos2"]} \
         in bench["per_layer"]
 
 
@@ -400,7 +401,8 @@ def test_prefetch_hit_share_reads_the_dispatch_span_as_data():
         "source": "program_counter", "layer": "staging and local training",
         "moves": "round_s", "workloads": ["resnet56_cifar10.silos10",
                                            "glm47_flash.silos2",
-                                           "keye_vl2_30b_a3b.silos2"]} \
+                                           "keye_vl2_30b_a3b.silos2",
+                                           "laguna_xs2.silos2"]} \
         in bench["per_layer"]
 
 
