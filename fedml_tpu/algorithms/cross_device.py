@@ -60,7 +60,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional
 
@@ -72,6 +71,7 @@ from fedml_tpu.algorithms.fedavg import (FedAvg, FedAvgConfig,
                                          gather_client_rows,
                                          scatter_client_rows,
                                          zeros_client_state)
+from fedml_tpu.core.global_crc import TreeCrc
 from fedml_tpu.core.sampling import sample_clients, sample_clients_jax
 from fedml_tpu.core.stream_agg import StreamingAggregator
 from fedml_tpu.data.stacking import gather_cohort
@@ -266,16 +266,14 @@ class CrossDevice(FedAvg):
             np.asarray(data.train["mask"]).any(axis=-1).sum(axis=-1)
             if self._tracer is not None else None)
 
-        # the round's global on the host, kept by the round that made it:
-        # (the device tree it mirrors, the copy `round.crc` took)
+        # the round's global on the host, kept by the round that made it
+        # where a reader needs one: (the device tree it mirrors, its copy)
         self._mirror = None
-        # the worker that takes that copy and its CRC beside the next
-        # round's wave program, and the one job it holds or is at
+        # the global's CRC on the device; the worker that has it computed
+        # (and takes that copy), and the one job it holds or is at
+        self._tree_crc = TreeCrc()
         self._crc_pool: Optional[ThreadPoolExecutor] = None
         self._crc_job = None
-        # set once the next round's first wave is dispatched: the job's
-        # transfer starts behind that launch, not in front of it
-        self._crc_go = threading.Event()
         # whether the wave leaves the device as its sum (`_ensure_bound`
         # decides, from the tree): the program that does, where the
         # configuration allows one
@@ -702,18 +700,12 @@ class CrossDevice(FedAvg):
 
     # -- the global's CRC, one round behind -----------------------------------
     def _start_crc(self, params):
-        """Hand the round's new global to the worker: ONE batched transfer
-        to the host (every leaf's started before any is awaited), its
-        `tree_crc`, and the copy kept as the next round's host copy
-        (`_run_round`).  Returns the job, a future of the CRC.  At most
-        one job is in flight: the one before it, which ran beside this
-        round's wave program, is joined first and re-raises here.  The
-        job waits for ``_crc_go`` (the next round's first dispatch, or
-        the run's end): forty transfers started in front of a wave
-        program's launch held it back 0.1-0.2 s (my chip run, PR 37)."""
+        """Hand the round's new global to the worker, which has its CRC
+        computed on the device (`_crc_of`).  Returns the job, a future of
+        the CRC.  At most one job is in flight: the one before it is
+        joined first and re-raises here."""
         if self._crc_job is not None:
             self._join_crc()
-        self._crc_go.clear()
         if self._crc_pool is None:
             self._crc_pool = ThreadPoolExecutor(
                 1, thread_name_prefix="fedml-crc")
@@ -731,24 +723,26 @@ class CrossDevice(FedAvg):
             return job.result()
 
     def _crc_of(self, params, round_ctx) -> int:
-        """On the worker.  Reads ``params``, which no round writes."""
-        from fedml_tpu.utils.journal import tree_crc
-        self._crc_go.wait(10.0)
+        """On the worker: the global's CRC program (`core.global_crc`:
+        `utils.journal.tree_crc`'s value, and nothing of the global
+        leaves the device), queued at once, so behind the round's server
+        step and ahead of the next round's first wave, and its word read
+        once the device has it.  The first round's job builds the
+        program, beside the main thread.  Where a reader needs the global
+        on the host (the health sketch, the poison seam), its copy is
+        taken here too, one batched transfer, and kept as the next
+        round's host copy (`_run_round`); the pair holds the device tree
+        too.  Reads ``params``, which no round writes."""
         # explicit parent, as `stage.prefetch`'s: the round it closes
-        with self._span("round.crc", parent=round_ctx) as crc_sp:
-            # one batched transfer, every leaf's started before any is
-            # awaited; the CRC reads each leaf as it lands
-            leaves = jax.tree.leaves(params)
-            for leaf in leaves:
-                leaf.copy_to_host_async()
+        with self._span("round.crc", parent=round_ctx,
+                        on_device=1) as crc_sp:
+            crc = self._tree_crc.dispatch(params)
             if self._tracer is not None:
-                crc_sp.set(bytes=sum(leaf.nbytes for leaf in leaves))
-            crc = tree_crc(params)
+                crc_sp.set(bytes=sum(leaf.nbytes
+                                     for leaf in jax.tree.leaves(params)))
             if self.health is not None or self._wave_attacks:
-                # kept only for a reader: the pair holds the device
-                # tree too (the copies are the arrays' own by now)
                 self._mirror = (params, jax.device_get(params))
-        return crc
+            return int(crc)
 
     def _stop_staging(self) -> None:
         """Drop what is staged and join the worker."""
@@ -756,7 +750,6 @@ class CrossDevice(FedAvg):
         if self._stage_pool is not None:
             self._stage_pool.shutdown(wait=True)
             self._stage_pool = None
-        self._crc_go.set()
         if self._crc_pool is not None:
             self._crc_pool.shutdown(wait=True)    # the last line lands
             self._crc_pool = None
@@ -780,7 +773,6 @@ class CrossDevice(FedAvg):
         # one (looked up before the pin, which may hand back another tree)
         needs_host = self.health is not None or bool(self._wave_attacks)
         if needs_host and self._crc_job is not None:
-            self._crc_go.set()          # no launch to wait behind: now
             self._join_crc()            # the worker may still be at it
         mirror, self._mirror = self._mirror, None
         host_params = (mirror[1] if mirror is not None
@@ -848,10 +840,8 @@ class CrossDevice(FedAvg):
                             params, wave_data, round_rng, offset)
                         new_c = c_delta = None
                         stats = self._stats_fn(mean, params)
-                    # the chip is busy from here: stage the wave after,
-                    # and let the last round's CRC job start its transfer
+                    # the chip is busy from here: stage the wave after
                     self._stage_next(waves, wi, round_idx)
-                    self._crc_go.set()
                     if self._real_steps is not None:
                         dispatch_sp.set(
                             **self._dispatch_counts(wave, prefetched))
@@ -966,7 +956,6 @@ class CrossDevice(FedAvg):
                                      round=round_idx) as round_sp:
                     params, rng = self._round(params, rng, round_idx,
                                               round_sp, checkpointer)
-            self._crc_go.set()
             if self._crc_job is not None:
                 self._join_crc()            # the last round's; re-raises
         finally:
@@ -1014,11 +1003,10 @@ class CrossDevice(FedAvg):
             extra = dict(info)
             # the round's post-finalize global CRC: the ingest
             # bench's bit-parity gate compares this sequence between
-            # the inline and pipelined twins (utils.journal.tree_crc
-            # — the same checksum the crash journal trusts)
-            # taken by the worker beside the next round's wave program
-            # (the copy and `zlib.crc32` both release the GIL); the
-            # ledger line below is written when it is there
+            # the inline and pipelined twins (utils.journal.tree_crc's
+            # value — the same checksum the crash journal trusts),
+            # computed on the device for the worker; the ledger line
+            # below is written when it is there
             extra["global_crc"] = self._start_crc(params)
             if self.server_opt is not None:
                 extra["server_opt"] = self.server_opt.name

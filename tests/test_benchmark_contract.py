@@ -61,6 +61,7 @@ def program_modules():
     reduction finds programs by."""
     from fedml_tpu.algorithms.cross_device import (CrossDevice,
                                                    CrossDeviceConfig)
+    from fedml_tpu.core import global_crc
     from fedml_tpu.core.stream_agg import (StreamingAggregator,
                                            zeros_acc_like)
     from fedml_tpu.data import load_data
@@ -87,8 +88,11 @@ def program_modules():
     fold = agg._fold_wave_fn.lower(acc, jnp.float32(0), stacked,
                                    jnp.ones(5, jnp.float32), params)
     finalize = agg._finalize_fn.lower(acc, jnp.float32(1), params, 0)
+    # the global's CRC, as `global_crc.TreeCrc` builds it for this tree
+    crc_fn, consts = global_crc.program(jax.tree.leaves(params), None)
+    crc = jax.jit(crc_fn).lower(jax.tree.leaves(params), consts)
     return {"cross_device": [_module_name(p)
-                             for p in (wave, fold, finalize)]}
+                             for p in (wave, fold, finalize, crc)]}
 
 
 def _hook_cases():

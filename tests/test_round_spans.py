@@ -77,14 +77,13 @@ def test_every_round_is_one_tree_under_one_root(cli_run):
                 # a child lies inside its parent, on the raw clock;
                 # `stage.prefetch` starts inside the round it runs beside
                 # and may end across its edge (the worker stages the next
-                # round's first wave), and `round.crc` closes its round
-                # from beside the next one: its worker starts it once the
-                # next round's first wave is dispatched (ISSUE 37)
+                # round's first wave), and so may `round.crc`: its worker
+                # starts it as the round closes and reads the CRC's word
+                # once the device program has run
                 ends = (e["args"]["t0_ns"] + e["args"]["dur_ns"],
                         parent["args"]["t0_ns"] + parent["args"]["dur_ns"])
                 assert e["args"]["t0_ns"] >= parent["args"]["t0_ns"]
-                assert e["args"]["t0_ns"] <= ends[1] \
-                    or e["name"] == "round.crc"
+                assert e["args"]["t0_ns"] <= ends[1]
                 assert ends[0] <= ends[1] or e["name"] in (
                     "stage.prefetch", "round.crc")
                 e, hops = parent, hops + 1
@@ -235,12 +234,19 @@ def test_each_ledger_phase_is_the_sum_of_its_spans(cli_run):
 
 
 def test_crc_span_counts_the_globals_bytes(cli_run):
-    """`round.crc` carries the global's bytes: the span table's bytes a
-    round beside its seconds give the copy's rate (lr on MNIST: 784 x 10
+    """`round.crc` carries the global's bytes, which its program reads on
+    the device (``on_device`` 1: no copy to the host), and is the CRC
+    worker's, parented to the round it closes (lr on MNIST: 784 x 10
     weights and 10 biases in f32)."""
     crcs = [e for e in cli_run["events"] if e["name"] == "round.crc"]
     assert len(crcs) == 2
     assert {e["args"]["bytes"] for e in crcs} == {(784 * 10 + 10) * 4}
+    assert {e["args"]["on_device"] for e in crcs} == {1}
+    by_id = {e["args"]["span_id"]: e for e in cli_run["events"]}
+    for e in crcs:
+        root = by_id[e["args"]["parent_id"]]
+        assert root["name"] == "round"
+        assert e["tid"] != root["tid"]
 
 
 def test_counts_ride_the_staging_spans(cli_run):

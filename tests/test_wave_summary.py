@@ -4,8 +4,9 @@ Where nothing reads one client's result, the wave program trains its
 clients in sequence and only their slot-order weighted sum leaves it
 (`train_cohort_sum`, `make_summed_wave_fn`, `StreamingAggregator.fold_sum`);
 the admission screen reads a few numbers the program computes beside its
-summary (`admission_stats`); the round keeps one host copy of a global
-(`round.crc`'s, which is the next round's `round.host_copy`).  Held here:
+summary (`admission_stats`); where a reader needs the global on the host,
+the round keeps one copy of it (the CRC worker's, which is the next
+round's `round.host_copy`).  Held here:
 (a) `choose_client_axis` by size; (b) the summed round against the stacked
 round: the same bits for one wave a round, to rounding for three; (c) the
 device-side screen gives the host walk's verdicts, case by case; (d) the
@@ -289,9 +290,10 @@ def test_a_runs_crc_sequence_is_the_stacked_paths(workload, data, tmp_path):
 
 def test_host_copy_after_round_0_is_the_crcs_copy(workload, data, tmp_path,
                                                   monkeypatch):
-    """With the health sketch reading the global on the host: round 0
-    transfers it once for `round.host_copy`, every later round is handed
-    the copy `round.crc` took of it, the same arrays."""
+    """With the health sketch reading the global on the host (so
+    ``needs_host``): round 0 transfers it once for `round.host_copy`,
+    every later round is handed the copy the CRC worker took of it, the
+    same arrays."""
     gets, seen = [], []
     real = jax.device_get
 
@@ -313,8 +315,9 @@ def test_host_copy_after_round_0_is_the_crcs_copy(workload, data, tmp_path,
     _, eng = _ledger_crcs(tmp_path, "mirror", workload, data, _cfg(),
                           health=health)
     # a round's transfers of a whole global: the wave's mean for the
-    # sketch, the new global for `health.round_end` and for the CRC;
-    # round 0 has the host copy besides
+    # sketch, the new global for `health.round_end` and the worker's
+    # copy of it for the next round (its CRC is the device's); round 0
+    # has the host copy besides
     assert len(seen) == 3 and len(gets) == 3 * 3 + 1
     crc_copies = [g for g in gets if any(g is s for s in seen[1:])]
     assert len(crc_copies) == 2
